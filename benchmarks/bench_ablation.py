@@ -53,9 +53,9 @@ def run() -> list[dict]:
     # no Rule 4 (VMEM-infeasible schedules kept in the candidate set)
     stats = PruneStats()
     cands = generate_candidates(
-        ch, hw=V5E.__class__(name="no_r4", vmem_bytes=1 << 62), stats=stats)
+        ch, hw=V5E.__class__(name="no_r4", vmem_budget=1 << 62), stats=stats)
     n_infeasible = sum(
-        1 for c in cands if vmem_estimate(c, V5E) > V5E.vmem_bytes)
+        1 for c in cands if vmem_estimate(c, V5E) > V5E.vmem_budget)
     rows.append({"variant": "no_rule4", "candidates": stats.n_kept,
                  "search_s": None, "best_us": None,
                  "quality_vs_full": None,

@@ -156,7 +156,9 @@ print("RESULT " + json.dumps(
 
 
 def _pipelined_wire_smoke() -> list[str]:
-    env = dict(os.environ)
+    # the child only counts bytes on forced host devices: keep it off
+    # the accelerator, which this process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (src, env.get("PYTHONPATH")) if p)

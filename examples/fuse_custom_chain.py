@@ -38,7 +38,7 @@ def main():
     print(f"\nbest schedule : {s.sub_expr()}  grid={s.grid}")
     print(f"tile sizes    : {s.tile_sizes}")
     print(f"VMEM estimate : {vmem_estimate(s, V5E)/2**20:.1f} MiB "
-          f"(budget {V5E.vmem_bytes/2**20:.0f} MiB)")
+          f"(budget {V5E.vmem_budget/2**20:.0f} MiB)")
     print(f"est. time     : {estimate(s, V5E)*1e6:.2f} us  "
           f"[mem {t_mem(s, V5E)*1e6:.2f}, comp {t_comp(s, V5E)*1e6:.2f}]")
     unfused = ch.io_bytes() / V5E.hbm_bw

@@ -1,4 +1,2 @@
 """MCFuser reproduction: fused MBCI kernels + the serving/training system
-around them.  Importing any ``repro`` module installs the JAX
-API-compatibility shims (see ``repro._compat``)."""
-from . import _compat  # noqa: F401  (side-effect import)
+around them."""

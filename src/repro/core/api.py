@@ -35,7 +35,8 @@ import numpy as np
 from . import codegen, pruning, schedule_cache
 from .chain import Chain, attention_chain, gemm_chain, mlp_chain
 from .dag import build_schedule
-from .perf_model import MeshSpec, TpuSpec, V5E, paged_gather_seconds
+from .perf_model import (MeshSpec, TpuSpec, device_spec,
+                         paged_gather_seconds)
 from .search import SearchReport, heuristic_search, rank_regimes
 
 _CACHE: dict[tuple, "TunedKernel"] = {}
@@ -188,7 +189,7 @@ def _tune_or_load(kind: str, chain: Chain, hw: TpuSpec,
 
 
 def fuse_gemm_chain(M: int, N: int, K: int, H: int, batch: int = 1,
-                    dtype: str = "float32", hw: TpuSpec = V5E,
+                    dtype: str = "float32", hw: Optional[TpuSpec] = None,
                     mesh: Optional[MeshSpec] = None,
                     interpret: Optional[bool] = None,
                     unit: int = 128, seed: int = 0,
@@ -201,6 +202,7 @@ def fuse_gemm_chain(M: int, N: int, K: int, H: int, batch: int = 1,
     does this wiring).  ``measure_fn`` enables wall-clock trials (real
     TPU); its outcome caches under the distinct "measured" trial kind.
     """
+    hw = hw or device_spec()
     interp = (not _is_tpu()) if interpret is None else interpret
     trial = "measured" if measure_fn is not None else "analytic"
     key = ("gemm", M, N, K, H, batch, dtype, hw.name, unit, mesh, interp,
@@ -240,7 +242,7 @@ def fuse_gemm_chain(M: int, N: int, K: int, H: int, batch: int = 1,
 
 def fuse_mlp_chain(M: int, FF: int, D: int, batch: int = 1,
                    dtype: str = "float32", gated: bool = True,
-                   act: str = "silu", hw: TpuSpec = V5E,
+                   act: str = "silu", hw: Optional[TpuSpec] = None,
                    mesh: Optional[MeshSpec] = None,
                    interpret: Optional[bool] = None,
                    unit: int = 128, seed: int = 0,
@@ -255,6 +257,7 @@ def fuse_mlp_chain(M: int, FF: int, D: int, batch: int = 1,
     key prefix, so they never collide with plain gemm-chain entries of
     the same dims.
     """
+    hw = hw or device_spec()
     interp = (not _is_tpu()) if interpret is None else interpret
     trial = "measured" if measure_fn is not None else "analytic"
     key = ("mlp", M, FF, D, batch, gated, act, dtype, hw.name, unit,
@@ -307,7 +310,8 @@ def fuse_attention(M: int, N: int, K: int, H: int, heads: int = 1,
                    batch: int = 1, dtype: str = "float32",
                    causal: bool = False, window: int = 0,
                    scale: Optional[float] = None,
-                   hw: TpuSpec = V5E, mesh: Optional[MeshSpec] = None,
+                   hw: Optional[TpuSpec] = None,
+                   mesh: Optional[MeshSpec] = None,
                    interpret: Optional[bool] = None,
                    unit: int = 128, seed: int = 0,
                    measure_fn=None) -> TunedKernel:
@@ -319,6 +323,7 @@ def fuse_attention(M: int, N: int, K: int, H: int, heads: int = 1,
     regime, the kv loop ``n`` enters through ``mesh.placement`` and the
     collective term prices the log-sum-exp combine).  ``measure_fn``
     enables wall-clock trials; see ``fuse_gemm_chain``."""
+    hw = hw or device_spec()
     interp = (not _is_tpu()) if interpret is None else interpret
     trial = "measured" if measure_fn is not None else "analytic"
     key = ("attn", M, N, K, H, heads, batch, dtype, causal, window,
@@ -364,7 +369,7 @@ def fuse_attention_paged(M: int, N: int, K: int, H: int, *,
                          page_size: int, heads: int = 1, batch: int = 1,
                          dtype: str = "float32", causal: bool = True,
                          window: int = 0, scale: Optional[float] = None,
-                         hw: TpuSpec = V5E,
+                         hw: Optional[TpuSpec] = None,
                          mesh: Optional[MeshSpec] = None,
                          interpret: Optional[bool] = None,
                          unit: int = 128, seed: int = 0) -> TunedKernel:
@@ -384,6 +389,7 @@ def fuse_attention_paged(M: int, N: int, K: int, H: int, *,
     Serving attention is causal by construction (``causal`` exists for
     pricing symmetry and must stay True for the built kernel).
     """
+    hw = hw or device_spec()
     interp = (not _is_tpu()) if interpret is None else interpret
     key = ("attn-paged", page_size, M, N, K, H, heads, batch, dtype,
            causal, window, scale, hw.name, unit, mesh, interp, seed)
@@ -430,7 +436,7 @@ def fuse_attention_regimes(M: int, N: int, K: int, H: int, *,
                            heads: int = 1, batch: int = 1,
                            dtype: str = "float32", causal: bool = False,
                            window: int = 0, scale: Optional[float] = None,
-                           hw: TpuSpec = V5E,
+                           hw: Optional[TpuSpec] = None,
                            regimes: dict[str, Optional[MeshSpec]],
                            interpret: Optional[bool] = None,
                            unit: int = 128, seed: int = 0) -> RegimeChoice:
@@ -472,7 +478,7 @@ def fuse_attention_paged_regimes(M: int, N: int, K: int, H: int, *,
                                  batch: int = 1, dtype: str = "float32",
                                  window: int = 0,
                                  scale: Optional[float] = None,
-                                 hw: TpuSpec = V5E,
+                                 hw: Optional[TpuSpec] = None,
                                  regimes: dict[str, Optional[MeshSpec]],
                                  interpret: Optional[bool] = None,
                                  unit: int = 128,

@@ -20,7 +20,8 @@ matrix is priced as NumPy array math:
   records (``Schedule.cached_dim_sets``): mult = prod of extents over
   each set.
 * **Rule-4** (``vmem_estimate_batch``) is the same visit/tile products
-  against the double-buffer + f32-accumulator charges.
+  against the pipeline-buffer + f32-scratch charges, with the kernels'
+  whole-held loops (``perf_model.resident_loops``) at full extent.
 
 Bit-compatibility contract: for any schedule, ``estimate_batch`` /
 ``vmem_estimate_batch`` on a 1-row tile matrix accumulate per-statement
@@ -40,7 +41,8 @@ import numpy as np
 
 from .chain import Chain, DTYPE_BYTES
 from .dag import bind_grid, build_schedule
-from .perf_model import MeshSpec, TpuSpec, V5E, collective_bytes
+from .perf_model import (MeshSpec, TpuSpec, V5E, collective_bytes,
+                         matmuls_into, resident_loops)
 from .tiling import Scope, expr_repr
 
 
@@ -78,6 +80,8 @@ class _CompStmt:
     related: tuple[str, ...]
     out_dims: tuple[str, ...]
     flops_per_point: int
+    n_matmuls: int              # perf_model.matmuls_into
+    stat_dim: Optional[str]     # row dim of online-softmax statistics
 
 
 @dataclass(frozen=True)
@@ -95,6 +99,7 @@ class ExprClassTable:
     #   Schedule.stmts order — vmem_estimate accumulates in this order
     cached_dim_sets: tuple[tuple[str, tuple[tuple[str, ...], ...]], ...]
     # ^ (intermediate, dim sets) for the Rule-2 blow-up
+    resident: frozenset         # loops the kernels hold whole in VMEM
 
     @classmethod
     def build(cls, chain: Chain, expr: Scope,
@@ -110,10 +115,15 @@ class ExprClassTable:
             if s.kind == "compute":
                 op = next(o for o in chain.ops if o.name == s.op)
                 order.append(("comp", len(comps)))
+                out_dims = chain.tensors[s.tensor].dims
                 comps.append(_CompStmt(
                     tensor=s.tensor, path=s.path, related=s.related,
-                    out_dims=chain.tensors[s.tensor].dims,
-                    flops_per_point=op.flops_per_point))
+                    out_dims=out_dims,
+                    flops_per_point=op.flops_per_point,
+                    n_matmuls=matmuls_into(op),
+                    stat_dim=(out_dims[0]
+                              if op.epilogue == "online_softmax"
+                              else None)))
             else:
                 t = chain.tensors[s.tensor]
                 grp = 0
@@ -130,7 +140,8 @@ class ExprClassTable:
                    mem_stmts=tuple(mems), comp_stmts=tuple(comps),
                    stmt_order=tuple(order),
                    cached_dim_sets=tuple(sorted(
-                       ref.cached_dim_sets.items())))
+                       ref.cached_dim_sets.items())),
+                   resident=resident_loops(ref.block_expr))
 
     # ------------------------------------------------------------------
     def _col(self, loop: str) -> int:
@@ -217,7 +228,9 @@ class ExprClassTable:
             trips, key = self._mem_trips_and_key(ext, s)
             tile_b = self._visit(tiles, s.dims, s.path) * s.dtype_bytes
             contrib = (tile_b * trips).astype(np.float64)
-            res = 2 * tile_b if s.is_load else tile_b
+            res = 2 * s.dtype_bytes * self._visit(
+                tiles, s.dims,
+                tuple(d for d in s.path if d not in self.resident))
             if s.is_load:
                 earlier = load_keys.setdefault(s.tensor, [])
                 if earlier:
@@ -262,12 +275,17 @@ class ExprClassTable:
             comp_total += (flops * trips) / np.maximum(util, 1e-9)
             elems = np.ones(A, dtype=np.int64)
             for d in s.out_dims:
-                elems = elems * tiles[:, self._col(d)]
+                elems = elems * (self.chain.loops[d] if d in self.resident
+                                 else tiles[:, self._col(d)])
             mult = mult_by_tensor.get(s.tensor)
             if mult is not None:
                 # scalar records the blow-up only when > 1
                 elems = elems * np.maximum(mult, 1)
-            vmem_comp += elems * DTYPE_BYTES["float32"]
+            f32 = DTYPE_BYTES["float32"]
+            vmem_comp += 2 * s.n_matmuls * elems * f32
+            if s.stat_dim is not None:
+                vmem_comp += (2 * tiles[:, self._col(s.stat_dim)]
+                              * hw.mxu_align * f32)
         # NOTE: scalar vmem_estimate accumulates in Schedule.stmts order
         # (computes interleaved with loads/stores); integer addition is
         # exact so regrouping into mem + comp partial sums is identical.

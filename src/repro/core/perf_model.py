@@ -19,9 +19,11 @@ chain) plus the ring-collective time needed to combine partial results
 across sharded reduction loops.  With no mesh — or a 1×1 mesh —
 ``t_coll`` is 0 and (2') degenerates to the paper's eq (2) exactly.
 
-VMEM estimation mirrors the paper's eq. (1) shared-memory estimate with
-a 2x double-buffer factor on pipelined input tiles (Mosaic allocates
-two copies of every streamed block).
+VMEM estimation mirrors the paper's eq. (1) shared-memory estimate,
+counted the way Mosaic allocates: two pipeline buffers of every input
+and output block, plus the kernels' f32 scratch (``vmem_estimate``).
+Rule 4 holds it to ``TpuSpec.vmem_budget``, the same number every
+kernel passes Mosaic as ``vmem_limit_bytes``.
 """
 from __future__ import annotations
 
@@ -31,6 +33,7 @@ from dataclasses import dataclass
 
 from .chain import Chain, DTYPE_BYTES
 from .dag import Schedule
+from .tiling import Scope
 from .ring import (ICI_HOP_LATENCY_S, pipelined_overlap_seconds,
                    ring_traffic_bytes)
 
@@ -38,22 +41,30 @@ from .ring import (ICI_HOP_LATENCY_S, pipelined_overlap_seconds,
 # (chain, tile assignment, mesh) — new terms, retuned constants, changed
 # hoisting semantics.  core.schedule_cache folds this into every disk
 # key, so persisted schedules from an older model never resurface.
-MODEL_VERSION = 4
+MODEL_VERSION = 5
 
 
 @dataclass(frozen=True)
 class TpuSpec:
-    """TPU v5e (the production target in this repo)."""
+    """Peaks and on-chip limits of one TPU chip, as the tuner prices it.
+
+    Defaults are TPU v5e.  Peaks: Google Cloud documentation, "TPU v5e"
+    (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI over
+    four links).  VMEM: 128 MiB per TensorCore, the capacity the v5e
+    compiler reports when a kernel overruns it."""
 
     name: str = "tpu_v5e"
     peak_flops: float = 197e12        # bf16 MXU peak (P)
     hbm_bw: float = 819e9             # bytes/s (W)
     vmem_bytes: int = 128 * 1024 * 1024
+    # Rule-4 budget and every kernel's ``vmem_limit_bytes``: three
+    # quarters of VMEM, leaving Mosaic room for the temporaries of a
+    # kernel body that ``vmem_estimate`` does not itemize
+    vmem_budget: int = 96 * 1024 * 1024
     ici_bw: float = 50e9              # bytes/s per link
     mxu_align: int = 128              # lane width; matmul tile unit
     sublane: int = 8
     pipeline_stages: int = 2          # double buffering (alpha, eq 5')
-    vmem_slack: float = 1.2           # paper's Rule-4 estimation slack
     n_cores: int = 1                  # v5e: 1 TensorCore per chip
 
 
@@ -61,6 +72,33 @@ V5E = TpuSpec()
 
 # fp32 path (interpret-mode / CPU correlation experiments use fp32)
 V5E_F32 = TpuSpec(name="tpu_v5e_f32", peak_flops=197e12 / 4)
+
+# The chips the tuner can price, keyed by ``jax.Device.device_kind``.
+TPU_SPECS = {"TPU v5 lite": V5E}
+
+
+def tpu_spec(device_kind: str) -> TpuSpec:
+    """The ``TpuSpec`` of a TPU by its ``device_kind``.  A kind the
+    table does not hold is an error: pricing it as another chip would
+    tune tiles for the wrong VMEM, peaks and bandwidth."""
+    try:
+        return TPU_SPECS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no TpuSpec for TPU device kind {device_kind!r}; known: "
+            f"{sorted(TPU_SPECS)}") from None
+
+
+def device_spec() -> TpuSpec:
+    """The spec the tuner prices against in this process: the attached
+    TPU's entry in ``TPU_SPECS``, or the v5e target when the backend is
+    not a TPU (interpret-mode kernels and compiles for a described
+    v5e)."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return V5E
+    return tpu_spec(dev.device_kind)
 
 
 @dataclass(frozen=True)
@@ -397,30 +435,74 @@ def estimate(sched: Schedule, hw: TpuSpec = V5E,
     return t
 
 
+def resident_loops(block_expr: Scope) -> frozenset:
+    """Loops the fused kernels hold whole in VMEM.  A flat block sweeps
+    sibling loops one after another inside a grid step, and the kernels
+    run every sibling after the first (the consumer's ``h`` in
+    ``n(k,h)``) untiled: the full-width row the flat kernels keep
+    (``kernels.gemm_chain``, ``kernels.gemm_chain3``)."""
+    out: set[str] = set()
+
+    def walk(scope: Scope, whole: bool) -> None:
+        for i, loop in enumerate(scope):
+            w = whole or (len(scope) > 1 and i > 0)
+            if w:
+                out.add(loop.name)
+            walk(loop.body, w)
+
+    walk(block_expr, False)
+    return frozenset(out)
+
+
+def matmuls_into(op) -> int:
+    """Matmuls accumulating into one op's output: one per operand after
+    the shared left-hand one (the gated MLP up-projection has two,
+    ``A@Wu`` and ``A@Wg``, and the kernel keeps an accumulator each)."""
+    return max(1, len(op.ins) - 1)
+
+
 def vmem_estimate(sched: Schedule, hw: TpuSpec = V5E) -> int:
-    """Paper eq (1) adapted: per-grid-step VMEM residency in bytes."""
+    """Paper eq (1), counted as Mosaic allocates it per grid step:
+
+    * two pipeline buffers of every input and output block — loops the
+      kernels hold whole (``resident_loops``) at full extent;
+    * per produced tile, for each matmul feeding it, an f32 accumulator
+      and the f32 dot result added into it (times the Rule-2
+      multiplicity of a cached intermediate);
+    * for an online-softmax producer, its (rows, lanes) f32 running max
+      and running sum.
+
+    Checked against the v5e compiler by tests/test_tpu_compile.py."""
     total = 0
     chain = sched.chain
-    producers = chain.producers()
+    ops = {o.name: o for o in chain.ops}
+    whole = resident_loops(sched.block_expr)
+    f32 = DTYPE_BYTES["float32"]
     for s in sched.stmts:
         tensor = chain.tensors[s.tensor]
-        if s.kind == "load":
-            tile = sched.visit_elems(s, tensor.dims) * tensor.dtype_bytes
-            total += 2 * tile  # double-buffered pipelined input
-        elif s.kind == "store":
-            total += sched.visit_elems(s, tensor.dims) * tensor.dtype_bytes
+        if s.kind in ("load", "store"):
+            elems = 1
+            for d in tensor.dims:
+                elems *= (sched.tile_sizes[d]
+                          if d in s.path and d not in whole
+                          else chain.loops[d])
+            total += 2 * elems * tensor.dtype_bytes
         elif s.kind == "compute":
-            # fp32 accumulator for the produced tile
+            op = ops[s.op]
             tile_elems = 1
             for d in tensor.dims:
-                tile_elems *= sched.tile_sizes[d]
+                tile_elems *= (chain.loops[d] if d in whole
+                               else sched.tile_sizes[d])
             mult = sched.cached_intermediates.get(s.tensor, 1)
-            total += tile_elems * mult * DTYPE_BYTES["float32"]
+            total += 2 * matmuls_into(op) * tile_elems * mult * f32
+            if op.epilogue == "online_softmax":
+                total += (2 * sched.tile_sizes[tensor.dims[0]]
+                          * hw.mxu_align * f32)
     return total
 
 
 def fits_vmem(sched: Schedule, hw: TpuSpec = V5E) -> bool:
-    return vmem_estimate(sched, hw) <= hw.vmem_slack * hw.vmem_bytes
+    return vmem_estimate(sched, hw) <= hw.vmem_budget
 
 
 def roofline_bound(sched: Schedule, hw: TpuSpec = V5E) -> float:

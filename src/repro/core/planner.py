@@ -47,7 +47,7 @@ from typing import Optional
 from . import schedule_cache
 from .chain import (Chain, DTYPE_BYTES, attention_chain, mlp_chain,
                     single_gemm)
-from .perf_model import MeshSpec, TpuSpec, V5E
+from .perf_model import MeshSpec, TpuSpec, V5E, device_spec
 from .pruning import stitched_vmem_ok
 
 # Bump when the carve/stitch semantics change: old plan records become
@@ -438,9 +438,11 @@ def config_fingerprint(cfg) -> tuple:
 
 
 def plan_key(cfg, batch: int, seq: int, stitch: bool,
-             hw: TpuSpec = V5E, mesh: Optional[MeshSpec] = None,
+             hw: Optional[TpuSpec] = None,
+             mesh: Optional[MeshSpec] = None,
              phase: str = "forward", paged: Optional[int] = None,
              kv_len: Optional[int] = None) -> tuple:
+    hw = hw or device_spec()
     return ("plan", PLANNER_VERSION, config_fingerprint(cfg), batch, seq,
             bool(stitch), hw.name,
             mesh.canonical() if mesh is not None else None,
@@ -453,7 +455,8 @@ def clear_memo() -> None:
 
 
 def plan_model(cfg, batch: int, seq: int, *, stitch: bool = True,
-               hw: TpuSpec = V5E, mesh: Optional[MeshSpec] = None,
+               hw: Optional[TpuSpec] = None,
+               mesh: Optional[MeshSpec] = None,
                use_cache: bool = True, phase: str = "forward",
                paged: Optional[int] = None,
                kv_len: Optional[int] = None) -> Plan:
@@ -478,6 +481,7 @@ def plan_model(cfg, batch: int, seq: int, *, stitch: bool = True,
     fingerprint after a kernel failure) is consulted by the callers —
     ``models/lm.py`` and ``serving/engine.py`` — not here: a
     denylisted plan still loads; it just never runs."""
+    hw = hw or device_spec()
     if not plannable(cfg):
         raise ValueError(f"config {cfg.name!r} is not plannable")
     if phase not in PHASES:
@@ -664,7 +668,7 @@ def _glue_stitched_seconds(node: OpNode, cfg, batch: int, seq: int,
     return extra / hw.hbm_bw
 
 
-def price_plan(plan: Plan, cfg, *, hw: TpuSpec = V5E,
+def price_plan(plan: Plan, cfg, *, hw: Optional[TpuSpec] = None,
                mesh: Optional[MeshSpec] = None, seed: int = 0) -> dict:
     """Price one block of ``plan`` under eq (2') and compare with the
     hand-wired layout (fused attention + unfused MLP + standalone
@@ -684,6 +688,7 @@ def price_plan(plan: Plan, cfg, *, hw: TpuSpec = V5E,
     term; the ``kv_write`` write-through prices standalone on *both*
     sides (planner and hand-wired execute the identical scatter).
     """
+    hw = hw or device_spec()
     from . import api
     from .perf_model import paged_gather_seconds
 

@@ -10,7 +10,8 @@ Rule 3  Padding: tile sizes that do not divide a power-of-two dim are
         discarded; otherwise padding ratio must stay < 0.05.  Dims below
         the MXU lane width are exempt (padding is mandatory there).
 Rule 4  VMEM limit: estimated residency (perf_model.vmem_estimate, the
-        paper's eq. (1)) must be <= 1.2 x VMEM.
+        paper's eq. (1)) must fit ``TpuSpec.vmem_budget``, which every
+        kernel also hands Mosaic as its ``vmem_limit_bytes``.
 """
 from __future__ import annotations
 
@@ -68,8 +69,8 @@ def validate_schedule(sched: Schedule, hw: TpuSpec = V5E,
     checks the search itself enforced, so a legitimately tuned outcome
     always passes: Rule 2 via ``Schedule.valid`` (the rebuild uses
     ``hard_rule2=True``), Rule 3 via :func:`rule3_padding_ok` per loop,
-    Rule 4 via the same ``vmem_slack`` budget ``heuristic_search``
-    prunes with.  (Rule 1 is a dedup, not a validity property — an
+    Rule 4 via the same ``vmem_budget`` ``heuristic_search`` prunes
+    with.  (Rule 1 is a dedup, not a validity property — an
     un-deduplicated schedule is wasteful, not wrong.)
 
     Returns ``(ok, reason)``; ``reason`` is "" when valid.
@@ -85,7 +86,7 @@ def validate_schedule(sched: Schedule, hw: TpuSpec = V5E,
             return False, f"bad_tile:{name}={t}"
         if not rule3_padding_ok(ext, t, unit):
             return False, f"rule3_padding:{name}={t}"
-    if vmem_estimate(sched, hw) > hw.vmem_slack * hw.vmem_bytes:
+    if vmem_estimate(sched, hw) > hw.vmem_budget:
         return False, "rule4_vmem"
     return True, ""
 
@@ -119,7 +120,7 @@ def stitched_vmem_ok(chain: Chain, extra_bytes: int, hw: TpuSpec = V5E,
     for t in chain.tensors.values():
         resident += math.prod(tile[d] for d in t.dims) * t.dtype_bytes
     resident *= hw.pipeline_stages
-    return resident + extra_bytes <= hw.vmem_slack * hw.vmem_bytes
+    return resident + extra_bytes <= hw.vmem_budget
 
 
 def iter_tile_assignments(chain: Chain, unit: int = 128,
@@ -181,7 +182,7 @@ def generate_candidates(chain: Chain, hw: TpuSpec = V5E, unit: int = 128,
 
     final = []
     for sched in kept.values():
-        if vmem_estimate(sched, hw) > hw.vmem_slack * hw.vmem_bytes:
+        if vmem_estimate(sched, hw) > hw.vmem_budget:
             stats.n_rule4 += 1
             continue
         final.append(sched)
@@ -329,7 +330,7 @@ def generate_candidates_batch(chain: Chain, hw: TpuSpec = V5E,
                        dtype=np.int64).reshape(-1, len(names))
     stats.n_rule3 = (n_raw_tiles - tiles.shape[0]) * len(exprs)
 
-    budget = hw.vmem_slack * hw.vmem_bytes
+    budget = hw.vmem_budget
     by_class: dict[tuple, int] = {}
     classes: list[PricedClass] = []
     candidates: list[tuple[int, int]] = []

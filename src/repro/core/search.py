@@ -79,7 +79,7 @@ def _mutate(sched: Schedule, chain: Chain, rng: random.Random,
         cand = build_schedule(chain, sched.expr, ts, hard_rule2=True)
         if not cand.valid:
             continue
-        if vmem_estimate(cand, hw) > hw.vmem_slack * hw.vmem_bytes:
+        if vmem_estimate(cand, hw) > hw.vmem_budget:
             continue
         return cand
     return None
@@ -270,7 +270,7 @@ def _search_batch(chain: Chain, measure_fn: Optional[MeasureFn],
     rule3_ok = {l: {t for t in tile_cands[l]
                     if rule3_padding_ok(chain.loops[l], t, unit)}
                 for l in loops}
-    vmem_budget = hw.vmem_slack * hw.vmem_bytes
+    vmem_budget = hw.vmem_budget
 
     best_t = math.inf
     best: Optional[tuple[int, int]] = None

@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import _compat
 from .sharding import Rules, ring_dispatch_spec
 
 
@@ -256,7 +255,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         den = jax.lax.psum(den_loc, axis)
         return finalize_partials(num, den[..., None], ql.dtype)
 
-    return _compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(qs, kvs, kvs), out_specs=qs,
         check_vma=False)(q, k, v)
 
@@ -341,7 +340,7 @@ def paged_ring_decode_attention(q, k_pages, v_pages, page_table,
             o = finalize_partials(acc, l, qb.dtype)
         return o.reshape(bl, hq, m, vv.shape[-1])
 
-    return _compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(qs, pgs, pgs, ts, pos_s),
         out_specs=qs, check_vma=False)(q, k_pages, v_pages, page_table,
                                        positions)

@@ -37,7 +37,6 @@ from typing import Optional, Sequence, Union
 import jax
 from jax.sharding import PartitionSpec as P
 
-from .. import _compat
 
 AxisName = Union[str, Sequence[str], None]
 
@@ -117,16 +116,16 @@ class Rules:
     def batch_spec(self, batch: int, mesh: Optional[jax.sharding.Mesh]) -> P:
         """Placement of a leading batch dimension of size ``batch``.
 
-        Returns a length-1 PartitionSpec whose entry is the tuple of
-        mesh axes the batch dim shards over, or an empty spec when the
-        batch cannot be sharded.  Degrades gracefully: axes are dropped
+        Returns a length-1 PartitionSpec over the mesh axes the batch
+        dim shards over, or an empty spec when the batch cannot be
+        sharded.  jax normalises a one-axis tuple entry to the bare
+        axis name, so callers that need the axes themselves take the
+        tuple from ``batch_placement``.  Degrades gracefully: axes are dropped
         from the right until their combined size divides ``batch``, so
         a batch of 4 on a (data=2, model=4) mesh still shards over
         data instead of failing.
         """
-        if not self.enabled or mesh is None:
-            return P()
-        axes = _divisible_axes(self, mesh, "batch", batch)
+        axes = batch_placement(self, mesh, batch)
         return P(axes) if axes else P()
 
 
@@ -161,16 +160,14 @@ def default_rules(mesh: jax.sharding.Mesh) -> Rules:
 
 def batch_placement(rules: Rules, mesh: jax.sharding.Mesh,
                     batch: int) -> tuple[str, ...]:
-    """Data axes a batch dim of size ``batch`` shards over (dropping
-    non-dividing axes, via ``Rules.batch_spec``).  Shared by the
-    kernel dispatcher (``kernels.ops``) and the tuner bridge
-    (``launch.mesh.tuner_mesh_spec``) so the tuner prices exactly what
-    is dispatched."""
-    spec = rules.batch_spec(batch, mesh)
-    if not len(spec) or spec[0] is None:
+    """Data axes a batch dim of size ``batch`` shards over, always as a
+    tuple (dropping non-dividing axes; ``()`` when the batch stays
+    whole).  Shared by the kernel dispatcher (``kernels.ops``), the
+    tuner bridge (``launch.mesh.tuner_mesh_spec``) and every cache and
+    input spec, so the tuner prices exactly what is dispatched."""
+    if not rules.enabled or mesh is None:
         return ()
-    ax = spec[0]
-    return ax if isinstance(ax, tuple) else (ax,)
+    return _divisible_axes(rules, mesh, "batch", batch)
 
 
 def feature_placement(rules: Rules, mesh: jax.sharding.Mesh,
@@ -273,8 +270,8 @@ def constrain(x: jax.Array, rules: Rules,
     """
     if rules is None or not rules.enabled:
         return x
-    mesh = _compat.current_mesh()
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     entries = [_dim_axes(rules, mesh, name, dim)
                for dim, name in zip(x.shape, logical)]

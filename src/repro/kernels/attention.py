@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.perf_model import device_spec
+
 LANES = 128
 NEG_INF = -1e30
 # Sentinel "position" for unallocated / out-of-range paged-KV slots:
@@ -120,8 +122,8 @@ def _attn_partial_kernel(q_ref, k_ref, v_ref, pos_ref, qpos_ref,
         preferred_element_type=jnp.float32) * scale  # (bq, bkv)
 
     if causal or window > 0:
-        rows = qpos_ref[...].reshape(bq, 1)  # global q positions
-        cols = pos_ref[...].reshape(1, bkv)  # global kv positions
+        rows = qpos_ref[0]                # (bq, 1) global q positions
+        cols = pos_ref[0]                 # (1, bkv) global kv positions
         mask = cols <= rows
         if window > 0:
             mask &= cols > rows - window
@@ -203,9 +205,13 @@ def fused_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
         bq -= 1
     while n % bkv:
         bkv -= 1
-    pos2d = kv_pos.astype(jnp.int32).reshape(-1, n)
-    qpos2d = q_pos.astype(jnp.int32).reshape(-1, m)
-    kvb, qb = pos2d.shape[0], qpos2d.shape[0]
+    # positions ride as (rows, 1, N) and (rows, M, 1): every block's
+    # last two dims are then (1, bkv) and (bq, 1), which Mosaic accepts
+    # for any batch (a (1, bkv) block of a (B, N) array is refused at
+    # B > 1: the second-last block dim must be 8-aligned or whole)
+    kv_pos3 = kv_pos.astype(jnp.int32).reshape(-1, 1, n)
+    q_pos3 = q_pos.astype(jnp.int32).reshape(-1, m, 1)
+    kvb, qb = kv_pos3.shape[0], q_pos3.shape[0]
     grid = (b, hq, m // bq, n // bkv)
 
     kernel = functools.partial(
@@ -221,10 +227,12 @@ def fused_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
                          lambda b_, h, i, j: (b_, h // group, j, 0)),
             pl.BlockSpec((1, 1, bkv, dv),
                          lambda b_, h, i, j: (b_, h // group, j, 0)),
-            pl.BlockSpec((1, bkv), (lambda b_, h, i, j: (b_, j)) if kvb > 1
-                         else (lambda b_, h, i, j: (0, j))),
-            pl.BlockSpec((1, bq), (lambda b_, h, i, j: (b_, i)) if qb > 1
-                         else (lambda b_, h, i, j: (0, i))),
+            pl.BlockSpec((1, 1, bkv),
+                         (lambda b_, h, i, j: (b_, 0, j)) if kvb > 1
+                         else (lambda b_, h, i, j: (0, 0, j))),
+            pl.BlockSpec((1, bq, 1),
+                         (lambda b_, h, i, j: (b_, i, 0)) if qb > 1
+                         else (lambda b_, h, i, j: (0, i, 0))),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, dv), lambda b_, h, i, j: (b_, h, i, 0)),
@@ -244,9 +252,10 @@ def fused_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
+            vmem_limit_bytes=device_spec().vmem_budget,
         ),
         interpret=interpret,
-    )(q, k, v, pos2d, qpos2d)
+    )(q, k, v, kv_pos3, q_pos3)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -303,6 +312,7 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
+            vmem_limit_bytes=device_spec().vmem_budget,
         ),
         interpret=interpret,
     )(q, k, v)

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.perf_model import device_spec
+
 
 def _chain_kernel(a_ref, b_ref, d_ref, e_ref, c_acc, e_acc, *, nn, nk,
                   n_axis, prologue=None, epilogue=None):
@@ -136,6 +138,7 @@ def fused_gemm_chain(a: jax.Array, b: jax.Array, d: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * (len(grid) - 2)
             + ("arbitrary", "arbitrary"),
+            vmem_limit_bytes=device_spec().vmem_budget,
         ),
         interpret=interpret,
     )(a, b, d)
@@ -274,6 +277,7 @@ def fused_mlp_chain(a: jax.Array, wu: jax.Array, wd: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * (len(grid) - 2)
             + ("arbitrary", "arbitrary"),
+            vmem_limit_bytes=device_spec().vmem_budget,
         ),
         interpret=interpret,
     )(a, wu, wg, wd)

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.perf_model import device_spec
+
 
 def _kernel(a_ref, b_ref, d_ref, f_ref, g_ref, c_acc, e_acc, *, nn, nk,
             prologue=None, epilogue=None):
@@ -92,6 +94,7 @@ def fused_gemm_chain3(a: jax.Array, b: jax.Array, d: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),
+            vmem_limit_bytes=device_spec().vmem_budget,
         ),
         interpret=interpret,
     )(a, b, d, f)

@@ -7,7 +7,7 @@ dry-runs, where Pallas cannot lower).  Padding for non-dividing tiles
 happens here (Rule 3 keeps the overhead < 5%).
 
 Sharded dispatch (docs/design.md §7): passing ``mesh=`` (plus optional
-``dist.sharding.Rules``) wraps the kernel in ``_compat.shard_map`` so
+``dist.sharding.Rules``) wraps the kernel in ``jax.shard_map`` so
 each shard runs the fused schedule on its local block — batch rides the
 rules' data axes, the output-feature/head dim rides tp-or-model.  The
 tuner is handed the matching ``MeshSpec``, so the tile sizes it picks
@@ -24,11 +24,11 @@ from typing import Optional
 import jax
 from jax.sharding import PartitionSpec as P
 
-from .. import _compat
 from ..core import api
 from ..core.perf_model import MeshSpec
 from ..dist import ring_dispatch
-from ..dist.sharding import Rules, default_rules, dispatch_mesh_spec
+from ..dist.sharding import (Rules, batch_placement, default_rules,
+                             dispatch_mesh_spec, feature_placement)
 from . import ref
 from .attention import fused_attention as _attn_kernel
 from .gemm_chain import _ACTS
@@ -42,16 +42,27 @@ def _backend_mode(mode: str) -> str:
     return "kernel" if jax.default_backend() == "tpu" else "ref"
 
 
-def _guarded(fingerprint: tuple, kernel_fn, ref_fn):
+def _executed(out):
+    """Wait for an eagerly dispatched kernel output, so a failure while
+    it runs on the device raises here.  Inside a trace nothing runs:
+    the output is returned as it is."""
+    if any(isinstance(x, jax.core.Tracer) for x in jax.tree.leaves(out)):
+        return out
+    return jax.block_until_ready(out)
+
+
+def guarded(fingerprint: tuple, kernel_fn, ref_fn):
     """Tiered dispatch for a fused-kernel tail (docs/reliability.md).
 
     The breaker-open check routes a quarantined fingerprint straight to
-    the XLA reference twin without retrying it; otherwise the fused
-    path runs behind the ``kernel_dispatch`` fault point, and any
-    compile/dispatch failure records the fingerprint (persisting a
-    denylist record next to the cached schedule) before degrading to
-    the twin.  The twin computes the same values — tolerances aside,
-    a degraded call is indistinguishable to the caller.
+    the XLA reference twin without retrying it.  Otherwise the fused
+    path runs behind the ``kernel_dispatch`` fault point; an injected
+    fault, or an error while the kernel executes, records the
+    fingerprint (persisting a denylist record next to the cached
+    schedule) before degrading to the twin.  Errors raised while the
+    kernel is traced, lowered or compiled propagate: a kernel the
+    compiler refuses is a bug, and degrading would hide that the fused
+    path never ran.
 
     The tail is also a sentinel seam: ``wrong_answer`` faults perturb
     the fused output here, and when shadow verification is armed
@@ -63,17 +74,24 @@ def _guarded(fingerprint: tuple, kernel_fn, ref_fn):
     from ..reliability import breaker as _breaker
     from ..reliability import faults as _faults
     from ..reliability import sentinels as _sentinels
+    op = str(fingerprint[0])
     if _breaker.is_open(fingerprint):
         return ref_fn()
     try:
-        _faults.fault_point("kernel_dispatch", op=str(fingerprint[0]))
-        out = _sentinels.corrupt_if_armed(kernel_fn(),
-                                          op=str(fingerprint[0]))
-        return _sentinels.shadow_kernel(fingerprint, out, ref_fn)
-    except Exception as e:  # noqa: BLE001 - degrade on any dispatch error
+        _faults.fault_point("kernel_dispatch", op=op)
+        out = kernel_fn()
+    except _faults.InjectedFault as e:
         _breaker.record_failure(fingerprint,
                                 reason=f"{type(e).__name__}: {e}")
         return ref_fn()
+    try:
+        out = _executed(out)
+    except jax.errors.JaxRuntimeError as e:
+        _breaker.record_failure(fingerprint,
+                                reason=f"{type(e).__name__}: {e}")
+        return ref_fn()
+    out = _sentinels.corrupt_if_armed(out, op=op)
+    return _sentinels.shadow_kernel(fingerprint, out, ref_fn)
 
 
 def gemm_chain(a: jax.Array, b: jax.Array, d: jax.Array,
@@ -103,7 +121,7 @@ def gemm_chain(a: jax.Array, b: jax.Array, d: jax.Array,
             body = _gemm_body(M, N, K, H, bsz, str(a.dtype), m, tuned,
                               interp, spec)
             bspec = baxes if baxes else None
-            return _compat.shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(bspec, None, None), P(bspec, None, None),
                           P(bspec, None, hax)),
@@ -121,15 +139,17 @@ def gemm_chain(a: jax.Array, b: jax.Array, d: jax.Array,
             return tk(a, b, d)
         return _gemm_kernel(a, b, d, interpret=interp)
 
-    return _guarded(("gemm", M, N, K, H, bsz, str(a.dtype)),
-                    _kernel, lambda: ref.gemm_chain_ref(a, b, d))
+    return guarded(("gemm", M, N, K, H, bsz, str(a.dtype)),
+                   _kernel, lambda: ref.gemm_chain_ref(a, b, d))
 
 
 def mlp_chain(x: jax.Array, w_up: jax.Array, w_down: jax.Array,
               w_gate: Optional[jax.Array] = None, act: str = "silu",
               mode: str = "auto", tuned: bool = True,
               interpret: Optional[bool] = None,
-              prologue=None, epilogue=None) -> jax.Array:
+              prologue=None, epilogue=None,
+              mesh: Optional[jax.sharding.Mesh] = None,
+              rules: Optional[Rules] = None) -> jax.Array:
     """Fused E = (act(X@Wg) * (X@Wu)) @ Wd with MCFuser-tuned schedule
     (``w_gate=None`` computes the ungated E = act(X@Wu) @ Wd).
 
@@ -144,6 +164,14 @@ def mlp_chain(x: jax.Array, w_up: jax.Array, w_down: jax.Array,
     exact XLA twin of ``models/layers.mlp_block``'s op sequence.
     ``prologue``/``epilogue`` are the tile-local FusionStitching hooks,
     forwarded to the kernel (applied whole-array in ref mode).
+
+    mesh: dispatch through shard_map, tensor-parallel — tokens over the
+    rules' data axes, d_ff over tp-or-model where it divides, each
+    shard running the kernel tuned for its slice and the partial
+    outputs summed over the axis (the epilogue then applies to the
+    sum).  A Mosaic kernel cannot be partitioned by XLA, so on a mesh
+    the kernel always runs under shard_map, replicated where nothing
+    divides.
     """
     m = _backend_mode(mode)
     gated = w_gate is not None
@@ -159,6 +187,25 @@ def mlp_chain(x: jax.Array, w_up: jax.Array, w_down: jax.Array,
 
     if m == "ref":
         return _ref()
+    if mesh is not None:
+        rules = rules if rules is not None else default_rules(mesh)
+        baxes = batch_placement(rules, mesh, x.shape[0])
+        ax = feature_placement(rules, mesh, w_up.shape[-1], taken=baxes)
+        bspec = baxes or None
+
+        def body(xl, wul, wdl, *wgl):
+            part = mlp_chain(xl, wul, wdl, w_gate=wgl[0] if wgl else None,
+                             act=act, mode=m, tuned=tuned,
+                             interpret=interpret, prologue=prologue)
+            return part if ax is None else jax.lax.psum(part, ax)
+
+        ws = P(None, ax)
+        out = jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(P(bspec, None), ws, P(ax, None)) + ((ws,) * gated),
+            out_specs=P(bspec, None), check_vma=False)(
+                x, w_up, w_down, *((w_gate,) * gated))
+        return out if epilogue is None else epilogue(out)
     M, K = x.shape
     N, H = w_up.shape[-1], w_down.shape[-1]
     interp = (m == "interpret") if interpret is None else interpret
@@ -176,8 +223,8 @@ def mlp_chain(x: jax.Array, w_up: jax.Array, w_down: jax.Array,
             prologue=prologue, epilogue=epilogue, interpret=interp, **kw)
         return out[0]
 
-    return _guarded(("mlp", M, N, H, str(x.dtype), gated, act),
-                    _kernel, _ref)
+    return guarded(("mlp", M, N, H, str(x.dtype), gated, act),
+                   _kernel, _ref)
 
 
 def _gemm_body(M, N, K, H, batch, dtype, m, tuned, interp,
@@ -255,7 +302,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
                               window, scale, m, tuned, interp, spec)
             bspec = baxes if baxes else None
             qs = P(bspec, hax, None, None)
-            return _compat.shard_map(
+            return jax.shard_map(
                 body, mesh=mesh, in_specs=(qs, qs, qs), out_specs=qs,
                 check_vma=False)(q, k, v)
 
@@ -273,7 +320,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return _attn_kernel(q, k, v, causal=causal, window=window,
                             scale=scale, interpret=interp)
 
-    return _guarded(
+    return guarded(
         ("attn", M, N, D, Dv, hq, b, str(q.dtype), causal, window),
         _kernel,
         lambda: ref.gqa_attention_ref(q, k, v, causal=causal,

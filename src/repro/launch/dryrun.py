@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell:
@@ -17,9 +14,13 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-8b \
         --shape train_4k --mesh single          # one cell
     PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
+
+``main`` forces 512 host devices through ``XLA_FLAGS`` before JAX first
+touches a backend; importing this module changes nothing.
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -199,6 +200,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main() -> None:
+    # must precede the first backend use: jax fixes the device count then
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ALIASES) + ARCHS)
     ap.add_argument("--shape", choices=sorted(SHAPES))

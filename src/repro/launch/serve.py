@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import math
 import time
 
@@ -39,6 +40,7 @@ from ..configs import ALIASES, ARCHS, get_config
 from ..dist.sharding import Rules
 from ..models.lm import Runtime
 from . import steps as S
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh
 
 
@@ -67,18 +69,32 @@ def generate(model, params, prompts: jax.Array, gen: int,
     return np.stack([np.asarray(t) for t in out], axis=1)
 
 
-def sharded_runtime(shard_model: int):
+def cut_layers(cfg, n_layers: int):
+    """``cfg`` with its depth cut to ``n_layers``: every width stays
+    published, only the stack is shorter.  The cut must hold whole
+    periods of the layer pattern."""
+    period = len(cfg.pattern)
+    if not 0 < n_layers <= cfg.n_layers or n_layers % period:
+        raise ValueError(f"--layers {n_layers}: need whole periods of "
+                         f"{period} layer(s), at most {cfg.n_layers}")
+    return dataclasses.replace(cfg, name=f"{cfg.name}-{n_layers}L",
+                               n_layers=n_layers)
+
+
+def sharded_runtime(shard_model: int, **runtime):
     """(mesh, rules, Runtime) for ``--shard-model N`` serving: N == 1
     is the plain single-device runtime; N > 1 builds the host mesh and
     the decode regime (resident TP weight shards, distributed
-    partial-softmax decode over the seq-sharded KV cache)."""
+    partial-softmax decode over the seq-sharded KV cache).
+    ``runtime`` sets further ``Runtime`` fields (``kernel_ops=True,
+    planner=True`` for the fused path)."""
     if shard_model <= 1:
-        return None, None, Runtime(remat=False)
+        return None, None, Runtime(remat=False, **runtime)
     mesh = make_host_mesh(model_axis=shard_model)
     rules = Rules(data=("data",), model="model", tp="model",
                   fsdp=False)   # decode regime: resident TP weights
     return mesh, rules, Runtime(rules=rules, mesh=mesh, remat=False,
-                                dist_decode_attn=True)
+                                dist_decode_attn=True, **runtime)
 
 
 def demo_side_inputs(cfg, batch: int) -> tuple[dict, int]:
@@ -171,10 +187,17 @@ def make_engine(model, params, *, batch: int, prompt_len: int, gen: int,
     """A ``ServingEngine`` sized for ``batch`` concurrent requests of
     up to ``prompt_len + gen`` positions, with ~25% page slack so
     admission (prompt pages + one decode page of headroom) stays
-    fluid without making preemption unreachable."""
+    fluid without making preemption unreachable.  On a mesh the page
+    count per sequence rounds up to a multiple of the model axis, so
+    the paged-ring regimes, which split whole table columns, are
+    offered to the tuner."""
     from ..serving import ServingEngine
 
     max_pages = math.ceil((prompt_len + gen) / page_size)
+    rt = model.rt
+    if rt.mesh is not None and rt.rules.model:
+        n_model = rt.mesh.shape[rt.rules.model]
+        max_pages = math.ceil(max_pages / n_model) * n_model
     n_pages = 1 + batch * (max_pages + 1) + max(1, batch * max_pages // 4)
     return ServingEngine(model, params, max_batch=batch,
                          page_size=page_size, n_pages=n_pages,
@@ -211,6 +234,9 @@ def main(argv=None):
     ap.add_argument("--arch", default="qwen3-8b",
                     choices=sorted(ALIASES) + ARCHS)
     ap.add_argument("--full", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to N layers (whole periods of "
+                         "the layer pattern); widths stay as configured")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
@@ -228,7 +254,11 @@ def main(argv=None):
                     help="KV page size for --continuous")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=not args.full)
+    if args.layers:
+        cfg = cut_layers(cfg, args.layers)
+        print(f"depth cut: {cfg.name} runs {cfg.n_layers} layers")
     mesh, rules, rt = sharded_runtime(args.shard_model)
 
     if args.continuous:

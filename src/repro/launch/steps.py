@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs import ShapeCell
-from ..dist.sharding import Rules
+from ..dist.sharding import Rules, batch_placement
 from ..models.config import ModelConfig
 from ..models.lm import LM, Runtime
 from ..models.whisper import EncDec
@@ -72,7 +72,6 @@ def make_compressed_train_step(model, opt: AdamW,
     opt_state, residuals, info)`` — one extra state leaf versus
     ``make_train_step``.  Params must be replicated across ``axis``
     (model-parallel sharding inside the body is not supported)."""
-    from .._compat import shard_map
     from ..dist import compression
     n = mesh.shape[axis]
 
@@ -91,7 +90,7 @@ def make_compressed_train_step(model, opt: AdamW,
         loss = jax.lax.psum(loss, axis) / n
         return loss, grads, new_res
 
-    reduce_grads = shard_map(
+    reduce_grads = jax.shard_map(
         _body, mesh=mesh,
         in_specs=(P(), P(axis), P(axis)),
         out_specs=(P(), P(), P(axis)),
@@ -177,8 +176,7 @@ def batch_specs(cfg: ModelConfig, shape: ShapeCell, rules: Rules,
                 mesh: jax.sharding.Mesh) -> dict:
     """PartitionSpecs matching input_specs."""
     b = shape.batch
-    lead = rules.batch_spec(b, mesh)
-    blead = lead[0] if len(lead) else None
+    blead = batch_placement(rules, mesh, b) or None
     specs = {}
     for key in input_specs(cfg, shape):
         if key == "pos":

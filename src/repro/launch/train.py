@@ -24,6 +24,7 @@ from ..dist.sharding import Rules
 from ..models.lm import Runtime
 from ..runtime.fault_tolerance import StepRunner
 from . import steps as S
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh
 
 
@@ -48,6 +49,7 @@ def main(argv=None) -> dict:
                          "cross-pod DCI saver; needs --model-axis 1")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=not args.full)
     mesh = make_host_mesh(model_axis=args.model_axis)
     n_data = mesh.shape["data"]

@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..dist.sharding import Rules, constrain
+from ..dist.sharding import (Rules, batch_placement, constrain,
+                             dispatch_mesh_spec)
 from .config import ModelConfig
 
 # ---------------------------------------------------------------------------
@@ -297,8 +298,7 @@ def attention_block(p: dict, x: jax.Array, cfg: ModelConfig, rules: Rules,
             # (measured 3-4x regressions on codeqwen/olmoe)
             # distributed flash-decode: cache write + partial-softmax
             # attention fused in one shard_map (SS Perf hillclimb #1)
-            baxes = (rules.batch_spec(b, mesh)[0]
-                     if rules.batch_spec(b, mesh) else None)
+            baxes = batch_placement(rules, mesh, b)
             o, knew, vnew, posnew = distributed_decode_attention(
                 q, cache["k"], cache["v"], k, v, positions[0] % nc,
                 positions, cache["pos"], causal=causal, window=win,
@@ -584,8 +584,7 @@ def _paged_attention_body(qt: jax.Array, cache: dict,
     if (dist_decode and rules.enabled and mesh is not None and rules.model
             and s == 1 and nm > 1 and mp % nm == 0):
         from ..dist.ring_dispatch import paged_ring_decode_attention
-        bspec = rules.batch_spec(b, mesh)
-        baxes = bspec[0] if len(bspec) else None
+        baxes = batch_placement(rules, mesh, b)
         return paged_ring_decode_attention(
             qt, cache["k_pages"], cache["v_pages"], page_table,
             positions[:, 0], window=win, scale=scale, rules=rules,
@@ -595,31 +594,37 @@ def _paged_attention_body(qt: jax.Array, cache: dict,
         # lengths-M..lengths-1, which padded prefill rows violate.
         # ``block`` carries the regime search's winning tiles, so the
         # executed schedule is the one the model priced.  Dispatch is
-        # guarded: a quarantined or failing fused paged kernel degrades
-        # to the bit-identical XLA gather twin below
-        # (docs/reliability.md).
-        from ..reliability import breaker as _breaker
-        from ..reliability import faults as _faults
-        from ..reliability import sentinels as _sentinels
+        # guarded like every kernel tail (``kernels.ops.guarded``): a
+        # quarantined fingerprint or an injected fault degrades to the
+        # bit-identical XLA gather twin below (docs/reliability.md),
+        # while a kernel the compiler refuses raises.
+        # On a mesh the kernel runs under shard_map (XLA cannot
+        # partition a Mosaic kernel): batch over the data axes, heads
+        # over tp-or-model where they divide — the placement the
+        # paged-spatial regime was priced under.
+        from ..kernels import ops as kernel_ops_mod
+        from ..kernels.attention import fused_attention_paged
         bq, bkv = block if block is not None else (128, 128)
         fp = ("attn-paged", b, qt.shape[1], ps, mp, win, bq, bkv,
               str(qt.dtype))
-        if not _breaker.is_open(fp):
-            try:
-                _faults.fault_point("kernel_dispatch", op="attn-paged")
-                from ..kernels.attention import fused_attention_paged
-                out = fused_attention_paged(
-                    qt, cache["k_pages"], cache["v_pages"], page_table,
-                    positions[:, -1] + 1, bq=bq, bkv=bkv, window=win,
-                    scale=scale)
-                # sentinel seam: wrong_answer corruption + sampled
-                # shadow verification against the gather twin
-                # (no-ops while tracing or with sentinels disarmed)
-                out = _sentinels.corrupt_if_armed(out, op="attn-paged")
-                return _sentinels.shadow_kernel(fp, out, _twin)
-            except Exception as e:  # noqa: BLE001 - degrade to twin
-                _breaker.record_failure(
-                    fp, reason=f"{type(e).__name__}: {e}")
+        kernel = functools.partial(fused_attention_paged, bq=bq, bkv=bkv,
+                                   window=win, scale=scale)
+        if rules.enabled and mesh is not None:
+            _, baxes, hax = dispatch_mesh_spec(
+                rules, mesh, kind="attention", batch=b,
+                feature_dims=(cache["k_pages"].shape[1], qt.shape[1]))
+            bspec = baxes or None
+            qs = P(bspec, hax, None, None)
+            kernel = jax.shard_map(
+                kernel, mesh=mesh,
+                in_specs=(qs, P(None, hax, None, None),
+                          P(None, hax, None, None), P(bspec, None),
+                          P(bspec)),
+                out_specs=qs, check_vma=False)
+        return kernel_ops_mod.guarded(
+            fp, lambda: kernel(qt, cache["k_pages"], cache["v_pages"],
+                               page_table, positions[:, -1] + 1),
+            _twin)
     return _twin()
 
 
@@ -761,9 +766,12 @@ def run_planned_layer(lp, p: dict, x: jax.Array, cfg: ModelConfig,
                 # the weights the way the XLA twin's matmul would
                 wu, wd = wu.astype(x2d.dtype), wd.astype(x2d.dtype)
                 wg = wg if wg is None else wg.astype(x2d.dtype)
+            sharded = rules.enabled and rt.mesh is not None
             o2d = kernel_ops_mod.mlp_chain(
                 x2d, wu, wd, w_gate=wg,
-                act="silu" if cfg.act == "swiglu" else "gelu")
+                act="silu" if cfg.act == "swiglu" else "gelu",
+                mesh=rt.mesh if sharded else None,
+                rules=rules if sharded else None)
             out = constrain(o2d.reshape(b, s, d), rules,
                             "batch", None, None)
             nm = mlp_unit.ops[-1]

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..dist.sharding import Rules, constrain
+from ..dist.sharding import Rules, batch_placement, constrain
 from . import layers as L
 from .config import ModelConfig
 
@@ -465,8 +465,7 @@ class LM:
 
         def layer_spec(kind, stacked: bool):
             lead = (None,) if stacked else ()
-            bspec = rules.batch_spec(batch_size, mesh)
-            b = bspec[0] if len(bspec) else None
+            b = batch_placement(rules, mesh, batch_size) or None
             if kind == "attn":
                 # shard kv heads over model when divisible, else seq
                 n_model = mesh.shape[rules.model] if mesh else 1

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..dist.sharding import Rules, constrain
+from ..dist.sharding import Rules, batch_placement, constrain
 from . import layers as L
 from .config import ModelConfig
 from .lm import Runtime
@@ -216,8 +216,7 @@ class EncDec:
 
     def cache_specs(self, batch_size: int) -> dict:
         cfg, rules, mesh = self.cfg, self.rt.rules, self.rt.mesh
-        bspec = rules.batch_spec(batch_size, mesh)
-        b = bspec[0] if len(bspec) else None
+        b = batch_placement(rules, mesh, batch_size) or None
         kv = P(None, b, None, rules.model, None)  # kv=12 < 16: shard seq
         # cross KV covers 1500 frames (not 16-divisible): batch-shard only
         ckv = P(None, b, None, None, None)

@@ -99,8 +99,11 @@ class CircuitBreaker:
                 self._open.add(k)
         return hit
 
-    def failures(self, key) -> int:
+    def failures(self, key=None) -> int:
+        """Failures recorded against ``key``; every key's when None."""
         with self._lock:
+            if key is None:
+                return sum(self._failures.values())
             return self._failures.get(self._norm(key), 0)
 
     def reset(self) -> None:
@@ -124,7 +127,7 @@ def is_open(key, hw=None) -> bool:
     return BREAKER.is_open(key, hw)
 
 
-def failures(key) -> int:
+def failures(key=None) -> int:
     return BREAKER.failures(key)
 
 

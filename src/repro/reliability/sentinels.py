@@ -306,7 +306,7 @@ def shadow_kernel(fingerprint: tuple, out, ref_fn: Callable[[], object],
                   *, bitwise_f32: bool = False):
     """Sampled shadow verification for a guarded fused dispatch.
 
-    Called by the kernel tails (``kernels/ops.py::_guarded``) and the
+    Called by the kernel tails (``kernels/ops.py::guarded``) and the
     fused paged-attention branch (``models/layers.py``) with the fused
     output and a thunk for the XLA twin.  Early-outs: sentinels not
     armed, tracing (a ``jax.core.Tracer`` has no concrete value to

@@ -30,13 +30,15 @@ persistent-cached) and enables the kv-sharded ring path — with the
 per-hop ppermute combine when the pipelined variant wins — only when
 the model ranks it fastest.
 
-Degradation (docs/reliability.md): the engine never dies on a bad
-fused unit.  Execution runs through a **tiered fallback chain** —
-tier 0 is the configured model (planner/kernel paths as built), tier 1
-its XLA twin (planner, kernel_ops and the ring decode disabled),
-tier 2 the same twin executed eagerly (no jit) — demoting stickily on
-a dispatch failure and quarantining the failing plan fingerprint
-through the circuit breaker so relaunches skip it.  Requests carry an
+Degradation (docs/reliability.md): the engine never dies on a fused
+unit that fails at dispatch.  Execution runs through a **tiered
+fallback chain** — tier 0 is the configured model (planner/kernel
+paths as built), tier 1 its XLA twin (planner, kernel_ops and the ring
+decode disabled), tier 2 the same twin executed eagerly (no jit) —
+demoting stickily on a dispatch failure and quarantining the failing
+plan fingerprint through the circuit breaker so relaunches skip it.
+Compiling a step program is not a dispatch: a program the compiler
+refuses raises.  Requests carry an
 optional per-request **deadline** (evicted honestly past it), a
 preemption **retry budget** bounds recompute livelock, a soft
 **watchdog** times every step, and ``drain()`` replaces the
@@ -205,6 +207,11 @@ class ServingEngine:
         self.model = model
         self._window = int(model.cfg.window or 0)
         self._shadow_fns = None      # lazily jitted tier-1 twin pair
+        # compiled step programs of the jitted tiers, keyed by
+        # (tier, phase, input avals and shardings), and the seconds each
+        # took to lower and compile (``_program``)
+        self.programs: dict[tuple, object] = {}
+        self.compile_s: dict[tuple, float] = {}
         self.cache = model.init_paged_cache(n_pages, page_size)
         self._build_exec()
         if model.rt.planner:
@@ -257,6 +264,28 @@ class ServingEngine:
             # last resort runs eagerly: no jit pipeline to fail
             self._decode = m.decode_step_paged
             self._prefill = m.prefill_paged
+
+    def _program(self, phase: str, args):
+        """The step program that runs ``args`` at the current tier.
+
+        Jitted tiers are lowered and compiled on the first sight of each
+        input signature, outside the fallback chain: a step program the
+        compiler refuses raises instead of demoting the engine, since a
+        refused kernel is a bug and not a transient fault.  A step that
+        is not jitted (the eager last tier) runs as it is."""
+        fn = self._decode if phase == "decode" else self._prefill
+        if not hasattr(fn, "lower"):
+            return fn
+        key = (self.exec_tier, phase) + tuple(
+            (x.shape, x.dtype, getattr(x, "sharding", None))
+            for x in jax.tree.leaves(args[1:]))
+        prog = self.programs.get(key)
+        if prog is None:
+            t0 = time.perf_counter()
+            prog = fn.lower(*args).compile()
+            self.compile_s[key] = time.perf_counter() - t0
+            self.programs[key] = prog
+        return prog
 
     def _note_tier_failure(self, phase: str, reason: str) -> None:
         """Quarantine what tier 0 was executing before demoting, so a
@@ -346,8 +375,9 @@ class ServingEngine:
         table = jnp.asarray(KP.table_array([None] * self.max_batch,
                                            self.max_pages))
         args = (self.params, self.cache, tokens, positions, table)
+        prog = self._program("decode", args)
         try:
-            out = self._decode(*args)
+            out = prog(*args)
             out = _sentinels.corrupt_if_armed(out, op="engine-golden")
             ref = self._shadow_exec("decode", args)
             ok = _sentinels.outputs_equal(out, ref)
@@ -369,16 +399,17 @@ class ServingEngine:
         dispatch is retried at the next tier with the SAME inputs —
         degradation changes which program computes the step, never
         which step is computed, which is what keeps chaos-run tokens
-        bit-identical (tests/test_reliability.py)."""
+        bit-identical (tests/test_reliability.py).  Compiling is not
+        part of the chain (``_program``)."""
         while True:
+            prog = self._program(phase, args)
             try:
                 if self.exec_tier == 0:
                     _faults.fault_point("kernel_dispatch",
                                         op=f"engine-{phase}")
                 _faults.fault_point("engine_step", op=phase,
                                     tier=self.exec_tier)
-                fn = self._decode if phase == "decode" else self._prefill
-                out = fn(*args)
+                out = prog(*args)
                 if self.exec_tier == 0:
                     out = self._sentinel_check(phase, args, out)
                 return out

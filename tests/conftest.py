@@ -2,16 +2,9 @@
 
 * Puts ``src/`` on sys.path so ``PYTHONPATH=src`` is not required when
   pytest is invoked from the repo root.
-* Installs a minimal ``hypothesis`` stand-in when the real library is
-  not available (the container pins the jax toolchain and nothing
-  else).  The stub runs each property test over a deterministic sample
-  of ``max_examples`` draws — strictly weaker than hypothesis (no
-  shrinking, no coverage-guided search) but it keeps the properties
-  exercised instead of skipped.  Installing the real ``hypothesis``
-  makes the stub dormant.
+* Points the persistent schedule cache at a throwaway directory.
 """
 import atexit
-import importlib.util
 import os
 import shutil
 import sys
@@ -30,80 +23,3 @@ if os.path.isdir(_SRC) and os.path.abspath(_SRC) not in \
 _SCHED_TMP = tempfile.mkdtemp(prefix="repro-sched-test-")
 os.environ["REPRO_CACHE_DIR"] = _SCHED_TMP
 atexit.register(shutil.rmtree, _SCHED_TMP, True)
-
-
-def _install_hypothesis_stub() -> None:
-    if importlib.util.find_spec("hypothesis") is not None:
-        return
-
-    import functools
-    import inspect
-    import random
-    import types
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw
-
-    def integers(min_value, max_value):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def sampled_from(elements):
-        elements = list(elements)
-        return _Strategy(lambda rng: rng.choice(elements))
-
-    def booleans():
-        return _Strategy(lambda rng: rng.random() < 0.5)
-
-    def floats(min_value=0.0, max_value=1.0, **_kw):
-        return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-    def settings(**kw):
-        def deco(fn):
-            fn._stub_settings = dict(kw)
-            return fn
-        return deco
-
-    def given(*arg_strategies, **kw_strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                cfg = getattr(wrapper, "_stub_settings", None) \
-                    or getattr(fn, "_stub_settings", {})
-                n = cfg.get("max_examples", 10)
-                rng = random.Random(0)
-                for _ in range(n):
-                    pos = [s.draw(rng) for s in arg_strategies]
-                    kws = {k: s.draw(rng)
-                           for k, s in kw_strategies.items()}
-                    fn(*args, *pos, **kwargs, **kws)
-
-            # pytest resolves fixtures from the *visible* signature;
-            # hide the strategy-filled params (and the __wrapped__
-            # attribute, which signature() would otherwise follow).
-            del wrapper.__wrapped__
-            sig = inspect.signature(fn)
-            params = list(sig.parameters.values())
-            params = params[len(arg_strategies):]
-            params = [p for p in params if p.name not in kw_strategies]
-            wrapper.__signature__ = sig.replace(parameters=params)
-            return wrapper
-        return deco
-
-    hyp = types.ModuleType("hypothesis")
-    st = types.ModuleType("hypothesis.strategies")
-    st.integers = integers
-    st.sampled_from = sampled_from
-    st.booleans = booleans
-    st.floats = floats
-    hyp.given = given
-    hyp.settings = settings
-    hyp.strategies = st
-    hyp.HealthCheck = types.SimpleNamespace(too_slow="too_slow",
-                                            data_too_large="data_too_large")
-    hyp.assume = lambda cond: None
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st
-
-
-_install_hypothesis_stub()

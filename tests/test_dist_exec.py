@@ -127,6 +127,17 @@ with jax.set_mesh(mesh):
 o_one = ops.attention(q, k, v, causal=True, mode="interpret")
 out["attn_maxerr"] = float(jnp.max(jnp.abs(o_sh - o_one)))
 
+# --- tensor-parallel fused MLP chain vs single-device fused kernel ------
+x = jax.random.normal(kx[0], (64, 128), jnp.float32)
+wg_, wu_ = (jax.random.normal(kx[i], (128, 512), jnp.float32) * 0.1
+            for i in (1, 2))
+wd_ = jax.random.normal(kx[3], (512, 128), jnp.float32) * 0.1
+with jax.set_mesh(mesh):
+    y_sh = ops.mlp_chain(x, wu_, wd_, w_gate=wg_, mode="interpret",
+                         mesh=mesh, rules=rules)
+y_one = ops.mlp_chain(x, wu_, wd_, w_gate=wg_, mode="interpret")
+out["mlp_maxerr"] = float(jnp.max(jnp.abs(y_sh - y_one)))
+
 # --- Runtime(kernel_ops=True) under the ambient mesh --------------------
 from repro.configs import get_config
 from repro.launch import steps as S_
@@ -164,6 +175,7 @@ def test_sharded_kernel_dispatch_matches_single_device(tmp_path):
     out = json.loads(line[-1][len("RESULT "):])
     assert out["gemm_maxerr"] < 1e-3, out
     assert out["attn_maxerr"] < 1e-3, out
+    assert out["mlp_maxerr"] < 1e-3, out
     # the dispatched schedule is the per-shard one, not the global one
     assert out["mesh_bh"] <= out["local_h"], out
     # the model wiring (Runtime(kernel_ops=True)) agrees with the twin

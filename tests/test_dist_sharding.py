@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.sharding import Rules, constrain
+from repro.dist.sharding import Rules, batch_placement, constrain
 
 
 class FakeMesh:
@@ -104,9 +104,15 @@ def test_batch_spec_batch_axes_override_drops_from_right():
 
 
 def test_batch_spec_indexing_contract():
-    # callers do `lead[0] if len(lead) else None`
-    lead = RULES.batch_spec(4, FakeMesh(data=2, model=4))
-    assert len(lead) == 1 and lead[0] == ("data",)
+    # callers take the batch axes as a tuple from batch_placement; the
+    # spec names the same axes (jax may normalise ("data",) to "data")
+    mesh = FakeMesh(data=2, model=4)
+    axes = batch_placement(RULES, mesh, 4)
+    assert axes == ("data",)
+    lead = RULES.batch_spec(4, mesh)
+    assert len(lead) == 1 and lead == P(axes)
+    assert batch_placement(RULES, mesh, 3) == ()
+    assert batch_placement(RULES, None, 4) == ()
 
 
 # ---------------------------------------------------------------------------

@@ -42,7 +42,7 @@ CFG = get_config("qwen3_8b", smoke=True)
 # allocator invariants
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(st.integers(2, 40), st.integers(0, 2 ** 31))
 def test_page_pool_invariants(n_pages, seed):
     """Random alloc/free churn: the scratch page is never handed out,
@@ -120,7 +120,7 @@ def _paged_setup(rng, b, hkv, d, ps, mp, n_pool, lengths):
     return pool_k, pool_v, jnp.asarray(table), dense_k, dense_v
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(st.integers(0, 2 ** 30), st.integers(0, 1), st.integers(0, 2))
 def test_paged_kernel_bit_identical_ragged(seed, m_choice, win_choice):
     """fused_attention_paged == the dense-layout partial kernel,

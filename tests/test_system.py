@@ -79,3 +79,40 @@ def test_mcfuser_attention_drives_model_numerics():
     ln = m2.forward(params, toks)
     np.testing.assert_allclose(np.asarray(lf), np.asarray(ln),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.fixture
+def restore_cache_dir():
+    old = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", old)
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_path(monkeypatch, tmp_path, restore_cache_dir,
+                            env_set):
+    """The persistent compilation cache stays where
+    JAX_COMPILATION_CACHE_DIR says; unset, it goes to a fixed directory
+    of the checkout, never to a temp name."""
+    from repro.launch.compile_cache import CHECKOUT, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(CHECKOUT / ".cache" / "jax")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert (CHECKOUT / "src" / "repro").is_dir()
+
+
+def test_cut_layers_keeps_widths():
+    from repro.launch.serve import cut_layers
+    full = get_config("qwen3_8b")
+    cut = cut_layers(full, 4)
+    assert cut.n_layers == 4 and cut.d_model == full.d_model
+    assert cut.d_ff == full.d_ff and cut.vocab == full.vocab
+    with pytest.raises(ValueError):
+        cut_layers(full, 0)

@@ -36,7 +36,9 @@ CHECKED_ROOT_FILES = ("README.md", "ROADMAP.md")
 
 _MD_LINK = re.compile(r"\[[^\]]*\]\(([^)\s]+)\)")
 _DOC_PATH = re.compile(r"\bdocs/[\w.\-/]+\.md\b")
-_BARE_CITE = re.compile(r"\b[A-Z][A-Z_]*\.md\b")
+# a root-level doc named bare (``PAPER.md``); the same name ending a
+# path (``a/b/NOTES.md``) is not a root citation
+_BARE_CITE = re.compile(r"(?<![\w/.-])[A-Z][A-Z_]*\.md\b")
 _MODULE_CITE = re.compile(
     r"\b((?:src/)?(?:repro/)?"
     r"(?:core|kernels|models|dist|launch|serving|reliability|configs|"
