@@ -1,0 +1,22 @@
+"""Share of the roofline bound reached by decode attention over the
+paged cache: the needed work (K/V of the positions below each active
+slot's length, q and o, 4 ctx heads head_dim flops; ``costs``) over the
+device time of every op under ``jit(fused_attention_paged)`` in the
+decode program, the page gather included."""
+from harness import costs, peaks, reading
+
+
+def read(rec):
+    if rec.trace is None:
+        return None
+    t = reading.kernel_time(rec, reading.PAGED_ATTENTION, reading.DECODE)
+    steps = [s for s in reading.traced_steps(rec) if s.ctx]
+    if t <= 0 or not steps:
+        return None
+    shape = rec.cell.shape
+    flops = nbytes = 0.0
+    for s in steps:
+        f, b = costs.paged_attention(shape, s.ctx)
+        flops, nbytes = flops + f, nbytes + b
+    share, bound = peaks.roofline_share(flops, nbytes, t, rec.peaks)
+    return share, f"{bound}-bound"

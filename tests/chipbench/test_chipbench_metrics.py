@@ -1,0 +1,150 @@
+"""Metric arithmetic against values worked out by hand."""
+import types
+
+import pytest
+
+from chipbench_helpers import tiny_cell
+
+from harness import costs, peaks, serve_loop, spec, trace_reduce
+
+Req, Step = serve_loop.Req, serve_loop.Step
+
+
+def read(name, rec):
+    v = spec.metric_reader(name)(rec)
+    return v[0] if isinstance(v, tuple) else v
+
+
+def record(requests, steps=(), trace=None, traced=(), chips=1):
+    rec = serve_loop.Record(
+        cell=tiny_cell(), seed=0, chips=chips, t_start=10.0, t_end=20.0,
+        setup_s=3.5, requests=list(requests), steps=list(steps),
+        counters_start={}, counters_end={}, compiles_in_window=0,
+        trace=trace, traced_steps=traced)
+    rec.peaks = peaks.Peaks(bf16_flops=100.0, hbm_bytes_s=10.0,
+                            source="test")
+    return rec
+
+
+def _req(due, times, admit_step=-1, i=0):
+    r = Req(i, due, 16, len(times))
+    r.times, r.admit_step = list(times), admit_step
+    return r
+
+
+@pytest.fixture
+def rec():
+    steps = [Step(11.0, 11.5, [16], []), Step(19.5, 20.5, [16], [])]
+    reqs = [
+        _req(9.0, [9.5, 10.5, 11.0], 0, 0),     # due before the window
+        _req(11.0, [12.0, 12.5, 13.5], 0, 1),   # ttft 1.0
+        _req(12.0, [15.0, 21.0], 1, 2),         # ttft 3.0, last gap ends late
+        _req(18.0, [], -1, 3),                  # no token: ttft 20 - 18 = 2
+    ]
+    return record(reqs, steps)
+
+
+def test_output_tokens_over_the_window(rec):
+    # tokens at 10.5, 11.0, 12.0, 12.5, 13.5, 15.0 -> 6 in 10 s
+    assert read("output_tok_s", rec) == pytest.approx(0.6)
+
+
+def test_ttft_p95_over_every_due_request(rec):
+    # due in window: ttft 1.0, 3.0, 2.0 -> p95 linear = 2 + 0.9 * 1
+    assert read("ttft_p95_ms", rec) == pytest.approx(2900.0)
+
+
+def test_itl_over_gaps_ending_in_the_window(rec):
+    # gaps ending inside: 1.0 (at 10.5), 0.5 (11.0), 0.5 (12.5), 1.0
+    # (13.5); 15.0 -> 21.0 ends after the window
+    assert read("itl_p50_ms", rec) == pytest.approx(750.0)
+
+
+def test_queue_wait_to_the_admitting_step(rec):
+    # 11.0 - 11.0 = 0, 19.5 - 12.0 = 7.5, not admitted 20 - 18 = 2
+    assert read("queue_wait_p95_ms", rec) == pytest.approx(
+        1e3 * (2.0 + 0.9 * 5.5))
+
+
+def test_setup_is_the_recorded_seconds(rec):
+    assert read("setup_s", rec) == 3.5
+
+
+def _trace():
+    """One decode program run [0, 4) holding an attention kernel [0, 2)
+    and an MLP kernel [2, 3); one prefill [5, 6) with an MLP kernel
+    [5, 5.5); steps span [0, 4.5) and [4.5, 8)."""
+    Op = trace_reduce.Op
+    ops = [
+        Op(0, 0.0, 4.0, 1.0, "while.1", "", "jit_decode_step_paged", ""),
+        Op(0, 0.0, 2.0, 2.0, "fused_attention_partial.1", "",
+           "jit_decode_step_paged", "jit(fused_attention_paged)/x"),
+        Op(0, 2.0, 3.0, 1.0, "fused_mlp_chain.1", "",
+           "jit_decode_step_paged", "jit(fused_mlp_chain)/y"),
+        Op(0, 5.0, 6.0, 0.5, "fusion.2", "", "jit_prefill_paged", ""),
+        Op(0, 5.0, 5.5, 0.5, "fused_mlp_chain.2", "",
+           "jit_prefill_paged", "jit(fused_mlp_chain)/y"),
+    ]
+    mods = [(0, 0.0, 4.0, "jit_decode_step_paged"),
+            (0, 5.0, 6.0, "jit_prefill_paged")]
+    spans = [trace_reduce.Span(0.0, 4.5, "bench.step"),
+             trace_reduce.Span(4.5, 8.0, "bench.step")]
+    return trace_reduce.Reduced([0], mods, ops, spans, (0.0, 8.0))
+
+
+def test_device_metrics_from_a_trace():
+    shape = costs.Shape(layers=2, d_model=8, heads=2, kv_heads=1,
+                        head_dim=4, d_ff=16, vocab=32)
+    steps = [Step(0.0, 4.5, [], [3, 5]), Step(4.5, 8.0, [10], [])]
+    rec = record([], steps, _trace(), (0, 2))
+    rec.cell = types.SimpleNamespace(shape=shape)
+    # busy 4 + 1 of 8 s
+    assert read("device_idle_share", rec) == pytest.approx(37.5)
+    # idle inside steps: 0.5 + 2.5 over 2 steps
+    assert read("sched_idle_ms_per_step", rec) == pytest.approx(1500.0)
+    assert read("decode_step_ms", rec) == pytest.approx(4000.0)
+    assert read("prefill_ms_per_ktok", rec) == pytest.approx(1e6 * 1.0 / 10)
+    # attention: ctx 3 and 5 -> 8 positions, 2 layers
+    flops = 4 * 8 * 2 * 4 * 2
+    nbytes = (2 * 8 * 1 * 4 * 2 + 2 * 2 * 2 * 4 * 2) * 2
+    want = 100 * max(flops / 100.0, nbytes / 10.0) / 2.0
+    assert read("paged_attn_roofline", rec) == pytest.approx(want)
+    # mlp: decode rows 2, prefill rows 10, over 1.5 s of kernel time
+    f1, b1 = costs.mlp_chain(shape, 2)
+    f2, b2 = costs.mlp_chain(shape, 10)
+    want = 100 * max((f1 + f2) / 100.0, (b1 + b2) / 10.0) / 1.5
+    assert read("mlp_chain_roofline", rec) == pytest.approx(want)
+    flops = (costs.decode_model_flops(shape, [3, 5])
+             + costs.prefill_model_flops(shape, 10))
+    assert read("step_mfu", rec) == pytest.approx(100 * flops / (5.0 * 100))
+
+
+def test_costs_by_hand():
+    s = costs.Shape(layers=1, d_model=4, heads=2, kv_heads=1, head_dim=2,
+                    d_ff=8, vocab=10)
+    assert s.layer_matmul_params == 4 * 4 + 2 * 4 * 2 + 4 * 4 + 3 * 4 * 8
+    assert costs.paged_attention(s, [1, 3]) == (4 * 4 * 2 * 2,
+                                                (2 * 4 * 2 * 2)
+                                                + 2 * 2 * 4 * 2)
+    assert costs.prefill_model_flops(s, 2) == (
+        2 * 2 * s.layer_matmul_params + 4 * 3 * 2 * 2 + 2 * 4 * 10)
+
+
+def test_roofline_names_its_bound_and_refuses_zero_time():
+    pk = peaks.Peaks(100.0, 10.0, "t")
+    assert peaks.roofline_share(100.0, 1.0, 2.0, pk) == (50.0, "compute")
+    assert peaks.roofline_share(1.0, 10.0, 4.0, pk) == (25.0, "memory")
+    with pytest.raises(ValueError):
+        peaks.roofline_share(1.0, 1.0, 0.0, pk)
+    with pytest.raises(KeyError):
+        peaks.for_kind("TPU v9 imaginary")
+    assert peaks.for_kind("TPU v5 lite").hbm_bytes_s == 819e9
+
+
+def test_union_and_gaps():
+    iv = [(0.0, 2.0), (1.0, 3.0), (5.0, 6.0)]
+    assert trace_reduce.union_length(iv) == 4.0
+    assert trace_reduce.union_length(iv, 1.5, 5.5) == 2.0
+    assert trace_reduce.gaps(iv, 0.0, 8.0) == [(3.0, 5.0), (6.0, 8.0)]
+    # nested: a parent [0, 4) with children [0, 2) and [2, 3)
+    assert trace_reduce._self_times([(0, 4), (0, 2), (2, 3)]) == [1, 2, 1]
