@@ -255,6 +255,7 @@ def fused_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
             vmem_limit_bytes=device_spec().vmem_budget,
         ),
         interpret=interpret,
+        name="fused_attention_partial",
     )(q, k, v, kv_pos3, q_pos3)
 
 
@@ -315,6 +316,7 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             vmem_limit_bytes=device_spec().vmem_budget,
         ),
         interpret=interpret,
+        name="fused_attention",
     )(q, k, v)
 
 @functools.partial(jax.jit, static_argnames=(

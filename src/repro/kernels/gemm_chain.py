@@ -141,6 +141,7 @@ def fused_gemm_chain(a: jax.Array, b: jax.Array, d: jax.Array,
             vmem_limit_bytes=device_spec().vmem_budget,
         ),
         interpret=interpret,
+        name="fused_gemm_chain",
     )(a, b, d)
 
 
@@ -280,4 +281,5 @@ def fused_mlp_chain(a: jax.Array, wu: jax.Array, wd: jax.Array,
             vmem_limit_bytes=device_spec().vmem_budget,
         ),
         interpret=interpret,
+        name="fused_mlp_chain",
     )(a, wu, wg, wd)

@@ -97,4 +97,5 @@ def fused_gemm_chain3(a: jax.Array, b: jax.Array, d: jax.Array,
             vmem_limit_bytes=device_spec().vmem_budget,
         ),
         interpret=interpret,
+        name="fused_gemm_chain3",
     )(a, b, d, f)

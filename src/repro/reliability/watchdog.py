@@ -26,8 +26,6 @@ class StepWatchdog:
     n_steps: int = 0
     breaches: int = 0
     max_step_s: float = 0.0
-    last_step_s: float = 0.0
-    last_label: str = ""
 
     @contextlib.contextmanager
     def watch(self, label: str = "") -> Iterator[None]:
@@ -37,8 +35,6 @@ class StepWatchdog:
         finally:
             dt = time.perf_counter() - t0
             self.n_steps += 1
-            self.last_step_s = dt
-            self.last_label = label
             if dt > self.max_step_s:
                 self.max_step_s = dt
             if self.budget_s is not None and dt > self.budget_s:
@@ -50,5 +46,3 @@ class StepWatchdog:
         self.n_steps = 0
         self.breaches = 0
         self.max_step_s = 0.0
-        self.last_step_s = 0.0
-        self.last_label = ""

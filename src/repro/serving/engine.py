@@ -55,6 +55,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..reliability import breaker as _breaker
 from ..reliability import faults as _faults
@@ -85,6 +86,13 @@ class FinishedRequest:
     n_preempted: int = 0
     outcome: str = "complete"    # one of OUTCOMES; anything but
     #                              "complete" means tokens is partial
+    # host clock (``time.perf_counter``) at submit, first admission,
+    # first token held by the host, and finish; None where the request
+    # never got that far.  A preemption keeps the first admission's.
+    t_submit: float = 0.0
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_finish: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -97,6 +105,9 @@ class _Pending:
     submit_step: int
     n_preempted: int = 0
     deadline: Optional[int] = None   # absolute step number, inclusive
+    t_submit: float = 0.0            # FinishedRequest's stamps
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -113,6 +124,9 @@ class _Slot:
     n_done_admit: int = 0        # generated tokens already inside
     #                              ``prompt`` (recompute re-prefilled them)
     deadline: Optional[int] = None
+    t_submit: float = 0.0
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
     @property
     def pos(self) -> int:
@@ -172,8 +186,8 @@ class ServingEngine:
         self._draining = False
         self.exec_tier = 0           # index into TIERS; sticky demotion
         self.stats = {"decode_steps": 0, "prefills": 0, "preemptions": 0,
-                      "generated": 0, "slot_steps": 0, "active_steps": 0,
-                      "ctx_tokens": 0, "page_slot_steps": 0,
+                      "generated": 0, "slot_steps": 0,
+                      "page_slot_steps": 0,
                       "admit_requeues": 0, "tier_demotions": 0,
                       "deadline_evictions": 0, "preempt_failures": 0,
                       "drained": 0, "shadow_checks": 0,
@@ -401,26 +415,27 @@ class ServingEngine:
         which step is computed, which is what keeps chaos-run tokens
         bit-identical (tests/test_reliability.py).  Compiling is not
         part of the chain (``_program``)."""
-        while True:
-            prog = self._program(phase, args)
-            try:
-                if self.exec_tier == 0:
-                    _faults.fault_point("kernel_dispatch",
-                                        op=f"engine-{phase}")
-                _faults.fault_point("engine_step", op=phase,
-                                    tier=self.exec_tier)
-                out = prog(*args)
-                if self.exec_tier == 0:
-                    out = self._sentinel_check(phase, args, out)
-                return out
-            except Exception as e:  # noqa: BLE001 - demote and retry
-                if self.exec_tier >= len(TIERS) - 1:
-                    raise
-                self._note_tier_failure(phase,
-                                        f"{type(e).__name__}: {e}")
-                self.exec_tier += 1
-                self.stats["tier_demotions"] += 1
-                self._build_exec()
+        with TraceAnnotation("engine.dispatch", phase=phase):
+            while True:
+                prog = self._program(phase, args)
+                try:
+                    if self.exec_tier == 0:
+                        _faults.fault_point("kernel_dispatch",
+                                            op=f"engine-{phase}")
+                    _faults.fault_point("engine_step", op=phase,
+                                        tier=self.exec_tier)
+                    out = prog(*args)
+                    if self.exec_tier == 0:
+                        out = self._sentinel_check(phase, args, out)
+                    return out
+                except Exception as e:  # noqa: BLE001 - demote, retry
+                    if self.exec_tier >= len(TIERS) - 1:
+                        raise
+                    self._note_tier_failure(phase,
+                                            f"{type(e).__name__}: {e}")
+                    self.exec_tier += 1
+                    self.stats["tier_demotions"] += 1
+                    self._build_exec()
 
     # ------------------------------------------------------------------
     def _choose_regime(self, model):
@@ -492,7 +507,8 @@ class ServingEngine:
         deadline = (self.step_no + deadline_steps
                     if deadline_steps is not None else None)
         self.queue.append(_Pending(rid, prompt, len(prompt), [], max_new,
-                                   self.step_no, deadline=deadline))
+                                   self.step_no, deadline=deadline,
+                                   t_submit=time.perf_counter()))
         return rid
 
     # ------------------------------------------------------------------
@@ -510,45 +526,50 @@ class ServingEngine:
         plen = len(pend.prompt)
         if self.pool.n_free < math.ceil((plen + 1) / self.page_size):
             return False
-        self.queue.pop(0)
         alloc = KP.RequestPages()
         if not alloc.ensure(plen + 1, self.pool):
             # admission raced the free list (or an injected
-            # page-exhaustion fault): put the head back and let a
+            # page-exhaustion fault): leave the head queued and let a
             # later step retry instead of dying — nothing was
             # allocated, so the engine state is untouched
-            self.queue.insert(0, pend)
             self.stats["admit_requeues"] += 1
             return False
         s_pad = math.ceil(plen / self.page_size) * self.page_size
-        toks = np.zeros((1, s_pad), np.int32)
-        toks[0, :plen] = pend.prompt
-        table = jnp.asarray(KP.table_array([alloc], self.max_pages))
-        logits, self.cache = self._exec(
-            "prefill", self.params, jnp.asarray(toks), self.cache,
-            table, jnp.int32(plen))
-        self.stats["prefills"] += 1
-        if self.model.rt.sentinels and not bool(
-                np.all(np.asarray(_sentinels.healthy(logits[:1])))):
-            # activation health monitor: the prefill produced
-            # NaN/Inf/exploded logits — evict honestly instead of
-            # admitting a request whose every future token is garbage
-            alloc.release(self.pool)
-            self.stats["health_evictions"] += 1
-            self._finish_request(pend.rid, pend.base_prompt_len,
-                                 pend.done, pend.submit_step,
-                                 pend.n_preempted, "health")
+        with TraceAnnotation("engine.admit", rid=pend.rid, tokens=plen,
+                             padded=s_pad):
+            self.queue.pop(0)
+            if pend.t_admit is None:
+                pend.t_admit = time.perf_counter()
+            toks = np.zeros((1, s_pad), np.int32)
+            toks[0, :plen] = pend.prompt
+            table = jnp.asarray(KP.table_array([alloc], self.max_pages))
+            logits, self.cache = self._exec(
+                "prefill", self.params, jnp.asarray(toks), self.cache,
+                table, jnp.int32(plen))
+            self.stats["prefills"] += 1
+            if self.model.rt.sentinels and not bool(
+                    np.all(np.asarray(_sentinels.healthy(logits[:1])))):
+                # activation health monitor: the prefill produced
+                # NaN/Inf/exploded logits — evict honestly instead
+                # of admitting a request whose every future token is
+                # garbage
+                alloc.release(self.pool)
+                self.stats["health_evictions"] += 1
+                self._finish_request(pend, pend.done, "health")
+                return True
+            tok = int(jnp.argmax(logits[0]))
+            if pend.t_first is None:
+                pend.t_first = time.perf_counter()
+            self.slots[free[0]] = _Slot(
+                pend.rid, pend.prompt, pend.base_prompt_len,
+                pend.done + [tok], pend.max_new, alloc,
+                pend.submit_step, self._admit_seq,
+                pend.n_preempted, n_done_admit=len(pend.done),
+                deadline=pend.deadline, t_submit=pend.t_submit,
+                t_admit=pend.t_admit, t_first=pend.t_first)
+            self._admit_seq += 1
+            self._maybe_finish(free[0])
             return True
-        tok = int(jnp.argmax(logits[0]))
-        slot = _Slot(pend.rid, pend.prompt, pend.base_prompt_len,
-                     pend.done + [tok], pend.max_new, alloc,
-                     pend.submit_step, self._admit_seq,
-                     pend.n_preempted, n_done_admit=len(pend.done),
-                     deadline=pend.deadline)
-        self._admit_seq += 1
-        self.slots[free[0]] = slot
-        self._maybe_finish(free[0])
-        return True
 
     def _preempt(self, idx: int) -> None:
         """Requeue slot ``idx`` for recompute: its pages go back to the
@@ -569,12 +590,9 @@ class ServingEngine:
         slot.alloc.release(self.pool)
         self.slots[idx] = None
         if slot.n_preempted + 1 > self.max_preemptions:
-            self.finished.append(FinishedRequest(
-                slot.rid, slot.base_prompt_len, list(slot.generated),
-                slot.submit_step, self.step_no, slot.n_preempted + 1,
-                outcome="preempt_budget"))
+            slot.n_preempted += 1
+            self._finish_request(slot, slot.generated, "preempt_budget")
             self.stats["preempt_failures"] += 1
-            self.stats["generated"] += len(slot.generated)
             return
         fresh = slot.generated[slot.n_done_admit:]
         pend = _Pending(
@@ -582,7 +600,8 @@ class ServingEngine:
             np.concatenate([slot.prompt, np.asarray(fresh, np.int32)]),
             slot.base_prompt_len, list(slot.generated), slot.max_new,
             slot.submit_step, slot.n_preempted + 1,
-            deadline=slot.deadline)
+            deadline=slot.deadline, t_submit=slot.t_submit,
+            t_admit=slot.t_admit, t_first=slot.t_first)
         if slot.n_preempted == 0:
             self.queue.insert(0, pend)
         else:
@@ -596,11 +615,8 @@ class ServingEngine:
                    and slot.generated[-1] == self.eos_id)
         if done_n >= slot.max_new or hit_eos:
             slot.alloc.release(self.pool)
-            self.finished.append(FinishedRequest(
-                slot.rid, slot.base_prompt_len, list(slot.generated),
-                slot.submit_step, self.step_no, slot.n_preempted))
             self.slots[idx] = None
-            self.stats["generated"] += done_n
+            self._finish_request(slot, slot.generated, "complete")
 
     def _grow_or_preempt(self) -> list[int]:
         """Every active slot gets capacity for the position it is about
@@ -616,11 +632,14 @@ class ServingEngine:
             victim = max(active, key=lambda i: self.slots[i].admit_seq)
             self._preempt(victim)
 
-    def _finish_request(self, rid, prompt_len, tokens, submit_step,
-                        n_preempted, outcome: str) -> None:
+    def _finish_request(self, req, tokens, outcome: str) -> None:
+        """Report ``req`` (a ``_Pending`` or ``_Slot``) finished with
+        ``tokens`` under ``outcome``."""
         self.finished.append(FinishedRequest(
-            rid, prompt_len, list(tokens), submit_step, self.step_no,
-            n_preempted, outcome=outcome))
+            req.rid, req.base_prompt_len, list(tokens), req.submit_step,
+            self.step_no, req.n_preempted, outcome=outcome,
+            t_submit=req.t_submit, t_admit=req.t_admit,
+            t_first=req.t_first, t_finish=time.perf_counter()))
         self.stats["generated"] += len(tokens)
 
     def _evict_slot(self, idx: int, outcome: str) -> None:
@@ -629,9 +648,7 @@ class ServingEngine:
         slot = self.slots[idx]
         slot.alloc.release(self.pool)
         self.slots[idx] = None
-        self._finish_request(slot.rid, slot.base_prompt_len,
-                             slot.generated, slot.submit_step,
-                             slot.n_preempted, outcome)
+        self._finish_request(slot, slot.generated, outcome)
 
     def _expire_deadlines(self) -> None:
         """SLO-aware eviction: queued or running requests past their
@@ -641,9 +658,7 @@ class ServingEngine:
         kept = []
         for pend in self.queue:
             if pend.deadline is not None and self.step_no > pend.deadline:
-                self._finish_request(pend.rid, pend.base_prompt_len,
-                                     pend.done, pend.submit_step,
-                                     pend.n_preempted, "deadline")
+                self._finish_request(pend, pend.done, "deadline")
                 self.stats["deadline_evictions"] += 1
             else:
                 kept.append(pend)
@@ -659,7 +674,8 @@ class ServingEngine:
         """One scheduler iteration; returns requests finished in it."""
         n_done = len(self.finished)
         self.step_no += 1
-        with self.watchdog.watch(f"step{self.step_no}"):
+        with StepTraceAnnotation("engine.step", step_num=self.step_no), \
+                self.watchdog.watch(f"step{self.step_no}"):
             self._step_inner()
         return self.finished[n_done:]
 
@@ -681,19 +697,21 @@ class ServingEngine:
                 slot.pos + 1 - self._window, self.pool)
 
     def _step_inner(self) -> None:
-        self._expire_deadlines()
-        self._reclaim_window()
-        # running slots take their growth pages BEFORE admission sees
-        # the free count, and admission reserves each fresh request's
-        # first decode slot — so the second growth pass below can only
-        # preempt on genuine cross-step pressure, never a request
-        # admitted this step
-        self._grow_or_preempt()
+        with TraceAnnotation("engine.schedule"):
+            self._expire_deadlines()
+            self._reclaim_window()
+            # running slots take their growth pages BEFORE admission
+            # sees the free count, and admission reserves each fresh
+            # request's first decode slot — so the second growth pass
+            # below can only preempt on genuine cross-step pressure,
+            # never a request admitted this step
+            self._grow_or_preempt()
         admitted = False
         if not self._draining:
             while self._admit_one():
                 admitted = True
-        active = self._grow_or_preempt()
+        with TraceAnnotation("engine.schedule"):
+            active = self._grow_or_preempt()
         if not active:
             if self.queue and not admitted and not self._draining:
                 # barren step with work queued: count it, and only die
@@ -710,37 +728,41 @@ class ServingEngine:
             return
         self._stall = 0
 
-        tokens = np.zeros((self.max_batch,), np.int32)
-        positions = np.full((self.max_batch,), -1, np.int32)
-        for i in active:
-            tokens[i] = self.slots[i].generated[-1]
-            positions[i] = self.slots[i].pos
-        table = jnp.asarray(KP.table_array(
-            [s.alloc if s is not None else None for s in self.slots],
-            self.max_pages))
+        with TraceAnnotation("engine.inputs") as span:
+            tokens = np.zeros((self.max_batch,), np.int32)
+            positions = np.full((self.max_batch,), -1, np.int32)
+            ctx_tokens = 0
+            for i in active:
+                tokens[i] = self.slots[i].generated[-1]
+                positions[i] = pos = self.slots[i].pos
+                ctx_tokens += pos + 1
+            table = jnp.asarray(KP.table_array(
+                [s.alloc if s is not None else None for s in self.slots],
+                self.max_pages))
+            inputs = (jnp.asarray(tokens), jnp.asarray(positions), table)
+            span.set_metadata(active=len(active), ctx_tokens=ctx_tokens)
         logits, self.cache = self._exec(
-            "decode", self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(positions), table)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        health = np.asarray(_sentinels.healthy(logits)) \
-            if self.model.rt.sentinels else None
-        self.stats["decode_steps"] += 1
-        self.stats["slot_steps"] += self.max_batch
-        self.stats["active_steps"] += len(active)
-        for i in active:
-            slot = self.slots[i]
-            self.stats["ctx_tokens"] += slot.pos + 1
-            self.stats["page_slot_steps"] += sum(
-                1 for p in slot.alloc.pages if p != KP.RECLAIMED)
-            if health is not None and not health[i]:
-                # activation health monitor: this slot's logits went
-                # NaN/Inf/exploded — its kv is poisoned, evict with
-                # the partial tokens instead of sampling from garbage
-                self.stats["health_evictions"] += 1
-                self._evict_slot(i, "health")
-                continue
-            slot.generated.append(int(nxt[i]))
-            self._maybe_finish(i)
+            "decode", self.params, self.cache, *inputs)
+        with TraceAnnotation("engine.sample"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            health = np.asarray(_sentinels.healthy(logits)) \
+                if self.model.rt.sentinels else None
+        with TraceAnnotation("engine.commit"):
+            self.stats["decode_steps"] += 1
+            self.stats["slot_steps"] += self.max_batch
+            for i in active:
+                slot = self.slots[i]
+                self.stats["page_slot_steps"] += slot.alloc.n_live
+                if health is not None and not health[i]:
+                    # activation health monitor: this slot's logits
+                    # went NaN/Inf/exploded — its kv is poisoned, evict
+                    # with the partial tokens instead of sampling from
+                    # garbage
+                    self.stats["health_evictions"] += 1
+                    self._evict_slot(i, "health")
+                    continue
+                slot.generated.append(int(nxt[i]))
+                self._maybe_finish(i)
 
     # ------------------------------------------------------------------
     def drain(self, deadline: Optional[float] = None,
@@ -757,9 +779,7 @@ class ServingEngine:
         try:
             def _fail_queue():
                 for pend in self.queue:
-                    self._finish_request(
-                        pend.rid, pend.base_prompt_len, pend.done,
-                        pend.submit_step, pend.n_preempted, "drained")
+                    self._finish_request(pend, pend.done, "drained")
                     self.stats["drained"] += 1
                 self.queue.clear()
 

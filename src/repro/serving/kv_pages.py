@@ -93,10 +93,12 @@ class RequestPages:
     """One request's page allocation: physical pages in logical order,
     plus the number of kv slots written so far.  Entries may be
     ``RECLAIMED`` (-1) after sliding-window reclamation — logical
-    order is preserved, the physical page is back in the pool."""
+    order is preserved, the physical page is back in the pool.
+    ``n_live`` counts the entries that still hold a page."""
 
     pages: list[int] = dataclasses.field(default_factory=list)
     length: int = 0
+    n_live: int = 0
 
     def ensure(self, length: int, pool: PagePool) -> bool:
         """Grow the allocation to cover ``length`` kv slots; False (and
@@ -108,6 +110,7 @@ class RequestPages:
         if got is None:
             return False
         self.pages.extend(got)
+        self.n_live += len(got)
         return True
 
     def reclaim_below(self, min_pos: int, pool: PagePool) -> int:
@@ -130,12 +133,14 @@ class RequestPages:
                 pool.free([self.pages[i]])
                 self.pages[i] = RECLAIMED
                 n += 1
+        self.n_live -= n
         return n
 
     def release(self, pool: PagePool) -> None:
         pool.free(p for p in self.pages if p != RECLAIMED)
         self.pages = []
         self.length = 0
+        self.n_live = 0
 
 
 def table_array(allocs: list[Optional[RequestPages]],

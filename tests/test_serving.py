@@ -90,8 +90,13 @@ def test_request_pages_ensure_growth_and_failure():
     assert req.pages == before           # failure left state unchanged
     pool.free(other)
     assert req.ensure(25, pool) and len(req.pages) == 4
+    assert req.n_live == 4
+    # pages wholly below position 17 go back; logical order stays
+    assert req.reclaim_below(17, pool) == 2 and pool.n_free == 2
+    assert req.pages[:2] == [KP.RECLAIMED] * 2 and req.n_live == 2
+    assert req.ensure(33, pool) and req.n_live == 3
     req.release(pool)
-    assert pool.n_free == 4
+    assert pool.n_free == 4 and req.n_live == 0
 
 
 # ---------------------------------------------------------------------------
@@ -339,6 +344,8 @@ def test_engine_preemption_recovers():
     assert [len(r.tokens) for r in results] == [10] * 4
     assert any(r.n_preempted for r in results)
     assert eng.pool.n_free == eng.pool.n_pages - 1
+    for r in results:
+        assert r.t_submit <= r.t_admit <= r.t_first <= r.t_finish
 
 
 def test_engine_repeated_preemption_prompt_consistent():
@@ -354,6 +361,7 @@ def test_engine_repeated_preemption_prompt_consistent():
                         choose_regime=False)
     eng.submit(prompt, 12)
     eng.step()                      # admit + first decode
+    first = (eng.slots[0].t_admit, eng.slots[0].t_first)
     for round_ in range(2):         # force-preempt the same request
         eng.step()
         idx = next(i for i, s in enumerate(eng.slots) if s is not None)
@@ -367,6 +375,9 @@ def test_engine_repeated_preemption_prompt_consistent():
         eng.step()
     (res,) = eng.finished
     assert len(res.tokens) == 12 and res.n_preempted == 2
+    # re-admissions keep the first admission's stamps
+    assert (res.t_admit, res.t_first) == first
+    assert res.t_submit <= res.t_admit <= res.t_first <= res.t_finish
     assert eng.pool.n_free == eng.pool.n_pages - 1
 
 

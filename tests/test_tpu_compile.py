@@ -12,6 +12,8 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and the test workers
 all import this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -42,9 +44,15 @@ def _shape(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
+def _compile(fn, kernel: str, *args):
+    """Compile ``fn`` for the described chip; its Mosaic kernels must
+    carry the instruction name ``kernel`` that the chip's trace shows
+    and the benchmark's breakdown keys on."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    names = [re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+) = ", line).group(1)
+             for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert names and {re.sub(r"\.\d+$", "", n) for n in names} == {kernel}
     return compiled
 
 
@@ -64,6 +72,7 @@ def test_mlp_chain_compiles(one_chip, m):
     kw = tk.params.as_kwargs()
     assert vmem_estimate(tk.report.best, V5E) <= V5E.vmem_budget
     _compile(lambda a, wu, wd, wg: fused_mlp_chain(a, wu, wd, wg=wg, **kw),
+             "fused_mlp_chain",
              _shape(one_chip, (1, m, D_MODEL)),
              _shape(one_chip, (1, D_MODEL, D_FF)),
              _shape(one_chip, (1, D_FF, D_MODEL)),
@@ -81,7 +90,7 @@ def test_mlp_chain_vmem_estimate_covers_mosaic(one_chip, monkeypatch, m):
     _with_vmem_limit(monkeypatch, gemm_chain,
                      vmem_estimate(tk.report.best, V5E))
     _compile(lambda a, wu, wd, wg: gemm_chain.fused_mlp_chain(
-                 a, wu, wd, wg=wg, **kw),
+                 a, wu, wd, wg=wg, **kw), "fused_mlp_chain",
              _shape(one_chip, (1, m, D_MODEL)),
              _shape(one_chip, (1, D_MODEL, D_FF)),
              _shape(one_chip, (1, D_FF, D_MODEL)),
@@ -100,7 +109,7 @@ def test_paged_decode_attention_compiles(one_chip):
                                   interpret=False)
     kw = tk.params.as_kwargs()
     _compile(lambda q, kp, vp, tbl, ln: fused_attention_paged(
-                 q, kp, vp, tbl, ln, **kw),
+                 q, kp, vp, tbl, ln, **kw), "fused_attention_partial",
              _shape(one_chip, (b, HEADS, 1, HEAD_DIM)),
              _shape(one_chip, (n_pages, KV_HEADS, page, HEAD_DIM)),
              _shape(one_chip, (n_pages, KV_HEADS, page, HEAD_DIM)),
@@ -116,6 +125,7 @@ def test_prefill_attention_compiles(one_chip, seq):
                             interpret=False)
     kw = tk.params.as_kwargs()
     _compile(lambda q, k, v: fused_attention(q, k, v, causal=True, **kw),
+             "fused_attention",
              _shape(one_chip, (1, HEADS, seq, HEAD_DIM)),
              _shape(one_chip, (1, KV_HEADS, seq, HEAD_DIM)),
              _shape(one_chip, (1, KV_HEADS, seq, HEAD_DIM)))
@@ -128,9 +138,20 @@ def test_gemm_chain_compiles(one_chip):
                              interpret=False)
     kw = tk.params.as_kwargs()
     _compile(lambda a, b, d: fused_gemm_chain(a, b, d, **kw),
+             "fused_gemm_chain",
              _shape(one_chip, (1, m, D_MODEL)),
              _shape(one_chip, (1, D_MODEL, D_FF)),
              _shape(one_chip, (1, D_FF, D_MODEL)))
+
+
+def test_gemm_chain3_compiles(one_chip):
+    """The three-GEMM chain at its default tiles, small trailing dims
+    held whole in VMEM."""
+    from repro.kernels.gemm_chain3 import fused_gemm_chain3
+    m, k, n, h = 1024, D_MODEL, 1024, 256
+    _compile(fused_gemm_chain3, "fused_gemm_chain3",
+             _shape(one_chip, (1, m, k)), _shape(one_chip, (1, k, n)),
+             _shape(one_chip, (1, n, h)), _shape(one_chip, (1, h, h)))
 
 
 def _pallas_vmem_limits(jaxpr) -> list:
