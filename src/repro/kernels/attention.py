@@ -11,6 +11,8 @@ that it fixes K == H and never tunes the reduction tiling.
 
 Supports GQA (kv-head sharing via BlockSpec index maps), causal and
 sliding-window masks, and decode (queries at the tail of the cache).
+Paged decode reads each slot's live pages in place from the page pool
+(``_paged_decode_kernel``).
 """
 from __future__ import annotations
 
@@ -89,6 +91,14 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, o_acc, m_sc, l_sc, *,
         if o_epilogue is not None:
             o = o_epilogue(o)
         o_ref[0, 0] = o.astype(o_ref.dtype)
+
+
+def _divisor_block(block: int, n: int) -> int:
+    """The largest block of at most ``block`` rows that divides ``n``."""
+    block = min(block, n)
+    while n % block:
+        block -= 1
+    return block
 
 
 def _attn_partial_kernel(q_ref, k_ref, v_ref, pos_ref, qpos_ref,
@@ -199,12 +209,8 @@ def fused_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
         kv_pos = jnp.arange(n, dtype=jnp.int32)
     if q_pos is None:
         q_pos = row_start + jnp.arange(m, dtype=jnp.int32)
-    bq = min(bq, m)
-    bkv = min(bkv, n)
-    while m % bq:
-        bq -= 1
-    while n % bkv:
-        bkv -= 1
+    bq = _divisor_block(bq, m)
+    bkv = _divisor_block(bkv, n)
     # positions ride as (rows, 1, N) and (rows, M, 1): every block's
     # last two dims are then (1, bkv) and (bq, 1), which Mosaic accepts
     # for any batch (a (1, bkv) block of a (B, N) array is refused at
@@ -319,6 +325,151 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         name="fused_attention",
     )(q, k, v)
 
+
+def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, l_ref, k_buf, v_buf, sems,
+                         o_acc, m_sc, l_sc, *, max_pages, page_size,
+                         bkv, group, window, scale):
+    """One slot's decode row per q-head over its live pages, read in
+    place from the pool.
+
+    Block ``j`` covers kv columns ``j*bkv .. (j+1)*bkv - 1``: its
+    ``bkv // page_size`` pages are copied whole (every kv-head at once)
+    from HBM into one half of ``k_buf``/``v_buf`` while the block
+    before it is computed from the other half.  Only the
+    ``ceil(lengths[b] / bkv)`` blocks that hold live positions are
+    visited; the ones past them are wholly masked, and skipping them
+    leaves ``(o, m, l)`` bitwise unchanged.  Each q-head row runs
+    ``_attn_partial_kernel``'s recurrence on the block of its kv-head,
+    so a row's arithmetic is the gather path's, and a copied block
+    serves every q-head of its group."""
+    b = pl.program_id(0)
+    ppb = bkv // page_size
+    row = len_ref[b] - 1                  # the query's global position
+    n_blocks = jnp.clip((row + bkv) // bkv, 0, max_pages // ppb)
+    first = b * max_pages                 # row b of the flat page table
+
+    def copies(j, slot):
+        out = []
+        for p in range(ppb):
+            # unallocated entries read the scratch page, rejected below
+            page = jnp.maximum(tbl_ref[first + j * ppb + p], 0)
+            cols = pl.ds(p * page_size, page_size)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[slot, cols], sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[slot, cols], sems.at[1, slot]))
+        return out
+
+    o_acc[...] = jnp.zeros_like(o_acc)
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        for c in copies(0, 0):
+            c.start()
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
+    page_of_lane = lane // page_size
+
+    def block(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < n_blocks)
+        def _():
+            for c in copies(j + 1, 1 - slot):
+                c.start()
+
+        for c in copies(j, slot):
+            c.wait()
+        unallocated = jnp.zeros((1, bkv), jnp.bool_)
+        for p in range(ppb):
+            unallocated |= ((page_of_lane == p)
+                            & (tbl_ref[first + j * ppb + p] < 0))
+        cols = jnp.where(unallocated, INVALID_POS, j * bkv + lane)
+        mask = cols <= row
+        if window > 0:
+            mask &= cols > row - window
+        for kh in range(k_buf.shape[2]):
+            # transposed once per kv-head, not once per q-head's product
+            kt = k_buf[slot, :, kh, :].T          # (d, bkv)
+            v = v_buf[slot, :, kh, :]             # (bkv, dv)
+            for h in range(kh * group, (kh + 1) * group):
+                s = jnp.dot(q_ref[0, h], kt,
+                            preferred_element_type=jnp.float32) * scale
+                s = jnp.where(mask, s, NEG_INF)   # (1, bkv)
+                m_prev = m_sc[h][:, :1]
+                l_prev = l_sc[h][:, :1]
+                m_curr = jnp.max(s, axis=1, keepdims=True)
+                m_new = jnp.maximum(m_prev, m_curr)
+                p_ = jnp.exp(s - m_new)
+                corr = jnp.exp(m_prev - m_new)
+                l_new = l_prev * corr + jnp.sum(p_, axis=1, keepdims=True)
+                o_acc[h] = (o_acc[h] * corr
+                            + jnp.dot(p_.astype(v.dtype), v,
+                                      preferred_element_type=jnp.float32))
+                m_sc[h] = jnp.broadcast_to(m_new, m_sc.shape[1:])
+                l_sc[h] = jnp.broadcast_to(l_new, l_sc.shape[1:])
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+    # rows masked everywhere emit the merge identity, as the partial
+    # kernel's epilogue does
+    dead = m_sc[:, :, :1] <= NEG_INF * 0.5
+    o_ref[0] = jnp.where(dead, 0.0, o_acc[...])
+    l_ref[0] = jnp.where(dead, 0.0, l_sc[:, :, :1])
+
+
+def _paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
+                            bkv, window, scale, interpret):
+    """``(o_unnorm, l_run)`` of one query row per q-head over the live
+    pages of each slot: grid ``(B,)``, the page table and lengths as
+    scalar prefetch, the pools, ``(n_pages, page_size, Hkv, D)``, left
+    in HBM and copied page by page."""
+    b, hq, _, d = q.shape
+    _, ps, hkv, dv = v_pages.shape
+    max_pages = page_table.shape[1]
+    kernel = functools.partial(
+        _paged_decode_kernel, max_pages=max_pages, page_size=ps, bkv=bkv,
+        group=hq // hkv, window=window, scale=scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, hq, 1, d), lambda b_, *_: (b_, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, hq, 1, dv), lambda b_, *_: (b_, 0, 0, 0)),
+                pl.BlockSpec((1, hq, 1, 1), lambda b_, *_: (b_, 0, 0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((2, bkv, hkv, d), k_pages.dtype),
+                pltpu.VMEM((2, bkv, hkv, dv), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hq, 1, dv), jnp.float32),
+                pltpu.VMEM((hq, 1, LANES), jnp.float32),
+                pltpu.VMEM((hq, 1, LANES), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hq, 1, dv), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, 1, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=device_spec().vmem_budget,
+        ),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(page_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
+      q, k_pages, v_pages)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "bq", "bkv", "window", "scale", "pages_per_chunk", "interpret"))
 def fused_attention_paged(q: jax.Array, k_pages: jax.Array,
@@ -338,18 +489,23 @@ def fused_attention_paged(q: jax.Array, k_pages: jax.Array,
     physical page per logical page, -1 = unallocated; lengths: (B,)
     int32 context length per request.
 
-    Each chunk of the page table is gathered into the contiguous
-    layout the fused schedule streams and run through
-    ``fused_attention_partial`` with per-request global positions —
+    Decode (``M == 1``, one chunk, and a kv block of whole pages) runs
+    ``_paged_decode_kernel``: each slot's live pages are read in place,
+    one grid step per slot, nothing gathered.  Otherwise each chunk of
+    the page table is gathered into the contiguous layout the fused
+    schedule streams and run through ``fused_attention_partial`` with
+    per-request global positions —
     unallocated slots carry the ``INVALID_POS`` sentinel the causal
     mask always rejects, and slots past ``lengths[b]`` (a partly
     filled tail page, possibly holding a previous tenant's stale kv)
     fail ``col <= row`` the same way.  Chunk states merge with the
     PR 4 log-sum-exp combine (``dist.ring_dispatch.merge_partials``).
-    With the default single chunk the recurrence visits exactly the
-    blocks ``fused_attention`` would on a contiguous cache of
-    ``max_pages * page_size`` slots, making the output bit-identical
-    to the contiguous-cache kernel (tests/test_serving.py);
+    With the default single chunk both paths run, row for row, the
+    recurrence ``fused_attention`` runs over the blocks of a contiguous
+    cache of ``max_pages * page_size`` slots (the decode kernel skips
+    only blocks wholly past ``lengths[b]``, which leave it unchanged),
+    making the output bit-identical to the contiguous-cache kernel
+    (tests/test_serving.py);
     ``pages_per_chunk`` bounds the gather staging buffer at the cost
     of one extra rescale per chunk boundary (f32-exact, not bitwise).
     """
@@ -361,6 +517,17 @@ def fused_attention_paged(q: jax.Array, k_pages: jax.Array,
     max_pages = page_table.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
+    blk = _divisor_block(bkv, max_pages * ps)
+    if m == 1 and pages_per_chunk == 0 and blk % ps == 0:
+        # pages go in as (page_size, Hkv, D), the order XLA lays the
+        # pool out in for the serving step's kv scatter: there the
+        # transpose is a bitcast, while a kernel reading (Hkv,
+        # page_size, D) pages makes XLA copy the layer's whole pool
+        o, l_run = _paged_decode_attention(
+            q, k_pages.transpose(0, 2, 1, 3), v_pages.transpose(0, 2, 1, 3),
+            page_table, lengths, bkv=blk,
+            window=window, scale=scale, interpret=interpret)
+        return finalize_partials(o, l_run, q.dtype)
     q_pos = (lengths.astype(jnp.int32)[:, None] - m
              + jnp.arange(m, dtype=jnp.int32)[None, :])
     cpp = (pages_per_chunk if 0 < pages_per_chunk < max_pages

@@ -28,7 +28,8 @@ from repro.core import api
 from repro.core.chain import attention_chain
 from repro.core.perf_model import (MeshSpec, paged_gather_bytes,
                                    paged_gather_seconds)
-from repro.kernels.attention import (fused_attention, fused_attention_paged,
+from repro.kernels.attention import (INVALID_POS, fused_attention,
+                                     fused_attention_paged,
                                      fused_attention_partial)
 from repro.dist.ring_dispatch import finalize_partials
 from repro.models.lm import LM, Runtime
@@ -191,6 +192,78 @@ def test_paged_chunked_merge_close():
                                         interpret=True)
         np.testing.assert_allclose(np.asarray(chunked), np.asarray(whole),
                                    atol=1e-6)
+
+
+def _pallas_names(fn) -> set:
+    """Names of the Pallas kernels ``fn()`` traces."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", None)
+                if inner is not None:
+                    yield from walk(getattr(inner, "jaxpr", inner))
+    return set(walk(jax.make_jaxpr(fn)().jaxpr))
+
+
+@pytest.mark.parametrize("hq,hkv,pages_per_block,window", [
+    (4, 4, 1, 0),       # group 1 (MHA), a block of one page
+    (8, 2, 3, 0),       # group 4, a block of three pages
+    (4, 1, 2, 0),       # Hkv = 1 (MQA)
+    (8, 2, 1, 7),       # windows of 7 straddle 4-slot blocks
+    (8, 2, 3, 7),       # ... and the 12-slot block boundary
+])
+def test_paged_decode_kernel_bit_identical(hq, hkv, pages_per_block,
+                                           window):
+    """The in-place decode kernel == the dense-layout partial kernel,
+    bitwise: an inactive slot (length 0, an all -1 row), lengths on a
+    page boundary and at n_ctx, a shuffled table with an unallocated
+    entry inside a live range (it reads the scratch page, which every
+    -1 entry shares)."""
+    rng = np.random.RandomState(hq * 100 + hkv * 10 + pages_per_block)
+    d, ps, mp = 8, 4, 6
+    n_ctx = mp * ps
+    bkv = pages_per_block * ps
+    lengths = [0, 8, n_ctx, 13, 5]
+    b = len(lengths)
+    pool_k, pool_v, table, dense_k, dense_v = _paged_setup(
+        rng, b, hkv, d, ps, mp, n_pool=b * mp + 2, lengths=lengths)
+    table = table.at[3, 1].set(-1)      # a hole in slot 3's live range
+    q = jnp.asarray(rng.randn(b, hq, 1, d), jnp.float32)
+    larr = jnp.asarray(lengths, jnp.int32)
+
+    def run():
+        return fused_attention_paged(q, pool_k, pool_v, table, larr,
+                                     bkv=bkv, window=window,
+                                     interpret=True)
+
+    assert _pallas_names(run) == {"paged_decode_attention"}
+    kv_pos = KP.paged_kv_positions(table, ps, invalid=INVALID_POS)
+    o, _, l = fused_attention_partial(
+        q, dense_k, dense_v, kv_pos, larr[:, None] - 1, bq=1, bkv=bkv,
+        causal=True, window=window, interpret=True)
+    want = finalize_partials(o, l, q.dtype)
+    got = run()
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert not np.asarray(got[0]).any()    # the inactive slot
+
+
+@pytest.mark.parametrize("m,bkv,pages_per_chunk", [
+    (4, 8, 0),          # prefill rows
+    (1, 8, 2),          # chunked
+    (1, 6, 0),          # a block of a page and a half
+])
+def test_paged_gather_path_kept(m, bkv, pages_per_chunk):
+    """Query blocks, chunked tables and blocks that split a page keep
+    the gather + partial path."""
+    q = jnp.zeros((2, 4, m, 8))
+    pages = jnp.zeros((9, 2, 4, 8))
+    table = jnp.zeros((2, 6), jnp.int32)
+    names = _pallas_names(lambda: fused_attention_paged(
+        q, pages, pages, table, jnp.full((2,), 10, jnp.int32), bkv=bkv,
+        pages_per_chunk=pages_per_chunk, interpret=True))
+    assert names == {"fused_attention_partial"}
 
 
 def test_model_paged_decode_bit_identical_with_churn():

@@ -109,12 +109,42 @@ def test_paged_decode_attention_compiles(one_chip):
                                   interpret=False)
     kw = tk.params.as_kwargs()
     _compile(lambda q, kp, vp, tbl, ln: fused_attention_paged(
-                 q, kp, vp, tbl, ln, **kw), "fused_attention_partial",
+                 q, kp, vp, tbl, ln, **kw), "paged_decode_attention",
              _shape(one_chip, (b, HEADS, 1, HEAD_DIM)),
              _shape(one_chip, (n_pages, KV_HEADS, page, HEAD_DIM)),
              _shape(one_chip, (n_pages, KV_HEADS, page, HEAD_DIM)),
              _shape(one_chip, (b, pages_per_seq), jnp.int32),
              _shape(one_chip, (b,), jnp.int32))
+
+
+@pytest.mark.parametrize("b,pages_per_seq", [(64, 80), (48, 256)])
+def test_paged_decode_attention_compiles_at_cell_shapes(one_chip, b,
+                                                        pages_per_seq):
+    """The benchmark cells' decode shapes (qwen3-8b chat: 64 slots of
+    1280; mistral-nemo reason: 48 of 4096) with the tuner's tiles.  The
+    pools are laid out (page, slot, kv-head, dim) in memory, as XLA
+    keeps them in the serving step for the kv scatter: the kernel reads
+    them in place, so the program stages neither a gathered table nor a
+    relaid copy of the pool."""
+    from jax.experimental.layout import Format, Layout
+    from repro.kernels.attention import fused_attention_paged
+    page = 16
+    n_pages = b * pages_per_seq + 1
+    pool = jax.ShapeDtypeStruct(
+        (n_pages, KV_HEADS, page, HEAD_DIM), jnp.bfloat16,
+        sharding=Format(Layout(major_to_minor=(0, 2, 1, 3)), one_chip))
+    tk = api.fuse_attention_paged(1, page * pages_per_seq, HEAD_DIM,
+                                  HEAD_DIM, page_size=page, heads=HEADS,
+                                  batch=b, dtype=BF16, causal=True,
+                                  hw=V5E, interpret=False)
+    kw = tk.params.as_kwargs()
+    compiled = _compile(
+        lambda q, kp, vp, tbl, ln: fused_attention_paged(
+            q, kp, vp, tbl, ln, **kw), "paged_decode_attention",
+        _shape(one_chip, (b, HEADS, 1, HEAD_DIM)), pool, pool,
+        _shape(one_chip, (b, pages_per_seq), jnp.int32),
+        _shape(one_chip, (b,), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 @pytest.mark.parametrize("seq", [512, 2048])
