@@ -87,6 +87,52 @@ def make_weights(cell, seed: int, shardings=None):
     return canon, program, jax.jit(program, out_shardings=shardings)
 
 
+def reference_weights(cell, seed: int, devices):
+    """The canonical draw for the reference, spread over ``devices``:
+    every matrix split along its last (output) axis where it divides,
+    the RMSNorm gains whole on each.  The values are those of the draw
+    on one device (the random bits are made whole and then split), so
+    a cell on four chips checks against the same weights as on one,
+    with a quarter of them on each chip."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    canon, _, _ = make_weights(cell, seed)
+    mesh = Mesh(np.asarray(devices), ("chips",))
+    n = len(devices)
+
+    def place(name, leaf):
+        split = (not W.is_gain(name) and leaf.ndim >= 2
+                 and leaf.shape[-1] % n == 0)
+        return NamedSharding(mesh, P(*[None] * (leaf.ndim - 1), "chips")
+                             if split else P())
+
+    shapes = jax.eval_shape(canon, W.seed_key(0))
+    out = {k: place(k, v) for k, v in shapes.items()}
+    return jax.jit(canon, out_shardings=out)(W.seed_key(seed))
+
+
+def regime_line(engine) -> str:
+    """The decode regime the engine picked and the times it priced."""
+    times = ", ".join(f"{k} {v * 1e6:.1f}us"
+                      for k, v in engine.regime_times.items())
+    return (f"decode regime {engine.regime} (priced: {times}; schedule "
+            f"from {engine.regime_source})")
+
+
+def cache_layout(cache) -> str:
+    """The KV pool's sharding and its bytes on the fullest chip."""
+    import jax
+    leaves = jax.tree.leaves(cache)
+    per_dev: dict = {}
+    for x in leaves:
+        for sh in x.addressable_shards:
+            per_dev[sh.device] = per_dev.get(sh.device, 0) + sh.data.nbytes
+    spec = getattr(leaves[0].sharding, "spec", leaves[0].sharding)
+    return (f"{spec}, {sum(x.nbytes for x in leaves)} B in all, "
+            f"{max(per_dev.values())} B on the fullest of {len(per_dev)} "
+            f"chips")
+
+
 @dataclasses.dataclass
 class Req:
     index: int

@@ -20,6 +20,7 @@ fused MLP.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import os
@@ -72,6 +73,59 @@ def instr_kind(instr: str) -> str:
     """``fusion.57`` -> ``fusion``; ``fused_attention_partial.11`` ->
     ``fused_attention_partial``."""
     return re.sub(r"\.\d+$", "", instr)
+
+
+COLLECTIVE = re.compile(r"^(all-reduce|all-gather|reduce-scatter|"
+                        r"collective-permute|all-to-all)(-start|-done)?$")
+_OPCODE = re.compile(r"^%[\w.\-]+ = .*? ([a-z][\w\-]*)\(")
+
+
+def collective_kind(op: "Op") -> str | None:
+    """The collective an op runs (``all-reduce``, ``all-gather``,
+    ``reduce-scatter``, ``collective-permute``, ``all-to-all``, each
+    also as its async ``-start`` / ``-done`` half), from its instruction
+    name or, where the name was changed, the opcode in its text; None
+    for any other op."""
+    for kind in (instr_kind(op.instr),
+                 (_OPCODE.match(op.text) or [None, ""])[1]):
+        m = COLLECTIVE.match(kind)
+        if m:
+            return m.group(0)
+    return None
+
+
+def exposed_collective_s(red: "Reduced", device: int,
+                         module: str | None = None) -> tuple[float, int]:
+    """(seconds, ops) of the device's collective ops that start inside
+    the window (in ``module`` alone where given), less the time in which
+    a compute op of the same device overlaps them.  Compute ops are the
+    other ops that hold no op nested inside them: the ``while`` of a
+    layer scan, which encloses its collectives, hides none of them."""
+    lo, hi = red.window
+    ops = [o for o in red.ops if o.device == device]
+    merged: list = []
+    for s, e in sorted((o.start, o.end) for o in ops
+                       if not collective_kind(o)
+                       and o.self_s >= (o.end - o.start) * (1 - 1e-9)):
+        if merged and s <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], e)
+        else:
+            merged.append([s, e])
+    starts = [m[0] for m in merged]
+    exposed, n = 0.0, 0
+    for o in ops:
+        if (not collective_kind(o) or not lo <= o.start <= hi
+                or module is not None and o.module != module):
+            continue
+        n += 1
+        hidden = 0.0
+        i = max(0, bisect.bisect_right(starts, o.start) - 1)
+        while i < len(merged) and merged[i][0] < o.end:
+            hidden += max(0.0, min(o.end, merged[i][1])
+                          - max(o.start, merged[i][0]))
+            i += 1
+        exposed += (o.end - o.start) - hidden
+    return exposed, n
 
 
 def _self_times(events):

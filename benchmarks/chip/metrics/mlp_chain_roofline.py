@@ -1,8 +1,10 @@
 """Share of the roofline bound reached by the fused MLP chain: per call
 the gate, up and down weights plus the rows' activations, 6 rows d_model
 d_ff flops (``costs``), over the device time of every op under
-``jit(fused_mlp_chain)``.  Rows are the active slots of a decode step and
-the real prompt tokens of a prefill whose plan fused the chain."""
+``jit(fused_mlp_chain)``, averaged over the chips.  Rows are the active
+slots of a decode step and the real prompt tokens of a prefill whose
+plan fused the chain.  On a mesh each chip holds d_ff / chips of every
+chain and is charged that share."""
 from harness import costs, peaks, reading
 
 
@@ -27,5 +29,7 @@ def read(rec):
     for m in rows:
         f, b = costs.mlp_chain(shape, m)
         flops, nbytes = flops + f, nbytes + b
+    # each chip runs its own d_ff / chips share of every layer's chain
+    flops, nbytes = flops / rec.chips, nbytes / rec.chips
     share, bound = peaks.roofline_share(flops, nbytes, t, rec.peaks)
     return share, f"{bound}-bound"

@@ -2,7 +2,9 @@
 paged cache: the needed work (K/V of the positions below each active
 slot's length, q and o, 4 ctx heads head_dim flops; ``costs``) over the
 device time of every op under ``jit(fused_attention_paged)`` in the
-decode program, the page gather included."""
+decode program, averaged over the chips.  On a mesh each chip attends
+with heads / chips of the heads (paged-spatial) or pages / chips of the
+pages (paged-ring) and is charged that share."""
 from harness import costs, peaks, reading
 
 
@@ -18,5 +20,7 @@ def read(rec):
     for s in steps:
         f, b = costs.paged_attention(shape, s.ctx)
         flops, nbytes = flops + f, nbytes + b
+    # each chip attends with its own share of the heads (or of the pages)
+    flops, nbytes = flops / rec.chips, nbytes / rec.chips
     share, bound = peaks.roofline_share(flops, nbytes, t, rec.peaks)
     return share, f"{bound}-bound"

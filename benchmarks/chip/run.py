@@ -88,18 +88,20 @@ def execute(cell, seed: int, seconds: float, trace: bool, devs,
     not correct); ``fault`` (``harness/faults.py``) is planted under the
     engine's dispatch.  The served tokens' own gap then goes to the log
     and under ``readings``."""
-    import jax
     from harness import check, faults, reading, serve_loop
 
     runner = serve_loop.Runner(cell, seed, seconds, trace, T_PROCESS,
                                log=log)
     runner.build()
+    log(serve_loop.regime_line(runner.engine))
     if fault is not None:
         faults.plant(runner.engine, fault)
     with runner.mesh_context():
         t = time.perf_counter()
         runner.warm_up()
         warm_s = time.perf_counter() - t
+        log(f"KV pool after warm-up: "
+            f"{serve_loop.cache_layout(runner.engine.cache)}")
         rec = runner.window()
     rec.peaks = pk
     memory_peak = serve_loop.device_peak_bytes(devs)
@@ -140,14 +142,13 @@ def execute(cell, seed: int, seconds: float, trace: bool, devs,
     rec.trace = None
     runner.release()
     del rec
-    canon, _, _ = serve_loop.make_weights(cell, seed)
     t = time.perf_counter()
-    with jax.default_device(devs[0]):
-        w = jax.jit(canon)(serve_loop.W.seed_key(seed))
-        gap, ctl, n_tok = check.gaps(w, cell.config, seqs, control)
+    w = serve_loop.reference_weights(cell, seed, devs)
+    gap, ctl, n_tok = check.gaps(w, cell.config, seqs, control)
     del w
     log(f"reference over {len(seqs)} requests, {n_tok} served tokens, "
-        f"{time.perf_counter() - t:.3f}s")
+        f"{time.perf_counter() - t:.3f}s on {len(devs)} chips; the "
+        f"process's peak so far {serve_loop.device_peak_bytes(devs)} B")
     if control:
         log(f"served tokens' max_logit_gap {gap!r}; the control's is "
             f"compared")

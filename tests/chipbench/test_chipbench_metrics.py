@@ -5,7 +5,7 @@ import pytest
 
 from chipbench_helpers import tiny_cell
 
-from harness import costs, peaks, serve_loop, spec, trace_reduce
+from harness import costs, peaks, reading, serve_loop, spec, trace_reduce
 
 Req, Step = serve_loop.Req, serve_loop.Step
 
@@ -148,3 +148,86 @@ def test_union_and_gaps():
     assert trace_reduce.gaps(iv, 0.0, 8.0) == [(3.0, 5.0), (6.0, 8.0)]
     # nested: a parent [0, 4) with children [0, 2) and [2, 3)
     assert trace_reduce._self_times([(0, 4), (0, 2), (2, 3)]) == [1, 2, 1]
+
+
+def test_rooflines_charge_each_chip_its_share():
+    """The same trace read as a four-chip cell's: each chip holds a
+    quarter of the heads and of d_ff, so each kernel's needed work per
+    chip, and its share of the roofline, is a quarter of one chip's."""
+    shape = costs.Shape(layers=2, d_model=8, heads=4, kv_heads=4,
+                        head_dim=4, d_ff=16, vocab=32)
+    steps = [Step(0.0, 4.5, [], [3, 5]), Step(4.5, 8.0, [10], [])]
+    one = record([], steps, _trace(), (0, 2), chips=1)
+    four = record([], steps, _trace(), (0, 2), chips=4)
+    for rec in (one, four):
+        rec.cell = types.SimpleNamespace(shape=shape)
+    # attention: 8 positions, 4 q-heads of 4, 2 layers, over 2 s
+    flops = 4 * 8 * 4 * 4 * 2
+    nbytes = (2 * 8 * 4 * 4 * 2 + 2 * 2 * 4 * 4 * 2) * 2
+    want = 100 * max(flops / 100.0, nbytes / 10.0) / 2.0
+    assert read("paged_attn_roofline", one) == pytest.approx(want)
+    assert read("paged_attn_roofline", four) == pytest.approx(want / 4)
+    for name in ("mlp_chain_roofline", "paged_attn_roofline"):
+        assert read(name, four) == pytest.approx(read(name, one) / 4)
+    # the whole step's share already counts every chip's peak
+    assert read("step_mfu", four) == pytest.approx(read("step_mfu", one) / 4)
+
+
+def _mesh_trace():
+    """Two chips, one decode program [0, 10) each, a layer scan's
+    ``while`` enclosing it.  Chip 0: a sync all-reduce [2, 3) alone, an
+    async pair whose start [4, 4.2) is followed by a fusion [4.2, 6)
+    while the transfer is in flight and whose done [6, 7) waits alone;
+    chip 1: an all-gather [2, 4) half covered by a fusion [3, 5).  A
+    prefill's all-reduce is not the decode's."""
+    Op = trace_reduce.Op
+    dec = reading.DECODE
+    ops = []
+    for dev in (0, 1):
+        ops.append(Op(dev, 0.0, 10.0, 0.0, "while.1", "", dec, ""))
+    ops += [
+        Op(0, 2.0, 3.0, 1.0, "all-reduce.7", "", dec, ""),
+        Op(0, 4.0, 4.2, 0.2, "all-reduce-start.2", "", dec, ""),
+        Op(0, 4.2, 6.0, 1.8, "fusion.3", "", dec, ""),
+        Op(0, 6.0, 7.0, 1.0, "all-reduce-done.2", "", dec, ""),
+        Op(1, 2.0, 4.0, 2.0, "ag.5",
+           "%ag.5 = bf16[8]{0} all-gather(%x), dimensions={0}", dec, ""),
+        Op(1, 3.0, 5.0, 2.0, "fusion.9", "", dec, ""),
+        Op(0, 12.0, 13.0, 1.0, "all-reduce.1", "", reading.PREFILL, ""),
+    ]
+    mods = [(0, 0.0, 10.0, dec), (1, 0.0, 10.0, dec),
+            (0, 12.0, 13.0, reading.PREFILL)]
+    spans = [trace_reduce.Span(0.0, 14.0, "bench.step")]
+    return trace_reduce.Reduced([0, 1], mods, ops, spans, (0.0, 14.0))
+
+
+def test_collective_kinds_by_name_or_opcode():
+    Op = trace_reduce.Op
+    kinds = [trace_reduce.collective_kind(Op(0, 0, 1, 1, n, t, "", ""))
+             for n, t in [("all-reduce.7", ""),
+                          ("all-reduce-start.2", ""),
+                          ("collective-permute-done.1", ""),
+                          ("reduce-scatter.4", ""),
+                          ("x.1", "%x.1 = f32[2]{0} all-to-all(%y)"),
+                          ("fusion.3", "%fusion.3 = f32[2]{0} fusion(%a)"),
+                          ("all-reduce-fusion.1", "")]]
+    assert kinds == ["all-reduce", "all-reduce-start",
+                     "collective-permute-done", "reduce-scatter",
+                     "all-to-all", None, None]
+
+
+def test_exposed_collectives_per_decode_step():
+    red = _mesh_trace()
+    # chip 0: 1 + 0.2 + 1 (the fusion runs between start and done);
+    # chip 1: 2 - 1 (half under the fusion)
+    assert trace_reduce.exposed_collective_s(red, 0, reading.DECODE) == (
+        pytest.approx(2.2), 3)
+    assert trace_reduce.exposed_collective_s(red, 1, reading.DECODE) == (
+        pytest.approx(1.0), 1)
+    rec = record([], (), red, (0, 1), chips=2)
+    # (2.2 + 1) s over 2 chips, one decode step each
+    assert read("collective_exposed_ms_per_step", rec) == pytest.approx(
+        1600.0)
+    # one chip's trace, no collective: nothing to read
+    assert read("collective_exposed_ms_per_step", record([], (), _trace(),
+                                                          (0, 2))) is None

@@ -1,13 +1,18 @@
 """The plain reference against the engine at a small size on the CPU:
-prefill, then decode through the paged cache; and the float8 control,
-which the comparison has to fail."""
+prefill, then decode through the paged cache; the float8 control, which
+the comparison has to fail; and the reference spread over four devices,
+which reads as on one."""
+import json
+import os
+import subprocess
+import sys
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench_helpers import TINY_CONFIG, tiny_cell
+from chipbench_helpers import ROOT, TINY_CONFIG, tiny_cell
 
 from harness import check, reference, serve_loop, weights as W
 
@@ -107,3 +112,57 @@ def test_the_float8_control_fails_the_comparison():
 
 # readings at this size on the CPU: served gap 0.0, control 0.0719
 LIMIT = 0.03
+
+
+FOUR_CHIPS = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json, sys
+sys.path.insert(0, sys.argv[1])
+import numpy as np
+import jax
+from chipbench_helpers import TINY_CONFIG, tiny_cell
+from harness import check, serve_loop, weights as W
+
+cell = tiny_cell(config=dict(TINY_CONFIG, num_attention_heads=8,
+                             num_key_value_heads=4, hidden_size=128,
+                             initializer_range=0.16))
+seed = 2**31 + 21
+plain = jax.jit(serve_loop.make_weights(cell, seed)[0])(W.seed_key(seed))
+one = serve_loop.reference_weights(cell, seed, jax.devices()[:1])
+four = serve_loop.reference_weights(cell, seed, jax.devices()[:4])
+rng = np.random.Generator(np.random.PCG64(seed))
+seqs = []
+for n, first in ((40, 20), (600, 500)):
+    seq = rng.integers(0, 512, n).astype(np.int32)
+    seqs.append((seq, rng.integers(0, 512, n).astype(np.int32), first))
+g1 = [check.gaps(one, cell.config, [s], control=True) for s in seqs]
+g4 = [check.gaps(four, cell.config, [s], control=True) for s in seqs]
+print("RESULT " + json.dumps({
+    "same_draw": all(np.array_equal(np.asarray(plain[k]), np.asarray(one[k]))
+                     and np.array_equal(np.asarray(plain[k]),
+                                        np.asarray(four[k])) for k in plain),
+    "split": sorted(k for k, v in four.items()
+                    if v.addressable_shards[0].data.shape != v.shape),
+    "one": [g[:2] for g in g1], "four": [g[:2] for g in g4]}))
+"""
+
+
+def test_the_reference_on_four_chips_reads_as_on_one():
+    """On four forced host devices the reference's weights are the same
+    draw, every matrix a quarter on each device, and its gaps (served
+    and control) equal the one-device reference's to float32 rounding."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    p = subprocess.run(
+        [sys.executable, "-c", FOUR_CHIPS, str(ROOT / "tests" / "chipbench")],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(next(ln for ln in p.stdout.splitlines()
+                          if ln.startswith("RESULT "))[len("RESULT "):])
+    assert out["same_draw"]
+    assert out["split"] == ["embed", "lm_head", "w_down", "w_gate", "w_up",
+                            "wk", "wo", "wq", "wv"]
+    for a, b in zip(out["one"], out["four"]):
+        assert a[0] > 0 and a[1] > 0
+        np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-5)

@@ -84,7 +84,14 @@ def test_a_sound_run_is_correct(run_mod, scaled):
                          ids=lambda name: f"_{name}")
 def test_a_broken_timed_path_makes_the_run_incorrect(run_mod, fault):
     """Each fault a served model can have on one chip, planted under the
-    timed path's dispatch, makes ``correct`` false."""
+    timed path's dispatch, makes ``correct`` false; the exchange between
+    chips, which one chip does not have, is refused there (the four-chip
+    run below leaves it out)."""
+    if faults.FAULTS[fault] in faults.ON_PARAMS:
+        with pytest.raises(ValueError, match="no exchange"):
+            _execute(run_mod, tiny_cell(config=SCALED),
+                     fault=faults.FAULTS[fault])
+        return
     out = _execute(run_mod, tiny_cell(config=SCALED),
                    fault=faults.FAULTS[fault])
     assert not out["correct"]
@@ -110,3 +117,48 @@ def test_the_control_in_the_program_place_makes_the_run_incorrect(run_mod):
 # qwen3-8b's width; at 0.02 and width 64 attention barely moves the
 # logits, and a cache that is never written reads about 0.05 (CPU).
 SCALED = dict(TINY_CONFIG, initializer_range=0.16)
+
+
+MESH_RUN = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import dataclasses, importlib.util, json, sys
+sys.path.insert(0, sys.argv[1])
+import jax
+from chipbench_helpers import CHIP, TINY_CONFIG, tiny_cell
+from harness import faults, peaks
+spec = importlib.util.spec_from_file_location("chipbench_run", CHIP / "run.py")
+run = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(run)
+cfg = dict(TINY_CONFIG, num_attention_heads=8, num_key_value_heads=4,
+           hidden_size=128, initializer_range=0.16, chips=4)
+cell = dataclasses.replace(tiny_cell(config=cfg), chips=4)
+out = {}
+for name in ("sound", "exchange_left_out"):
+    r = run.execute(cell, 2**31 + 5, 1.5, False, jax.devices()[:4], 4,
+                    peaks.Peaks(1e12, 1e11, "t"),
+                    fault=faults.FAULTS.get(name))
+    out[name] = [r["correct"], r["checks"]["max_logit_gap"]["value"],
+                 r["failed"], r["attempted"]]
+print("RESULT " + json.dumps(out))
+"""
+
+
+def test_a_four_chip_run_is_correct_until_the_exchange_is_left_out():
+    """A whole run of a four-chip cell on four forced host devices, the
+    chip look skipped: the engine serves tensor-parallel over the mesh
+    and the run is correct; with the exchange between chips left out it
+    is not."""
+    p = subprocess.run([sys.executable, "-c", MESH_RUN,
+                        str(ROOT / "tests" / "chipbench")],
+                       env=_cpu_env(), capture_output=True, text=True,
+                       timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(next(ln for ln in p.stdout.splitlines()
+                          if ln.startswith("RESULT "))[len("RESULT "):])
+    correct, gap, failed, attempted = out["sound"]
+    assert correct and failed == 0 and attempted > 0, out
+    correct, gap, _, _ = out["exchange_left_out"]
+    assert not correct and gap > 0.05, out
+    assert "KV pool after warm-up" in p.stderr
+    assert "decode regime paged-" in p.stderr
