@@ -34,10 +34,11 @@ def sequences(sample, traffic, vocab: int):
     return out
 
 
-def gaps(weights, config: dict, seqs, control: bool = False):
-    """(widest served gap, widest control gap or None, tokens compared)."""
+def gaps(weights, cell, seqs, control: bool = False):
+    """(widest served gap, widest control gap or None, tokens compared),
+    by the reference of the cell's architecture module."""
     import jax.numpy as jnp
-    items = reference.cfg_items(config)
+    key = reference.cfg_key(cell.config)
     worst, worst_ctl, n = 0.0, 0.0, 0
     for seq, tgt, first in seqs:
         s = len(seq)
@@ -47,7 +48,8 @@ def gaps(weights, config: dict, seqs, control: bool = False):
         tg = np.zeros(pad, np.int32)
         tg[:s] = tgt
         g_s, g_c = reference.gaps(weights, jnp.asarray(toks),
-                                  jnp.asarray(tg), items, control)
+                                  jnp.asarray(tg), cell.arch.hidden, key,
+                                  control)
         g_s = np.asarray(g_s)[first:s]
         worst = max(worst, float(g_s.max()))
         if control:

@@ -1,5 +1,6 @@
 """Arithmetic the metric readers share: percentiles over all samples,
-token times in the window, and device time from the reduced trace.
+token times in the window, device time from the reduced trace, and the
+cell's architecture module's costs.
 
 A percentile is NumPy's default (linear between the two nearest order
 statistics) over every sample, never a median of per-chunk figures.
@@ -85,3 +86,17 @@ def kernel_in_module(rec, path_part: str, module: str) -> list:
     hits = [o.start for o in red.ops if o.device == dev
             and o.module == module and path_part in o.path]
     return [any(a <= t <= b for t in hits) for a, b in mods]
+
+
+def cost(rec, name: str, metric: str):
+    """The function ``name`` of the cell's architecture module, with the
+    configuration bound.  A module without it cannot price ``metric``:
+    that raises, naming both, rather than reading 0."""
+    arch = rec.cell.arch
+    fn = getattr(arch, name, None)
+    if fn is None:
+        raise NotImplementedError(
+            f"{metric}: architecture module {arch.__file__} has no "
+            f"{name}(config, ...) to count the work with")
+    config = rec.cell.config
+    return lambda *a: fn(config, *a)

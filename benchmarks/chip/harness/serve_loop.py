@@ -50,50 +50,32 @@ def configure_caches() -> str:
     return path
 
 
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file (HF keys)."""
-    from repro.models.config import ModelConfig
-    c = config
-    if c.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"only SwiGLU (silu) MLPs: {c['hidden_act']}")
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab=c["vocab_size"], head_dim=c["head_dim"],
-        qk_norm=bool(c.get("qk_norm", False)),
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        dtype=c["torch_dtype"], act="swiglu")
-
-
 def make_weights(cell, seed: int, shardings=None):
     """The canonical draw (for the reference) or the program's tree, in
-    one jitted call on the device."""
+    one jitted call on the device, as the cell's architecture module
+    lays them out."""
     import jax
     import jax.numpy as jnp
-    c = cell.config
+    arch, c = cell.arch, cell.config
     dt = jnp.dtype(c["torch_dtype"])
-    qk = bool(c.get("qk_norm", False))
     sd = float(c["initializer_range"])
-    shape = cell.shape
 
     def canon(key):
-        return W.canonical(shape, qk, key, dt, sd)
+        return arch.canonical(c, key, dt, sd)
 
     def program(key):
-        return W.to_program(canon(key), qk)
+        return arch.to_program(canon(key), c)
 
     return canon, program, jax.jit(program, out_shardings=shardings)
 
 
 def reference_weights(cell, seed: int, devices):
     """The canonical draw for the reference, spread over ``devices``:
-    every matrix split along its last (output) axis where it divides,
-    the RMSNorm gains whole on each.  The values are those of the draw
-    on one device (the random bits are made whole and then split), so
-    a cell on four chips checks against the same weights as on one,
-    with a quarter of them on each chip."""
+    each leaf split along the axis the architecture module's ``spread``
+    names, or whole on each.  The values are those of the draw on one
+    device (the random bits are made whole and then split), so a cell on
+    four chips checks against the same weights as on one, with a quarter
+    of them on each chip."""
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     canon, _, _ = make_weights(cell, seed)
@@ -101,10 +83,12 @@ def reference_weights(cell, seed: int, devices):
     n = len(devices)
 
     def place(name, leaf):
-        split = (not W.is_gain(name) and leaf.ndim >= 2
-                 and leaf.shape[-1] % n == 0)
-        return NamedSharding(mesh, P(*[None] * (leaf.ndim - 1), "chips")
-                             if split else P())
+        axis = cell.arch.spread(name, leaf, n)
+        if axis is None:
+            return NamedSharding(mesh, P())
+        spec = [None] * leaf.ndim
+        spec[axis] = "chips"
+        return NamedSharding(mesh, P(*spec))
 
     shapes = jax.eval_shape(canon, W.seed_key(0))
     out = {k: place(k, v) for k, v in shapes.items()}
@@ -205,7 +189,7 @@ class Runner:
         from repro.launch import steps as S
         from repro.launch.serve import make_engine, sharded_runtime
         cell = self.cell
-        cfg = model_config(cell.config)
+        cfg = cell.arch.model_config(cell.config)
         mesh, _, rt = sharded_runtime(cell.chips, kernel_ops=True,
                                       planner=True)
         self.mesh = mesh

@@ -1,24 +1,21 @@
 """Random weights from the seed, made on the device in one jitted call.
 
-``canonical`` draws the published parametrisation of a dense GQA
-decoder: matrices with the source's ``initializer_range`` as their
-standard deviation, RMSNorm gains ``g`` near 1 (drawn, not all ones, so
-that every norm's weight is exercised).  Matrices are drawn in float32
-and rounded once to the served dtype; gains are rounded to the same
-dtype and kept in float32.  The reference and the program's adapter
-both start from this draw, so they see identical values.
-
-``to_program`` lays the draw out as the program's ``LM.init_params``
-tree.  The program keeps an RMSNorm weight ``w`` and scales by
-``1 + w`` (``models/layers.py``); ``w = g - 1`` is exact in float32
-for ``g`` in [0.5, 2], which the clipped draw guarantees.
+``draw`` makes the published parametrisation from a table of leaf
+shapes, which each architecture module (``arch/<name>.py``) gives:
+matrices with the source's ``initializer_range`` as their standard
+deviation, RMSNorm gains ``g`` near 1 (drawn, not all ones, so that
+every norm's weight is exercised).  Matrices are drawn in float32 and
+rounded once to the served dtype; gains are rounded to the same dtype
+and kept in float32.  The reference and the program's adapter (the
+module's ``to_program``) both start from this draw, so they see
+identical values.  A program that scales by ``1 + w`` gets
+``w = g - 1``, exact in float32 for ``g`` in [0.5, 2], which the
+clipped draw guarantees.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from .costs import Shape
 
 GAIN_SD = 0.1
 
@@ -31,29 +28,15 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def leaf_shapes(s: Shape, qk_norm: bool) -> dict:
-    L, d, f = s.layers, s.d_model, s.d_ff
-    hd, kvd = s.heads * s.head_dim, s.kv_heads * s.head_dim
-    shapes = {
-        "embed": (s.vocab, d), "lm_head": (d, s.vocab),
-        "final_norm": (d,),
-        "attn_norm": (L, d), "wq": (L, d, hd), "wk": (L, d, kvd),
-        "wv": (L, d, kvd), "wo": (L, hd, d), "mlp_norm": (L, d),
-        "w_gate": (L, d, f), "w_up": (L, d, f), "w_down": (L, f, d),
-    }
-    if qk_norm:
-        shapes["q_norm"] = (L, s.head_dim)
-        shapes["k_norm"] = (L, s.head_dim)
-    return shapes
-
-
 def is_gain(name: str) -> bool:
     return name.endswith("norm")
 
 
-def canonical(s: Shape, qk_norm: bool, key, dtype, init_sd: float) -> dict:
+def draw(shapes: dict, key, dtype, init_sd: float) -> dict:
+    """One leaf per entry of ``shapes``, the i-th name in sorted order
+    drawn from ``fold_in(key, i)``."""
     out = {}
-    for i, (name, shape) in enumerate(sorted(leaf_shapes(s, qk_norm).items())):
+    for i, (name, shape) in enumerate(sorted(shapes.items())):
         z = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
         if is_gain(name):
             g = 1.0 + GAIN_SD * jnp.clip(z, -4.0, 4.0)
@@ -61,22 +44,6 @@ def canonical(s: Shape, qk_norm: bool, key, dtype, init_sd: float) -> dict:
         else:
             out[name] = (z * init_sd).astype(dtype)
     return out
-
-
-def to_program(c: dict, qk_norm: bool) -> dict:
-    """The program's parameter tree for a uniform stack of attention
-    layers (one scanned super-block of pattern ``("attn",)``)."""
-    mix = {"wq": c["wq"], "wk": c["wk"], "wv": c["wv"], "wo": c["wo"]}
-    if qk_norm:
-        mix["q_norm"] = c["q_norm"] - 1.0
-        mix["k_norm"] = c["k_norm"] - 1.0
-    layer = {"ln1": {"w": c["attn_norm"] - 1.0}, "mix": mix,
-             "ln2": {"w": c["mlp_norm"] - 1.0},
-             "ff": {"w_gate": c["w_gate"], "w_up": c["w_up"],
-                    "w_down": c["w_down"]}}
-    return {"embed": c["embed"], "final_norm": {"w": c["final_norm"] - 1.0},
-            "lm_head": c["lm_head"], "stack": {"b0_attn": layer},
-            "tail": []}
 
 
 def check_layout(made, expected) -> None:

@@ -1,11 +1,11 @@
 """Share of the roofline bound reached by the fused MLP chain: per call
 the gate, up and down weights plus the rows' activations, 6 rows d_model
-d_ff flops (``costs``), over the device time of every op under
-``jit(fused_mlp_chain)``, averaged over the chips.  Rows are the active
-slots of a decode step and the real prompt tokens of a prefill whose
-plan fused the chain.  On a mesh each chip holds d_ff / chips of every
-chain and is charged that share."""
-from harness import costs, peaks, reading
+d_ff flops (the architecture module's ``mlp_chain``), over the device
+time of every op under ``jit(fused_mlp_chain)``, averaged over the
+chips.  Rows are the active slots of a decode step and the real prompt
+tokens of a prefill whose plan fused the chain.  On a mesh each chip
+holds d_ff / chips of every chain and is charged that share."""
+from harness import peaks, reading
 
 
 def read(rec):
@@ -14,7 +14,6 @@ def read(rec):
     t = reading.kernel_time(rec, reading.MLP_CHAIN)
     if t <= 0:
         return None
-    shape = rec.cell.shape
     steps = reading.traced_steps(rec)
     rows = [len(s.ctx) for s in steps if s.ctx]
     if not any(reading.kernel_in_module(rec, reading.MLP_CHAIN,
@@ -25,9 +24,10 @@ def read(rec):
     rows += [p for p, f in zip(prefills, fused) if f]
     if not rows:
         return None
+    chain = reading.cost(rec, "mlp_chain", "mlp_chain_roofline")
     flops = nbytes = 0.0
     for m in rows:
-        f, b = costs.mlp_chain(shape, m)
+        f, b = chain(m)
         flops, nbytes = flops + f, nbytes + b
     # each chip runs its own d_ff / chips share of every layer's chain
     flops, nbytes = flops / rec.chips, nbytes / rec.chips

@@ -1,8 +1,9 @@
 """Model FLOPs of the real tokens the step programs processed (prompt
 tokens of each prefill, active slots of each decode; padding and idle
-slots do not count; ``costs``) over the device time of the decode and
-prefill programs times chips times the chip's bf16 peak, in percent."""
-from harness import costs, reading
+slots do not count), as the cell's architecture module counts them,
+over the device time of the decode and prefill programs times chips
+times the chip's bf16 peak, in percent."""
+from harness import reading
 
 
 def read(rec):
@@ -11,11 +12,12 @@ def read(rec):
     t = sum(reading.module_time(rec, m)[0] for m in reading.STEP_MODULES)
     if t <= 0:
         return None
-    shape = rec.cell.shape
+    decode = reading.cost(rec, "decode_model_flops", "step_mfu")
+    prefill = reading.cost(rec, "prefill_model_flops", "step_mfu")
     flops = 0.0
     for s in reading.traced_steps(rec):
         if s.ctx:
-            flops += costs.decode_model_flops(shape, s.ctx)
+            flops += decode(s.ctx)
         for p in s.prefills:
-            flops += costs.prefill_model_flops(shape, p)
+            flops += prefill(p)
     return 100.0 * flops / (t * rec.chips * rec.peaks.bf16_flops)

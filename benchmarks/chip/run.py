@@ -144,7 +144,7 @@ def execute(cell, seed: int, seconds: float, trace: bool, devs,
     del rec
     t = time.perf_counter()
     w = serve_loop.reference_weights(cell, seed, devs)
-    gap, ctl, n_tok = check.gaps(w, cell.config, seqs, control)
+    gap, ctl, n_tok = check.gaps(w, cell, seqs, control)
     del w
     log(f"reference over {len(seqs)} requests, {n_tok} served tokens, "
         f"{time.perf_counter() - t:.3f}s on {len(devs)} chips; the "

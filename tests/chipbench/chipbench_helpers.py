@@ -14,7 +14,8 @@ if str(CHIP) not in sys.path:
     sys.path.insert(0, str(CHIP))
 
 TINY_CONFIG = {
-    "name": "tiny", "source": "test", "hidden_size": 64,
+    "name": "tiny", "source": "test",
+    "architectures": ["Qwen3ForCausalLM"], "hidden_size": 64,
     "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2,
     "vocab_size": 512, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
@@ -36,12 +37,19 @@ TINY_CELL = {"slots": 4, "page_size": 8, "rate_per_s": 20.0,
 def tiny_cell(config=None, traffic=None, params=None, name="tiny.chat"):
     from harness import spec
     bench = spec.load_benchmark()
+    config = dict(config or TINY_CONFIG)
     return spec.Cell(
-        name=name, chips=1, config=dict(config or TINY_CONFIG),
+        name=name, chips=1, config=config,
         traffic=copy.deepcopy(traffic or TINY_CHAT),
         params=dict(params or TINY_CELL),
         end_to_end=tuple(bench["end_to_end"]),
-        per_layer=tuple(bench["per_layer"]))
+        per_layer=tuple(bench["per_layer"]),
+        arch=spec.architecture(config))
+
+
+def arch_id(module) -> str:
+    """A module of ``harness/arch/`` by its file name, for test ids."""
+    return Path(module.__file__).stem
 
 
 def write_tree(root: Path, bench: dict, files: dict) -> None:
@@ -124,3 +132,29 @@ class FakeEngine:
                 done.append(_Done(s.rid, list(s.generated)))
                 self.slots[i] = None
         return done
+
+
+def recorded(trace: str, cell):
+    """A traced run's record around a trace recorded on a TPU v5e
+    (``data/<trace>.xplane.pb``, ``record_trace.py``) as ``cell`` would
+    read it: the three ``bench.step`` spans are the traced steps (a
+    64-token prefill, then one slot decoding at contexts 65, 66, 67), and
+    one request, due 10 ms before the window's first step, is admitted
+    by it and served a token at the end of each."""
+    from harness import peaks, serve_loop, trace_reduce
+    data = Path(__file__).resolve().parent / "data"
+    meta = json.loads((data / f"{trace}_hlo_meta.json").read_text())
+    red = trace_reduce.reduce(str(data / f"{trace}.xplane.pb"), meta)
+    spans = [s for s in red.spans if s.name == "bench.step"]
+    steps = [serve_loop.Step(s.start, s.end, [64] if i == 0 else [],
+                             [65 + i]) for i, s in enumerate(spans)]
+    t_start = red.window[0] - 0.010
+    req = serve_loop.Req(0, t_start, 64, 8)
+    req.admit_step, req.times = 0, [s.end for s in spans]
+    rec = serve_loop.Record(
+        cell=cell, seed=0, chips=1, t_start=t_start, t_end=red.window[1],
+        setup_s=1.0, requests=[req], steps=steps, counters_start={},
+        counters_end={}, compiles_in_window=0, trace=red,
+        traced_steps=(0, len(steps)))
+    rec.peaks = peaks.for_kind("TPU v5 lite")
+    return rec

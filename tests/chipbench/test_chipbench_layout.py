@@ -1,16 +1,20 @@
 """Every cell, configuration, mix and metric of ``BENCHMARK.json`` loads by
 name, the file keeps to the benchmark's contract whatever cells it holds,
-and a new cell, on one chip or four, is added with new files and entries
-alone."""
+and a new cell, on one chip or four, or a new architecture, is added with
+new files and entries alone."""
 import copy
+import dataclasses
+import hashlib
 import json
 import shutil
+from pathlib import Path
 
+import jax
 import pytest
 
-from chipbench_helpers import CHIP, ROOT, write_tree
+from chipbench_helpers import CHIP, ROOT, recorded, write_tree
 
-from harness import spec
+from harness import serve_loop, spec, weights as W
 
 BENCH = spec.load_benchmark()
 CONTRACT_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
@@ -127,8 +131,9 @@ def _add_cell(tmp_path, chips: int):
     """A configuration, a mix, a metric and a cell added as new files and
     new entries to a copy of the benchmark; no existing file changes."""
     chip = tmp_path / "benchmarks" / "chip"
-    for part in ("metrics", "configs", "traffic", "cells"):
-        shutil.copytree(CHIP / part, chip / part)
+    for part in ("metrics", "configs", "traffic", "cells", "harness/arch"):
+        shutil.copytree(CHIP / part, chip / part,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     bench = copy.deepcopy(BENCH)
     base = json.loads((CHIP / "configs" / "qwen3-8b-4L.json").read_text())
     name = f"other-8L-c{chips}"
@@ -165,7 +170,8 @@ def _check_added(tmp_path, bench, chip, cell, chips):
     assert contract_problems(bench, tmp_path, chip) == []
     c = spec.load_cell(cell, bench, chip)
     assert c.chips == chips == c.config["chips"]
-    assert c.shape.layers == 8 and c.traffic["name"] == "steady"
+    assert c.config["num_hidden_layers"] == 8
+    assert c.traffic["name"] == "steady"
     assert [m["name"] for m in c.per_layer][-1] == "slots_busy"
     assert spec.metric_reader("slots_busy", chip)(None) == 42.0
     assert all(m["name"] != "queue_wait_p95_ms" for m in c.per_layer)
@@ -224,8 +230,112 @@ def test_the_held_back_four_chip_cell_needs_entries_alone():
         "moves": "itl_p50_ms", "workloads": [cell]})
     assert contract_problems(bench) == []
     c = spec.load_cell(cell, bench)
-    assert c.chips == c.config["chips"] == 4 and c.shape.layers == 36
+    assert c.chips == c.config["chips"] == 4
+    assert c.config["num_hidden_layers"] == 36
     assert c.config["reduced"] == {} and c.params["slots"] == 32
     assert c.params["rate_per_s"] == 1.76
     assert [m["name"] for m in c.per_layer][-1] == \
         "collective_exposed_ms_per_step"
+
+
+def _files(root: Path) -> dict:
+    return {p.relative_to(root): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in sorted(root.rglob("*")) if p.is_file()
+            and "__pycache__" not in p.parts}
+
+
+# The new module: the dense decoder's arithmetic under another name,
+# whose model FLOPs count twice, so that ``step_mfu`` shows whose costs
+# it read.
+TWICE = """
+
+_decode, _prefill = decode_model_flops, prefill_model_flops
+
+
+def decode_model_flops(config, ctx_lens):
+    return 2.0 * _decode(config, ctx_lens)
+
+
+def prefill_model_flops(config, prompt_len):
+    return 2.0 * _prefill(config, prompt_len)
+"""
+
+
+def test_a_new_architecture_is_data_only(tmp_path):
+    """An architecture module, a configuration that names it, a cell and
+    entries added to a copy of the benchmark: the cell loads with the new
+    module, which builds the program's ``ModelConfig``, draws weights in
+    the program's layout and prices a recorded trace's ``step_mfu``; no
+    file of the benchmark changes."""
+    chip = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(CHIP, chip, ignore=shutil.ignore_patterns("__pycache__"))
+    before = _files(chip)
+    module = (CHIP / "harness" / "arch" / "dense_gqa.py").read_text()
+    module = module.replace(
+        'ARCHITECTURES = ("Qwen3ForCausalLM", "MistralForCausalLM")',
+        'ARCHITECTURES = ("ToyDecoderForCausalLM",)').replace(
+        '["Qwen3ForCausalLM"]', '["ToyDecoderForCausalLM"]') + TWICE
+    base = json.loads((CHIP / "configs" / "qwen3-8b-4L.json").read_text())
+    name, cell = "toy-4L", "toy-4L.chat"
+    bench = copy.deepcopy(BENCH)
+    bench["configs"].append({"name": name, "source": base["source"],
+                             "file": f"benchmarks/chip/configs/{name}.json",
+                             "reduced": ["num_hidden_layers"], "why": "t"})
+    bench["workloads"].append({"name": cell, "config": name,
+                               "traffic": "chat", "chips": 1, "why": "t"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "qwen3-8b-4L.chat" in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    write_tree(tmp_path, bench, {
+        "benchmarks/chip/harness/arch/toy_decoder.py": module,
+        f"benchmarks/chip/configs/{name}.json": dict(
+            base, name=name, architectures=["ToyDecoderForCausalLM"]),
+        f"benchmarks/chip/cells/{cell}.json":
+            (CHIP / "cells" / "qwen3-8b-4L.chat.json").read_text()})
+    after = _files(chip)
+    assert {k: v for k, v in after.items() if k in before} == before
+    assert sorted(set(after) - set(before)) == [
+        Path(f"cells/{cell}.json"), Path(f"configs/{name}.json"),
+        Path("harness/arch/toy_decoder.py")]
+
+    bench = spec.load_benchmark(tmp_path)
+    assert contract_problems(bench, tmp_path, chip) == []
+    c = spec.load_cell(cell, bench, chip)
+    arch = c.arch
+    assert Path(arch.__file__) == chip / "harness" / "arch" / "toy_decoder.py"
+    assert arch.ARCHITECTURES == ("ToyDecoderForCausalLM",)
+    cfg = arch.model_config(c.config)
+    assert (cfg.n_layers, cfg.d_model, cfg.d_ff) == (4, 4096, 12288)
+    from repro.models.lm import LM
+    small = dataclasses.replace(c, config=arch.SMOKE)
+    _, program, _ = serve_loop.make_weights(small, 3)
+    W.check_layout(jax.eval_shape(program, W.seed_key(0)),
+                   LM(arch.model_config(arch.SMOKE)).abstract_params())
+    params = jax.jit(program)(W.seed_key(2**31 + 3))
+    assert all(x.size for x in jax.tree.leaves(params))
+    mfu = spec.metric_reader("step_mfu", chip)
+    qwen = spec.load_cell("qwen3-8b-4L.chat", bench, chip)
+    assert spec.architecture(qwen.config, chip) is not arch
+    twice, once = mfu(recorded("decode", c)), mfu(recorded("decode", qwen))
+    assert once > 0 and twice == pytest.approx(2 * once, rel=1e-12)
+
+
+def test_an_architecture_no_module_or_two_modules_claim_raises(tmp_path):
+    """A configuration's ``architectures[0]`` must be claimed by exactly
+    one module of ``harness/arch/``; the error names the value."""
+    chip = tmp_path / "chip"
+    shutil.copytree(CHIP / "harness" / "arch", chip / "harness" / "arch",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    cfg = json.loads((CHIP / "configs" / "qwen3-8b-4L.json").read_text())
+    assert spec.architecture(cfg, chip).ARCHITECTURES[0] == "Qwen3ForCausalLM"
+    with pytest.raises(LookupError, match="'MellumForCausalLM'.* 0 modules"):
+        spec.architecture(dict(cfg, architectures=["MellumForCausalLM"]),
+                          chip)
+    with pytest.raises(LookupError, match="None.* 0 modules"):
+        spec.architecture({k: v for k, v in cfg.items()
+                           if k != "architectures"}, chip)
+    shutil.copy(chip / "harness" / "arch" / "dense_gqa.py",
+                chip / "harness" / "arch" / "dense_gqa_twin.py")
+    with pytest.raises(LookupError,
+                       match="'Qwen3ForCausalLM'.* 2 modules .*dense_gqa_twin"):
+        spec.architecture(cfg, chip)

@@ -1,13 +1,24 @@
-"""Metric arithmetic against values worked out by hand."""
+"""Metric arithmetic against values worked out by hand, and every
+per-layer reading of the traces recorded on the chip."""
 import types
 
 import pytest
 
-from chipbench_helpers import tiny_cell
+from chipbench_helpers import TINY_CONFIG, recorded, tiny_cell
 
-from harness import costs, peaks, reading, serve_loop, spec, trace_reduce
+from harness import peaks, reading, serve_loop, spec, trace_reduce
 
 Req, Step = serve_loop.Req, serve_loop.Step
+DENSE = spec.architecture(TINY_CONFIG)
+
+
+def dense(layers, d_model, heads, kv_heads, head_dim, d_ff, vocab):
+    """A dense GQA configuration (HF keys) and its cell as a reader sees
+    it."""
+    cfg = dict(TINY_CONFIG, num_hidden_layers=layers, hidden_size=d_model,
+               num_attention_heads=heads, num_key_value_heads=kv_heads,
+               head_dim=head_dim, intermediate_size=d_ff, vocab_size=vocab)
+    return cfg, types.SimpleNamespace(arch=DENSE, config=cfg)
 
 
 def read(name, rec):
@@ -93,11 +104,11 @@ def _trace():
 
 
 def test_device_metrics_from_a_trace():
-    shape = costs.Shape(layers=2, d_model=8, heads=2, kv_heads=1,
-                        head_dim=4, d_ff=16, vocab=32)
+    cfg, cell = dense(layers=2, d_model=8, heads=2, kv_heads=1,
+                      head_dim=4, d_ff=16, vocab=32)
     steps = [Step(0.0, 4.5, [], [3, 5]), Step(4.5, 8.0, [10], [])]
     rec = record([], steps, _trace(), (0, 2))
-    rec.cell = types.SimpleNamespace(shape=shape)
+    rec.cell = cell
     # busy 4 + 1 of 8 s
     assert read("device_idle_share", rec) == pytest.approx(37.5)
     # idle inside steps: 0.5 + 2.5 over 2 steps
@@ -110,24 +121,45 @@ def test_device_metrics_from_a_trace():
     want = 100 * max(flops / 100.0, nbytes / 10.0) / 2.0
     assert read("paged_attn_roofline", rec) == pytest.approx(want)
     # mlp: decode rows 2, prefill rows 10, over 1.5 s of kernel time
-    f1, b1 = costs.mlp_chain(shape, 2)
-    f2, b2 = costs.mlp_chain(shape, 10)
+    f1, b1 = DENSE.mlp_chain(cfg, 2)
+    f2, b2 = DENSE.mlp_chain(cfg, 10)
     want = 100 * max((f1 + f2) / 100.0, (b1 + b2) / 10.0) / 1.5
     assert read("mlp_chain_roofline", rec) == pytest.approx(want)
-    flops = (costs.decode_model_flops(shape, [3, 5])
-             + costs.prefill_model_flops(shape, 10))
+    flops = (DENSE.decode_model_flops(cfg, [3, 5])
+             + DENSE.prefill_model_flops(cfg, 10))
     assert read("step_mfu", rec) == pytest.approx(100 * flops / (5.0 * 100))
 
 
 def test_costs_by_hand():
-    s = costs.Shape(layers=1, d_model=4, heads=2, kv_heads=1, head_dim=2,
-                    d_ff=8, vocab=10)
+    cfg, _ = dense(layers=1, d_model=4, heads=2, kv_heads=1, head_dim=2,
+                   d_ff=8, vocab=10)
+    s = DENSE.shape_of(cfg)
     assert s.layer_matmul_params == 4 * 4 + 2 * 4 * 2 + 4 * 4 + 3 * 4 * 8
-    assert costs.paged_attention(s, [1, 3]) == (4 * 4 * 2 * 2,
-                                                (2 * 4 * 2 * 2)
-                                                + 2 * 2 * 4 * 2)
-    assert costs.prefill_model_flops(s, 2) == (
+    assert DENSE.paged_attention(cfg, [1, 3]) == (4 * 4 * 2 * 2,
+                                                  (2 * 4 * 2 * 2)
+                                                  + 2 * 2 * 4 * 2)
+    assert DENSE.prefill_model_flops(cfg, 2) == (
         2 * 2 * s.layer_matmul_params + 4 * 3 * 2 * 2 + 2 * 4 * 10)
+
+
+def test_a_module_without_a_kernels_costs_raises_naming_it():
+    """A reader that has kernel time to charge and no cost function in
+    the cell's architecture module raises, naming the module and the
+    metric; it never reads 0."""
+    cfg, _ = dense(layers=2, d_model=8, heads=2, kv_heads=1, head_dim=4,
+                   d_ff=16, vocab=32)
+    bare = types.SimpleNamespace(
+        __file__="harness/arch/bare.py",
+        decode_model_flops=DENSE.decode_model_flops,
+        prefill_model_flops=DENSE.prefill_model_flops)
+    steps = [Step(0.0, 4.5, [], [3, 5]), Step(4.5, 8.0, [10], [])]
+    rec = record([], steps, _trace(), (0, 2))
+    rec.cell = types.SimpleNamespace(arch=bare, config=cfg)
+    for metric in ("mlp_chain_roofline", "paged_attn_roofline"):
+        with pytest.raises(NotImplementedError,
+                           match=f"{metric}: .*bare.py"):
+            read(metric, rec)
+    assert read("step_mfu", rec) > 0
 
 
 def test_roofline_names_its_bound_and_refuses_zero_time():
@@ -154,13 +186,13 @@ def test_rooflines_charge_each_chip_its_share():
     """The same trace read as a four-chip cell's: each chip holds a
     quarter of the heads and of d_ff, so each kernel's needed work per
     chip, and its share of the roofline, is a quarter of one chip's."""
-    shape = costs.Shape(layers=2, d_model=8, heads=4, kv_heads=4,
-                        head_dim=4, d_ff=16, vocab=32)
+    _, cell = dense(layers=2, d_model=8, heads=4, kv_heads=4, head_dim=4,
+                    d_ff=16, vocab=32)
     steps = [Step(0.0, 4.5, [], [3, 5]), Step(4.5, 8.0, [10], [])]
     one = record([], steps, _trace(), (0, 2), chips=1)
     four = record([], steps, _trace(), (0, 2), chips=4)
     for rec in (one, four):
-        rec.cell = types.SimpleNamespace(shape=shape)
+        rec.cell = cell
     # attention: 8 positions, 4 q-heads of 4, 2 layers, over 2 s
     flops = 4 * 8 * 4 * 4 * 2
     nbytes = (2 * 8 * 4 * 4 * 2 + 2 * 2 * 4 * 4 * 2) * 2
@@ -231,3 +263,55 @@ def test_exposed_collectives_per_decode_step():
     # one chip's trace, no collective: nothing to read
     assert read("collective_exposed_ms_per_step", record([], (), _trace(),
                                                           (0, 2))) is None
+
+
+# Every per-layer reading of the recorded traces (``recorded``) as the
+# harness read them before the architecture modules, by repr.
+RECORDED = {
+    ("decode", "qwen3-8b-4L.chat"): {
+        "sched_idle_ms_per_step": "3.5506849999999908",
+        "queue_wait_p95_ms": "10.000000000000002",
+        "decode_step_ms": "11.236072666666667",
+        "prefill_ms_per_ktok": "174.77439062500005",
+        "paged_attn_roofline": "(1.1063890591736816, 'memory-bound')",
+        "mlp_chain_roofline": "(46.05555594990408, 'memory-bound')",
+        "device_idle_share": "19.175388568056107",
+        "step_mfu": "1.2272928019104579",
+    },
+    ("decode", "mistral-nemo-12b-4L.reason"): {
+        "sched_idle_ms_per_step": "3.5506849999999908",
+        "decode_step_ms": "11.236072666666667",
+        "paged_attn_roofline": "(1.1063890591736816, 'memory-bound')",
+        "mlp_chain_roofline": "(67.15564101286661, 'memory-bound')",
+        "device_idle_share": "19.175388568056107",
+        "step_mfu": "1.7146797228181587",
+    },
+    ("decode_spans", "qwen3-8b-4L.chat"): {
+        "sched_idle_ms_per_step": "3.7358136666666684",
+        "queue_wait_p95_ms": "10.000000000000002",
+        "decode_step_ms": "11.233570666666667",
+        "prefill_ms_per_ktok": "174.82464062499994",
+        "paged_attn_roofline": "(1.105829891451103, 'memory-bound')",
+        "mlp_chain_roofline": "(46.05450709828453, 'memory-bound')",
+        "device_idle_share": "19.97986480138304",
+        "step_mfu": "1.2274100918649105",
+    },
+    ("decode_spans", "mistral-nemo-12b-4L.reason"): {
+        "sched_idle_ms_per_step": "3.7358136666666684",
+        "decode_step_ms": "11.233570666666667",
+        "paged_attn_roofline": "(1.105829891451103, 'memory-bound')",
+        "mlp_chain_roofline": "(67.15411163597852, 'memory-bound')",
+        "device_idle_share": "19.97986480138304",
+        "step_mfu": "1.714843591380149",
+    },
+}
+
+
+@pytest.mark.parametrize("trace,cell", sorted(RECORDED),
+                         ids=lambda v: v.replace(".", "_"))
+def test_every_reading_of_the_recorded_traces_is_as_before(trace, cell):
+    c = spec.load_cell(cell)
+    rec = recorded(trace, c)
+    got = {m["name"]: repr(spec.metric_reader(m["name"])(rec))
+           for m in c.per_layer}
+    assert got == RECORDED[(trace, cell)]
