@@ -269,7 +269,7 @@ def paged_ring_decode_attention(q, k_pages, v_pages, page_table,
     pages — the kv reduction axis at page granularity) sharded over the
     tp-or-model axis (docs/serving.md).
 
-    q: (B, Hq, 1, D); k_pages/v_pages: (n_pages, Hkv, ps, D) — the
+    q: (B, Hq, 1, D); k_pages/v_pages: (n_pages, ps, Hkv, D) — the
     pools stay replicated (every shard holds them; the engine's writes
     land identically on each replica), but each shard *gathers* only
     its ``max_pages / n_shards`` slice of every request's table, so the
@@ -293,7 +293,7 @@ def paged_ring_decode_attention(q, k_pages, v_pages, page_table,
     axis = rules.model
     n_shards = mesh.shape[axis]
     b, hq, m, d = q.shape
-    hkv, ps = k_pages.shape[1], k_pages.shape[2]
+    ps, hkv = k_pages.shape[1], k_pages.shape[2]
     group = hq // hkv
     mp = page_table.shape[1]
     assert mp % n_shards == 0, (mp, n_shards)

@@ -11,8 +11,8 @@ that it fixes K == H and never tunes the reduction tiling.
 
 Supports GQA (kv-head sharing via BlockSpec index maps), causal and
 sliding-window masks, and decode (queries at the tail of the cache).
-Paged decode reads each slot's live pages in place from the page pool
-(``_paged_decode_kernel``).
+Paged decode reads each slot's live pages in place from its layer of
+the stacked page pool (``_paged_decode_kernel``).
 """
 from __future__ import annotations
 
@@ -326,12 +326,12 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     )(q, k, v)
 
 
-def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
+def _paged_decode_kernel(layer_ref, tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
                          o_ref, l_ref, k_buf, v_buf, sems,
                          o_acc, m_sc, l_sc, *, max_pages, page_size,
                          bkv, group, window, scale):
     """One slot's decode row per q-head over its live pages, read in
-    place from the pool.
+    place from layer ``layer_ref[0]`` of the stacked pool.
 
     Block ``j`` covers kv columns ``j*bkv .. (j+1)*bkv - 1``: its
     ``bkv // page_size`` pages are copied whole (every kv-head at once)
@@ -348,6 +348,7 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
     row = len_ref[b] - 1                  # the query's global position
     n_blocks = jnp.clip((row + bkv) // bkv, 0, max_pages // ppb)
     first = b * max_pages                 # row b of the flat page table
+    layer = layer_ref[0]
 
     def copies(j, slot):
         out = []
@@ -356,9 +357,11 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
             page = jnp.maximum(tbl_ref[first + j * ppb + p], 0)
             cols = pl.ds(p * page_size, page_size)
             out.append(pltpu.make_async_copy(
-                k_hbm.at[page], k_buf.at[slot, cols], sems.at[0, slot]))
+                k_hbm.at[layer, page], k_buf.at[slot, cols],
+                sems.at[0, slot]))
             out.append(pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[slot, cols], sems.at[1, slot]))
+                v_hbm.at[layer, page], v_buf.at[slot, cols],
+                sems.at[1, slot]))
         return out
 
     o_acc[...] = jnp.zeros_like(o_acc)
@@ -421,14 +424,14 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm,
     l_ref[0] = jnp.where(dead, 0.0, l_sc[:, :, :1])
 
 
-def _paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
-                            bkv, window, scale, interpret):
+def _paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
+                            layer, *, bkv, window, scale, interpret):
     """``(o_unnorm, l_run)`` of one query row per q-head over the live
-    pages of each slot: grid ``(B,)``, the page table and lengths as
-    scalar prefetch, the pools, ``(n_pages, page_size, Hkv, D)``, left
-    in HBM and copied page by page."""
+    pages of each slot: grid ``(B,)``, the layer index, the page table
+    and lengths as scalar prefetch, the stacked pools, ``(L, n_pages,
+    page_size, Hkv, D)``, left in HBM and copied page by page."""
     b, hq, _, d = q.shape
-    _, ps, hkv, dv = v_pages.shape
+    ps, hkv, dv = v_pages.shape[2:]
     max_pages = page_table.shape[1]
     kernel = functools.partial(
         _paged_decode_kernel, max_pages=max_pages, page_size=ps, bkv=bkv,
@@ -436,7 +439,7 @@ def _paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((1, hq, 1, d), lambda b_, *_: (b_, 0, 0, 0)),
@@ -466,7 +469,8 @@ def _paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         ),
         interpret=interpret,
         name="paged_decode_attention",
-    )(page_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      page_table.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
       q, k_pages, v_pages)
 
 
@@ -474,7 +478,7 @@ def _paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     "bq", "bkv", "window", "scale", "pages_per_chunk", "interpret"))
 def fused_attention_paged(q: jax.Array, k_pages: jax.Array,
                           v_pages: jax.Array, page_table: jax.Array,
-                          lengths: jax.Array,
+                          lengths: jax.Array, layer: jax.Array | None = None,
                           bq: int = 128, bkv: int = 128,
                           window: int = 0, scale: float | None = None,
                           pages_per_chunk: int = 0,
@@ -484,10 +488,13 @@ def fused_attention_paged(q: jax.Array, k_pages: jax.Array,
     q: (B, Hq, M, D) — request b's query rows sit at the TAIL of its
     context, global positions ``lengths[b]-M .. lengths[b]-1`` (the
     serving decode convention; attention is causal by construction).
-    k_pages/v_pages: (n_pages, Hkv, page_size, D/Dv), the shared page
-    pool (``serving.kv_pages``); page_table: (B, max_pages) int32
-    physical page per logical page, -1 = unallocated; lengths: (B,)
-    int32 context length per request.
+    k_pages/v_pages: (L, n_pages, page_size, Hkv, D/Dv), the stacked
+    page pools of a layer scan (``serving.kv_pages``), read at layer
+    ``layer`` (int32 scalar, traceable) in place; an unstacked pool
+    (n_pages, page_size, Hkv, D/Dv) with ``layer=None`` is read as layer
+    0 of a stack of one.  page_table: (B, max_pages) int32 physical page
+    per logical page, -1 = unallocated; lengths: (B,) int32 context
+    length per request.
 
     Decode (``M == 1``, one chunk, and a kv block of whole pages) runs
     ``_paged_decode_kernel``: each slot's live pages are read in place,
@@ -513,19 +520,17 @@ def fused_attention_paged(q: jax.Array, k_pages: jax.Array,
     from ..serving.kv_pages import gather_pages, paged_kv_positions
 
     b, hq, m, d = q.shape
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    layer = jnp.asarray(layer, jnp.int32)
     ps = k_pages.shape[2]
     max_pages = page_table.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     blk = _divisor_block(bkv, max_pages * ps)
     if m == 1 and pages_per_chunk == 0 and blk % ps == 0:
-        # pages go in as (page_size, Hkv, D), the order XLA lays the
-        # pool out in for the serving step's kv scatter: there the
-        # transpose is a bitcast, while a kernel reading (Hkv,
-        # page_size, D) pages makes XLA copy the layer's whole pool
         o, l_run = _paged_decode_attention(
-            q, k_pages.transpose(0, 2, 1, 3), v_pages.transpose(0, 2, 1, 3),
-            page_table, lengths, bkv=blk,
+            q, k_pages, v_pages, page_table, lengths, layer, bkv=blk,
             window=window, scale=scale, interpret=interpret)
         return finalize_partials(o, l_run, q.dtype)
     q_pos = (lengths.astype(jnp.int32)[:, None] - m
@@ -539,8 +544,8 @@ def fused_attention_paged(q: jax.Array, k_pages: jax.Array,
     state = None
     for c0 in range(0, page_table.shape[1], cpp):
         tbl = page_table[:, c0:c0 + cpp]                    # (B, C)
-        kc = gather_pages(k_pages, tbl)
-        vc = gather_pages(v_pages, tbl)
+        kc = gather_pages(k_pages, tbl, layer)
+        vc = gather_pages(v_pages, tbl, layer)
         kv_pos = paged_kv_positions(tbl, ps, invalid=INVALID_POS,
                                     first_page=c0)
         part = fused_attention_partial(

@@ -487,13 +487,16 @@ def paged_attention_block(p: dict, x: jax.Array, cfg: ModelConfig,
 
     x: (B, S, D); positions: (B, S) absolute position of each row
     (-1 = masked: prompt padding or an inactive engine slot); cache:
-    ``{"k_pages", "v_pages"}`` of shape (n_pages, Hkv, page_size, dh)
-    — the shared pool, no batch dim; page_table: (B, max_pages)
-    physical page per logical page (-1 = unallocated).
+    ``{"k_pages", "v_pages"}`` of shape (n_pages, page_size, Hkv, dh)
+    — the shared pool, no batch dim — or, inside the layer scan, the
+    stacked pools (L, n_pages, page_size, Hkv, dh) and the scan's
+    ``"layer"`` index; page_table: (B, max_pages) physical page per
+    logical page (-1 = unallocated).
 
     Projections/RoPE/GQA are identical to ``attention_block``; the kv
-    write scatters through ``serving.kv_pages.slot_coords`` (masked
-    rows land on the scratch page) and attention runs over the
+    write scatters this step's rows in place through
+    ``serving.kv_pages.slot_coords`` (masked rows land on the scratch
+    page) and attention runs over the
     page-table gather with per-request positions.  Serving is causal
     by construction.  Three bodies, one semantics (docs/design.md §3):
     the XLA twin (``_paged_positional_attention``), the fused kernel
@@ -503,12 +506,9 @@ def paged_attention_block(p: dict, x: jax.Array, cfg: ModelConfig,
     ``dist_decode`` and a mesh with a model axis that divides the page
     table are present.
     """
-    from ..serving import kv_pages as KP
-
     b, s, d = x.shape
     dh = cfg.dh
     win = cfg.window if window is None else window
-    ps = cache["k_pages"].shape[2]
 
     q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, s, cfg.n_heads, dh)
     k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, s, cfg.n_kv_heads, dh)
@@ -520,11 +520,7 @@ def paged_attention_block(p: dict, x: jax.Array, cfg: ModelConfig,
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
 
-    phys, off = KP.slot_coords(page_table, positions, ps)
-    cache = {
-        "k_pages": KP.scatter_pages(cache["k_pages"], phys, off, k),
-        "v_pages": KP.scatter_pages(cache["v_pages"], phys, off, v),
-    }
+    cache = _write_kv(cache, page_table, positions, k, v)
 
     qt = q.transpose(0, 2, 1, 3)          # (B, Hq, S, dh)
     qt = constrain(qt, rules, "batch", "tp", None, None)
@@ -544,6 +540,21 @@ def paged_attention_block(p: dict, x: jax.Array, cfg: ModelConfig,
     return constrain(out, rules, "batch", "seq", None), cache
 
 
+def _write_kv(cache: dict, page_table: jax.Array, positions: jax.Array,
+              k: jax.Array, v: jax.Array) -> dict:
+    """Scatter this step's k/v rows (B, S, Hkv, dh) into the paged
+    cache at their slots, in the layer ``cache["layer"]`` of a stacked
+    pool when the scan hands one; the rest of the pool is untouched."""
+    from ..serving import kv_pages as KP
+    phys, off = KP.slot_coords(page_table, positions,
+                               cache["k_pages"].shape[-3])
+    layer = cache.get("layer")
+    return dict(
+        cache,
+        k_pages=KP.scatter_pages(cache["k_pages"], phys, off, k, layer),
+        v_pages=KP.scatter_pages(cache["v_pages"], phys, off, v, layer))
+
+
 def _paged_attention_body(qt: jax.Array, cache: dict,
                           page_table: jax.Array, positions: jax.Array,
                           *, group: int, win: int, scale: float,
@@ -560,11 +571,16 @@ def _paged_attention_body(qt: jax.Array, cache: dict,
     is bit-identical to the hand-wired one by construction.
 
     qt: (B, Hq, S, dh) already transposed+constrained; cache holds the
-    POST-write page pools."""
+    POST-write page pools.  Every body reads its layer from the stacked
+    pools at ``cache["layer"]``; an unstacked pool (a tail site) is
+    read as layer 0 of a stack of one."""
     from ..serving import kv_pages as KP
 
     b, _, s, _ = qt.shape
-    ps = cache["k_pages"].shape[2]
+    kp, vp, layer = cache["k_pages"], cache["v_pages"], cache.get("layer")
+    if layer is None:
+        kp, vp, layer = kp[None], vp[None], jnp.int32(0)
+    ps, hkv = kp.shape[2], kp.shape[3]
     nm = mesh.shape[rules.model] if (mesh is not None and rules.model) else 1
     mp = page_table.shape[1]
 
@@ -573,9 +589,9 @@ def _paged_attention_body(qt: jax.Array, cache: dict,
         # positional attention — the reference body every other regime
         # must match bit-identically (f32), and the shadow-verification
         # oracle for the fused branch below
-        kk = jnp.repeat(KP.gather_pages(cache["k_pages"], page_table),
+        kk = jnp.repeat(KP.gather_pages(kp, page_table, layer),
                         group, axis=1)
-        vv = jnp.repeat(KP.gather_pages(cache["v_pages"], page_table),
+        vv = jnp.repeat(KP.gather_pages(vp, page_table, layer),
                         group, axis=1)
         kv_pos = KP.paged_kv_positions(page_table, ps)
         return _paged_positional_attention(qt, kk, vv, positions, kv_pos,
@@ -585,9 +601,13 @@ def _paged_attention_body(qt: jax.Array, cache: dict,
             and s == 1 and nm > 1 and mp % nm == 0):
         from ..dist.ring_dispatch import paged_ring_decode_attention
         baxes = batch_placement(rules, mesh, b)
+        # every shard gathers all kv-heads of its pages, so the ring
+        # takes its layer's pool whole on each chip: one layer made
+        # whole where the carried stack is split by kv-head, not the
+        # whole stack
         return paged_ring_decode_attention(
-            qt, cache["k_pages"], cache["v_pages"], page_table,
-            positions[:, 0], window=win, scale=scale, rules=rules,
+            qt, kp[layer], vp[layer], page_table, positions[:, 0],
+            window=win, scale=scale, rules=rules,
             mesh=mesh, batch_axes=baxes, pipelined=dist_pipelined)
     if kernel_ops and s == 1 and jax.default_backend() == "tpu":
         # decode only: the kernel's tail convention needs q rows at
@@ -612,18 +632,17 @@ def _paged_attention_body(qt: jax.Array, cache: dict,
         if rules.enabled and mesh is not None:
             _, baxes, hax = dispatch_mesh_spec(
                 rules, mesh, kind="attention", batch=b,
-                feature_dims=(cache["k_pages"].shape[1], qt.shape[1]))
+                feature_dims=(hkv, qt.shape[1]))
             bspec = baxes or None
             qs = P(bspec, hax, None, None)
+            pools = P(None, None, None, hax, None)
             kernel = jax.shard_map(
                 kernel, mesh=mesh,
-                in_specs=(qs, P(None, hax, None, None),
-                          P(None, hax, None, None), P(bspec, None),
-                          P(bspec)),
+                in_specs=(qs, pools, pools, P(bspec, None), P(bspec), P()),
                 out_specs=qs, check_vma=False)
         return kernel_ops_mod.guarded(
-            fp, lambda: kernel(qt, cache["k_pages"], cache["v_pages"],
-                               page_table, positions[:, -1] + 1),
+            fp, lambda: kernel(qt, kp, vp, page_table,
+                               positions[:, -1] + 1, layer),
             _twin)
     return _twin()
 
@@ -691,8 +710,9 @@ def run_planned_layer(lp, p: dict, x: jax.Array, cfg: ModelConfig,
 
     Serving phases: a plan traced with ``phase="prefill"``/``"decode"``
     carries a ``kv_write`` node — pass the paged ``cache``
-    ({"k_pages","v_pages"}) and ``page_table`` and the walk scatters
-    this step's k/v through ``serving.kv_pages`` then runs the shared
+    ({"k_pages","v_pages"}, plus the scan's ``"layer"`` over stacked
+    pools) and ``page_table`` and the walk scatters this step's k/v
+    in place through ``serving.kv_pages`` then runs the shared
     ``_paged_attention_body`` (ring / fused paged kernel / XLA twin —
     the same three-body dispatch the hand-wired block uses).
     Contiguous (non-paged) caches are priced by the planner but not
@@ -711,8 +731,6 @@ def run_planned_layer(lp, p: dict, x: jax.Array, cfg: ModelConfig,
     serving plans, or the ``cache`` argument passed in (None for the
     cache-free forward).
     """
-    from ..serving import kv_pages as KP
-
     b, s, d = x.shape
     dh = cfg.dh
     dt = x.dtype
@@ -826,16 +844,8 @@ def run_planned_layer(lp, p: dict, x: jax.Array, cfg: ModelConfig,
             # attention core then reads the cache, not these tensors
             if not paged:
                 raise ValueError("kv_write node requires a paged cache")
-            phys, off = KP.slot_coords(page_table, positions,
-                                       cache["k_pages"].shape[2])
-            cache = {
-                "k_pages": KP.scatter_pages(
-                    cache["k_pages"], phys, off,
-                    env[ins[0]].astype(cache["k_pages"].dtype)),
-                "v_pages": KP.scatter_pages(
-                    cache["v_pages"], phys, off,
-                    env[ins[1]].astype(cache["v_pages"].dtype)),
-            }
+            cache = _write_kv(cache, page_table, positions,
+                              env[ins[0]], env[ins[1]])
             out = None
         elif role == "attn_qk":
             # the attention core executes as one unit here (fused chain

@@ -252,7 +252,7 @@ class LM:
             if planner_mod.plannable(cfg):
                 b_, s_ = int(x.shape[0]), int(x.shape[1])
                 if paged:
-                    ps_ = int(cache["k_pages"].shape[2])
+                    ps_ = int(cache["k_pages"].shape[-3])
                     plan_kw = dict(
                         phase="prefill" if s_ > 1 else "decode",
                         paged=ps_,
@@ -316,17 +316,28 @@ class LM:
                     caches: Optional[dict],
                     page_table: Optional[jax.Array] = None
                     ) -> tuple[jax.Array, Any]:
-        """Scan the super-block stack, then the tail."""
+        """Scan the super-block stack, then the tail.
+
+        Paged caches (a ``page_table`` is given) ride the scan's carry
+        whole, with the layer index: each attention site writes this
+        step's rows into its layer of the stacked pool in place and
+        reads that layer from the stack, so no layer's pool is sliced
+        out or stacked back.  Contiguous caches go through ``xs``/``ys``
+        one layer at a time."""
         cfg, rt = self.cfg, self.rt
         pat, n_super, rem = _layer_types(cfg)
 
-        def super_block(x, layer_params, layer_caches):
+        def super_block(x, layer_params, layer_caches, layer=None):
             new_caches = []
             for i, kind in enumerate(pat):
                 c = layer_caches[i] if layer_caches is not None else None
+                if layer is not None:
+                    c = dict(c, layer=layer)
                 x, nc = self._apply_layer(kind, layer_params[f"b{i}_{kind}"],
                                           x, positions, c, i,
                                           page_table=page_table)
+                if layer is not None:
+                    nc = {k: a for k, a in nc.items() if k != "layer"}
                 new_caches.append(nc)
             return x, (tuple(new_caches) if layer_caches is not None
                        else None)
@@ -346,6 +357,14 @@ class LM:
             x, _ = jax.lax.scan(scan_fn, x, params["stack"],
                                 unroll=n_super if rt.unroll else 1)
             new_stack_caches = None
+        elif page_table is not None:
+            def scan_fn(carry, lp):
+                x, stack, layer = carry
+                x, stack = body(x, lp, stack, layer)
+                return (x, stack, layer + 1), None
+            (x, new_stack_caches, _), _ = jax.lax.scan(
+                scan_fn, (x, caches["stack"], jnp.int32(0)),
+                params["stack"], unroll=n_super if rt.unroll else 1)
         else:
             def scan_fn(x, xs):
                 lp, lc = xs
@@ -513,7 +532,8 @@ class LM:
                          dtype=None) -> dict:
         """Paged KV cache pytree: the same ``{"stack", "tail"}`` layout
         as ``init_cache``, but every attention site holds a shared page
-        pool ``(n_pages, n_kv_heads, page_size, dh)`` with NO batch dim
+        pool ``(n_pages, page_size, n_kv_heads, dh)``, page-major, with
+        NO batch dim (``(n_super, ...)`` stacked for the scanned sites)
         — the engine's page tables map requests onto pages, and page 0
         is the scratch page (``serving.kv_pages``).  Attention-only
         stacks for now: SSM/hybrid recurrent state is per-request, not
@@ -530,7 +550,7 @@ class LM:
                 f"paged serving does not thread prefix embeddings yet; "
                 f"{cfg.name} needs n_prefix_embeds={cfg.n_prefix_embeds}")
         dt = dtype or jnp.dtype(cfg.dtype)
-        shape = (n_pages, cfg.n_kv_heads, page_size, cfg.dh)
+        shape = (n_pages, page_size, cfg.n_kv_heads, cfg.dh)
 
         def site():
             return {"k_pages": jnp.zeros(shape, dt),

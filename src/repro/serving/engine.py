@@ -222,10 +222,14 @@ class ServingEngine:
         self._window = int(model.cfg.window or 0)
         self._shadow_fns = None      # lazily jitted tier-1 twin pair
         # compiled step programs of the jitted tiers, keyed by
-        # (tier, phase, input avals and shardings), and the seconds each
-        # took to lower and compile (``_program``)
+        # (tier, phase, input avals and shardings), the seconds each
+        # took to lower and compile, and the temporary bytes each holds
+        # on the device (None where the backend gives none): a step
+        # that updates the KV pool in place holds no layer's pool there
+        # (``_program``)
         self.programs: dict[tuple, object] = {}
         self.compile_s: dict[tuple, float] = {}
+        self.temp_bytes: dict[tuple, Optional[int]] = {}
         self.cache = model.init_paged_cache(n_pages, page_size)
         self._build_exec()
         if model.rt.planner:
@@ -299,6 +303,12 @@ class ServingEngine:
             prog = fn.lower(*args).compile()
             self.compile_s[key] = time.perf_counter() - t0
             self.programs[key] = prog
+            mem = prog.memory_analysis()
+            self.temp_bytes[key] = getattr(mem, "temp_size_in_bytes", None)
+            if self.verbose:
+                print(f"compiled {phase} step (tier {self.exec_tier}) in "
+                      f"{self.compile_s[key]:.1f}s; temporaries "
+                      f"{self.temp_bytes[key]} B")
         return prog
 
     def _note_tier_failure(self, phase: str, reason: str) -> None:

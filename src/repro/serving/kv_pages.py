@@ -2,8 +2,9 @@
 (docs/serving.md).
 
 The device side is a shared *page pool* per attention site — arrays of
-shape ``(n_pages, n_kv_heads, page_size, head_dim)`` — and requests
-own disjoint sets of physical pages.  A request's logical slot for
+shape ``(n_pages, page_size, n_kv_heads, head_dim)``, page-major, with
+a leading layer axis where the sites of a layer scan share one stacked
+pool — and requests own disjoint sets of physical pages.  A request's logical slot for
 absolute position ``p`` is page ``p // page_size``, offset
 ``p % page_size``; its page table maps that logical page to a physical
 one.  Allocation is a host-side free list: admission takes pages for
@@ -199,22 +200,35 @@ def slot_coords(page_table: jnp.ndarray, positions: jnp.ndarray,
     return phys, offset
 
 
-def gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
-    """(n_pages, H, ps, D) through (B, MP) indices ->
-    (B, H, MP*ps, D); unallocated entries gather the scratch page and
-    must be rejected by position."""
-    g = jnp.take(pages, jnp.clip(page_table, 0, pages.shape[0] - 1),
-                 axis=0)
-    b, mp, h, ps, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, mp * ps, d)
+def gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray,
+                 layer=None) -> jnp.ndarray:
+    """(n_pages, ps, H, D) through (B, MP) indices -> (B, H, MP*ps, D);
+    unallocated entries gather the scratch page and must be rejected
+    by position.  With ``layer`` (int or traced scalar) ``pages`` is a
+    stacked pool (L, n_pages, ps, H, D) and the gather reads that
+    layer's pages from the stack, without slicing the layer out."""
+    n_pages = pages.shape[-4]
+    idx = jnp.clip(page_table, 0, n_pages - 1)
+    if layer is not None:
+        # the stack seen as one (L * n_pages, ...) pool, a bitcast
+        pages = pages.reshape((-1,) + pages.shape[2:])
+        idx = idx + layer * n_pages
+    g = jnp.take(pages, idx, axis=0)
+    b, mp, ps, h, d = g.shape
+    return g.transpose(0, 3, 1, 2, 4).reshape(b, h, mp * ps, d)
 
 
 def scatter_pages(pages: jnp.ndarray, phys: jnp.ndarray,
-                  offset: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:
+                  offset: jnp.ndarray, values: jnp.ndarray,
+                  layer=None) -> jnp.ndarray:
     """Write ``values`` (B, S, H, D) into ``pages`` at per-token
-    (phys, offset) coordinates (each (B, S)).  Distinct live slots
-    never collide (pages are exclusively owned); duplicate scratch
-    writes land in arbitrary order, which is fine — scratch is never
-    read validly."""
-    return pages.at[phys, :, offset, :].set(
-        values.astype(pages.dtype), mode="drop")
+    (phys, offset) coordinates (each (B, S)), in place: only these
+    rows are written.  With ``layer`` the rows land in that layer of a
+    stacked pool (L, n_pages, ps, H, D).  Distinct live slots never
+    collide (pages are exclusively owned); duplicate scratch writes
+    land in arbitrary order, which is fine — scratch is never read
+    validly."""
+    values = values.astype(pages.dtype)
+    if layer is None:
+        return pages.at[phys, offset].set(values, mode="drop")
+    return pages.at[layer, phys, offset].set(values, mode="drop")

@@ -106,13 +106,14 @@ def test_request_pages_ensure_growth_and_failure():
 
 def _paged_setup(rng, b, hkv, d, ps, mp, n_pool, lengths):
     """Scatter per-request kv (position order) into a shuffled page
-    assignment; returns (pools, table, dense) where dense is the
-    contiguous (B, hkv, mp*ps, d) layout with garbage beyond length."""
+    assignment of page-major (n_pool, ps, hkv, d) pools; returns
+    (pools, table, dense) where dense is the contiguous (B, hkv,
+    mp*ps, d) layout with garbage beyond length."""
     n_ctx = mp * ps
     dense_k = jnp.asarray(rng.randn(b, hkv, n_ctx, d), jnp.float32)
     dense_v = jnp.asarray(rng.randn(b, hkv, n_ctx, d), jnp.float32)
-    pool_k = jnp.asarray(rng.randn(n_pool, hkv, ps, d), jnp.float32)
-    pool_v = jnp.asarray(rng.randn(n_pool, hkv, ps, d), jnp.float32)
+    pool_k = jnp.asarray(rng.randn(n_pool, ps, hkv, d), jnp.float32)
+    pool_v = jnp.asarray(rng.randn(n_pool, ps, hkv, d), jnp.float32)
     order = rng.permutation(n_pool - 1) + 1   # never the scratch page
     table = np.full((b, mp), -1, np.int32)
     nxt = 0
@@ -121,8 +122,9 @@ def _paged_setup(rng, b, hkv, d, ps, mp, n_pool, lengths):
         for j in range(npages):
             pg = int(order[nxt]); nxt += 1
             table[i, j] = pg
-            pool_k = pool_k.at[pg].set(dense_k[i, :, j * ps:(j + 1) * ps])
-            pool_v = pool_v.at[pg].set(dense_v[i, :, j * ps:(j + 1) * ps])
+            page = slice(j * ps, (j + 1) * ps)
+            pool_k = pool_k.at[pg].set(dense_k[i, :, page].transpose(1, 0, 2))
+            pool_v = pool_v.at[pg].set(dense_v[i, :, page].transpose(1, 0, 2))
     return pool_k, pool_v, jnp.asarray(table), dense_k, dense_v
 
 
@@ -258,7 +260,7 @@ def test_paged_gather_path_kept(m, bkv, pages_per_chunk):
     """Query blocks, chunked tables and blocks that split a page keep
     the gather + partial path."""
     q = jnp.zeros((2, 4, m, 8))
-    pages = jnp.zeros((9, 2, 4, 8))
+    pages = jnp.zeros((9, 4, 2, 8))
     table = jnp.zeros((2, 6), jnp.int32)
     names = _pallas_names(lambda: fused_attention_paged(
         q, pages, pages, table, jnp.full((2,), 10, jnp.int32), bkv=bkv,
@@ -632,8 +634,8 @@ out = {}
 # ring decode attention vs the single-device twin, window straddling
 mesh, rules, rt = sharded_runtime(4)
 b, hq, hkv, d, ps, MP = 2, 4, 2, 16, 8, 8
-kp = jax.random.normal(jax.random.PRNGKey(0), (20, hkv, ps, d))
-vp = jax.random.normal(jax.random.PRNGKey(1), (20, hkv, ps, d))
+kp = jax.random.normal(jax.random.PRNGKey(0), (20, ps, hkv, d))
+vp = jax.random.normal(jax.random.PRNGKey(1), (20, ps, hkv, d))
 q = jax.random.normal(jax.random.PRNGKey(2), (b, hq, 1, d))
 table = np.full((b, MP), -1, np.int32)
 table[0, :3] = [7, 2, 11]; table[1, :2] = [4, 5]

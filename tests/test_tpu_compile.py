@@ -111,8 +111,8 @@ def test_paged_decode_attention_compiles(one_chip):
     _compile(lambda q, kp, vp, tbl, ln: fused_attention_paged(
                  q, kp, vp, tbl, ln, **kw), "paged_decode_attention",
              _shape(one_chip, (b, HEADS, 1, HEAD_DIM)),
-             _shape(one_chip, (n_pages, KV_HEADS, page, HEAD_DIM)),
-             _shape(one_chip, (n_pages, KV_HEADS, page, HEAD_DIM)),
+             _shape(one_chip, (n_pages, page, KV_HEADS, HEAD_DIM)),
+             _shape(one_chip, (n_pages, page, KV_HEADS, HEAD_DIM)),
              _shape(one_chip, (b, pages_per_seq), jnp.int32),
              _shape(one_chip, (b,), jnp.int32))
 
@@ -122,28 +122,27 @@ def test_paged_decode_attention_compiles_at_cell_shapes(one_chip, b,
                                                         pages_per_seq):
     """The benchmark cells' decode shapes (qwen3-8b chat: 64 slots of
     1280; mistral-nemo reason: 48 of 4096) with the tuner's tiles.  The
-    pools are laid out (page, slot, kv-head, dim) in memory, as XLA
-    keeps them in the serving step for the kv scatter: the kernel reads
-    them in place, so the program stages neither a gathered table nor a
-    relaid copy of the pool."""
-    from jax.experimental.layout import Format, Layout
+    pools are the serving step's: stacked for the cells' 4 layers and
+    page-major (layer, page, slot, kv-head, dim), the order the kv
+    scatter writes.  The kernel reads a traced layer's pages in place,
+    so the program stages neither a gathered table nor a copy of a
+    layer's pool."""
     from repro.kernels.attention import fused_attention_paged
-    page = 16
+    page, n_layers = 16, 4
     n_pages = b * pages_per_seq + 1
-    pool = jax.ShapeDtypeStruct(
-        (n_pages, KV_HEADS, page, HEAD_DIM), jnp.bfloat16,
-        sharding=Format(Layout(major_to_minor=(0, 2, 1, 3)), one_chip))
+    pool = _shape(one_chip, (n_layers, n_pages, page, KV_HEADS, HEAD_DIM))
     tk = api.fuse_attention_paged(1, page * pages_per_seq, HEAD_DIM,
                                   HEAD_DIM, page_size=page, heads=HEADS,
                                   batch=b, dtype=BF16, causal=True,
                                   hw=V5E, interpret=False)
     kw = tk.params.as_kwargs()
     compiled = _compile(
-        lambda q, kp, vp, tbl, ln: fused_attention_paged(
-            q, kp, vp, tbl, ln, **kw), "paged_decode_attention",
+        lambda q, kp, vp, tbl, ln, layer: fused_attention_paged(
+            q, kp, vp, tbl, ln, layer, **kw), "paged_decode_attention",
         _shape(one_chip, (b, HEADS, 1, HEAD_DIM)), pool, pool,
         _shape(one_chip, (b, pages_per_seq), jnp.int32),
-        _shape(one_chip, (b,), jnp.int32))
+        _shape(one_chip, (b,), jnp.int32),
+        _shape(one_chip, (), jnp.int32))
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
@@ -208,7 +207,7 @@ def test_budget_is_every_kernels_vmem_limit():
     z = jnp.zeros
     a, w = z((1, 256, 256)), z((1, 256, 256))
     q, kv = z((2, 4, 8, 128)), z((2, 2, 128, 128))
-    pages, tbl = z((9, 2, 16, 128)), jnp.zeros((2, 4), jnp.int32)
+    pages, tbl = z((9, 16, 2, 128)), jnp.zeros((2, 4), jnp.int32)
     calls = [
         lambda: fused_gemm_chain(a, w, w, style="flat", interpret=True),
         lambda: fused_gemm_chain(a, w, w, style="deep", interpret=True),
