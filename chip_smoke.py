@@ -90,7 +90,7 @@ def serve(model, params, reqs, label: str):
     setup_s = time.perf_counter() - t0
     results, stats = engine.run(reqs)
     print(f"[smoke, not a measurement] {label}: engine set-up "
-          f"(regime tuning, decode pre-plan) {setup_s:.1f}s; lower and "
+          f"(regime tuning) {setup_s:.1f}s; lower and "
           f"compile of {len(engine.programs)} step programs (tuning of "
           f"planned chains included) {sum(engine.compile_s.values()):.1f}s;"
           f" serving {stats['wall_s']:.1f}s", flush=True)

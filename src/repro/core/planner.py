@@ -250,7 +250,7 @@ def _template_chains(cfg, batch: int, seq: int,
                                       dtype=dt, name="wv")),
         ("attention", ("qk", "softmax", "pv"),
          attention_chain(seq, kv, dh, dh, heads=hq, batch=batch,
-                         dtype=dt, causal=True, window=cfg.window)),
+                         dtype=dt, causal=True, window=cfg.attn_window)),
         ("gemm", ("wo",), single_gemm(seq, d, hq * dh, batch=batch,
                                       dtype=dt, name="wo")),
     ]
@@ -434,7 +434,7 @@ def config_fingerprint(cfg) -> tuple:
     """The structural fields the op DAG and chain dims derive from."""
     return (cfg.name, cfg.n_layers, cfg.d_model, cfg.n_heads,
             cfg.n_kv_heads, cfg.dh, cfg.d_ff, cfg.act, cfg.norm,
-            cfg.use_rope, cfg.qk_norm, cfg.window, cfg.dtype)
+            cfg.use_rope, cfg.qk_norm, cfg.attn_window, cfg.dtype)
 
 
 def plan_key(cfg, batch: int, seq: int, stitch: bool,
@@ -478,9 +478,9 @@ def plan_model(cfg, batch: int, seq: int, *, stitch: bool = True,
     bytes forever.  A *stale* ``PLANNER_VERSION`` is neither: the
     record stays in place and a fresh plan is carved beside it.
     Dispatch-level quarantine (the circuit breaker denylisting a plan
-    fingerprint after a kernel failure) is consulted by the callers —
-    ``models/lm.py`` and ``serving/engine.py`` — not here: a
-    denylisted plan still loads; it just never runs."""
+    fingerprint after a kernel failure) is consulted by the caller —
+    ``models/lm.py`` ``LM._layer_plan`` — not here: a denylisted plan
+    still loads; it just never runs."""
     hw = hw or device_spec()
     if not plannable(cfg):
         raise ValueError(f"config {cfg.name!r} is not plannable")
@@ -704,12 +704,12 @@ def price_plan(plan: Plan, cfg, *, hw: Optional[TpuSpec] = None,
             tk = api.fuse_attention_paged(
                 seq, kv, cfg.dh, cfg.dh, page_size=plan.paged,
                 heads=cfg.n_heads, batch=batch, dtype=cfg.dtype,
-                causal=True, window=cfg.window, hw=hw, mesh=mesh,
+                causal=True, window=cfg.attn_window, hw=hw, mesh=mesh,
                 seed=seed)
         elif kind == "attention":
             tk = api.fuse_attention(
                 seq, kv, cfg.dh, cfg.dh, heads=cfg.n_heads, batch=batch,
-                dtype=cfg.dtype, causal=True, window=cfg.window, hw=hw,
+                dtype=cfg.dtype, causal=True, window=cfg.attn_window, hw=hw,
                 mesh=mesh, seed=seed)
         else:
             tk = api.fuse_mlp_chain(

@@ -239,7 +239,7 @@ def kernelized_attention_bytes(cfg, shape, n_dev: int, mesh=None,
                      if pat[i % len(pat)] == "attn")
         if n_attn == 0:
             return 0.0, 0
-        win = cfg.window or (cfg.rglru.local_window if cfg.rglru else 0)
+        win = cfg.attn_window
         n_kv = min(s, win) if win else s
         total = layer_bytes(s, n_kv) * n_attn
         count = n_attn

@@ -81,6 +81,13 @@ class ModelConfig:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
 
     @property
+    def attn_window(self) -> int:
+        """The window of this model's attention layers (0 = full): a
+        hybrid's interleaved local-attention window, else ``window``."""
+        return (self.rglru.local_window if self.rglru is not None
+                else self.window)
+
+    @property
     def sub_quadratic(self) -> bool:
         """Can this arch decode at 500k context? (DESIGN §4 skip rule)."""
         return self.family in ("ssm", "hybrid") or self.window > 0

@@ -41,35 +41,29 @@ def dense_init(rng, shape, dtype, scale: Optional[float] = None):
     return (jax.random.normal(rng, shape, jnp.float32) * scale).astype(dtype)
 
 
-def _rmsnorm_f32(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    """rmsnorm without the trailing downcast — the stitched-epilogue
-    form (run_planned_layer): glue inside a carved unit computes wide
+def normalize(x: jax.Array, w: jax.Array, eps: float,
+              b: Optional[jax.Array] = None, *,
+              wide: bool = False) -> jax.Array:
+    """RMSNorm, or LayerNorm where a bias ``b`` is given, computed in
+    f32.  ``wide`` keeps the f32 result: the stitched-glue form
+    (run_planned_layer), where glue inside a carved unit computes wide
     and downcasts once at the unit boundary."""
     xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return xf * jax.lax.rsqrt(var + eps) * (1.0 + w.astype(jnp.float32))
+    if b is None:
+        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        out = xf * jax.lax.rsqrt(var + eps) * (1.0 + w.astype(jnp.float32))
+    else:
+        mu = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.var(xf, axis=-1, keepdims=True)
+        out = (xf - mu) * jax.lax.rsqrt(var + eps) * w + b
+    return out if wide else out.astype(x.dtype)
 
 
-def rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    return _rmsnorm_f32(x, w, eps).astype(x.dtype)
-
-
-def _layernorm_f32(x: jax.Array, w: jax.Array, b: jax.Array,
-                   eps: float) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    return (xf - mu) * jax.lax.rsqrt(var + eps) * w + b
-
-
-def layernorm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float) -> jax.Array:
-    return _layernorm_f32(x, w, b, eps).astype(x.dtype)
-
-
-def apply_norm(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    if cfg.norm == "layernorm":
-        return layernorm(x, p["w"], p["b"], cfg.norm_eps)
-    return rmsnorm(x, p["w"], cfg.norm_eps)
+def apply_norm(p: dict, x: jax.Array, cfg: ModelConfig, *,
+               wide: bool = False) -> jax.Array:
+    """The model's norm (``cfg.norm``) with the gains ``p``."""
+    return normalize(x, p["w"], cfg.norm_eps,
+                     p["b"] if cfg.norm == "layernorm" else None, wide=wide)
 
 
 def init_norm(cfg: ModelConfig, d: Optional[int] = None) -> dict:
@@ -90,8 +84,10 @@ def specs_norm(cfg: ModelConfig, rules: Rules) -> dict:
 # RoPE
 # ---------------------------------------------------------------------------
 
-def _rope_f32(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """rope without the trailing downcast (see _rmsnorm_f32)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float, *,
+         wide: bool = False) -> jax.Array:
+    """x: (..., S, H, Dh), positions: (S,) or (B, S).  ``wide`` keeps
+    the f32 result (see ``normalize``)."""
     dh = x.shape[-1]
     half = dh // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -105,13 +101,9 @@ def _rope_f32(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     while cos.ndim < x.ndim - 1:
         cos, sin = cos[..., None, :], sin[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1)
-
-
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, Dh), positions: (S,) or (B, S)."""
-    return _rope_f32(x, positions, theta).astype(x.dtype)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                          axis=-1)
+    return out if wide else out.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -237,8 +229,8 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int,
                     window: Optional[int] = None, dtype=None) -> dict:
     """Cache pytree: {"k","v","pos"}; "pos" holds each slot's absolute
     position (-1 = empty) so full and ring (windowed) caches share one
-    code path."""
-    win = cfg.window if window is None else window
+    code path.  window None -> ``cfg.attn_window``."""
+    win = cfg.attn_window if window is None else window
     n = min(max_len, win) if win else max_len
     dt = dtype or _dtype(cfg)
     return {
@@ -248,42 +240,130 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int,
     }
 
 
+# ---------------------------------------------------------------------------
+# The ops of an attention layer, one definition each.  The hand-wired
+# blocks call them in the order of ``core.planner.layer_op_dag``, and the
+# planner's node walk (``run_planned_layer``) calls them node by node,
+# with ``wide`` set where the plan stitched the node into a carved unit.
+# ---------------------------------------------------------------------------
+
+def proj(name: str, x: jax.Array, w: jax.Array, cfg: ModelConfig,
+         rules: Rules) -> jax.Array:
+    """The layer's matmul ``name`` (a ``gemm`` node of the op DAG):
+    wq/wk/wv split their output into heads (B, S, H, dh), wo takes the
+    merged heads back to the model width, and the MLP's w_gate, w_up
+    and w_down are plain matmuls."""
+    if name in ("wq", "wk", "wv"):
+        b, s, _ = x.shape
+        heads = cfg.n_heads if name == "wq" else cfg.n_kv_heads
+        return jnp.einsum("bsd,dh->bsh", x, w).reshape(b, s, heads, cfg.dh)
+    if name == "wo":
+        return constrain(jnp.einsum("bsh,hd->bsd", x, w), rules,
+                         "batch", "seq", None)
+    if name == "w_down":
+        return constrain(x @ w, rules, "batch", None, None)
+    if name in ("w_gate", "w_up"):
+        return x @ w
+    raise ValueError(f"unknown gemm node {name!r}")
+
+
+def residual(res: jax.Array, mix: jax.Array, *,
+             wide: bool = False) -> jax.Array:
+    """The residual add ``res + mix``, in f32 where ``wide``."""
+    if wide:
+        return res.astype(jnp.float32) + mix.astype(jnp.float32)
+    return res + mix
+
+
+def heads_major(t: jax.Array, rules: Rules,
+                axis: Optional[str] = None) -> jax.Array:
+    """(B, S, H, dh) -> (B, H, S, dh), the heads placed on ``axis`` (q's
+    on "tp"; k and v whole)."""
+    return constrain(t.transpose(0, 2, 1, 3), rules, "batch", axis,
+                     None, None)
+
+
+def merge_heads(o: jax.Array, rules: Rules) -> jax.Array:
+    """The attention output (B, H, S, dh) -> (B, S, H * dh)."""
+    o = constrain(o, rules, "batch", "tp", None, None)
+    b, h, s, dh = o.shape
+    return o.transpose(0, 2, 1, 3).reshape(b, s, h * dh)
+
+
+def attention_core(q: jax.Array, k: jax.Array, v: jax.Array,
+                   cfg: ModelConfig, rules: Rules, *, window: int,
+                   causal: bool = True, bkv: int = 512,
+                   unroll: bool = False,
+                   mesh: Optional[jax.sharding.Mesh] = None,
+                   kernel_ops: bool = False) -> jax.Array:
+    """Cache-free attention over heads-major q (B, Hq, S, dh) and k/v
+    (B, Hkv, S, dh).  kernel_ops: the MCFuser-tuned kernel through
+    ``kernels.ops``, dispatched per shard via shard_map when a mesh is
+    ambient (docs/design.md §7), GQA inside the kernel; otherwise the
+    XLA streaming-attention twin for long rows, the naive one else."""
+    s = q.shape[2]
+    scale = 1.0 / math.sqrt(cfg.dh)
+    if kernel_ops and s > 1:
+        from ..kernels import ops as kernel_ops_mod
+        return kernel_ops_mod.attention(
+            q, k, v, causal=causal, window=window, scale=scale,
+            mesh=mesh if rules.enabled else None, rules=rules)
+    group = cfg.n_heads // cfg.n_kv_heads
+    kk = jnp.repeat(k, group, axis=1)
+    vv = jnp.repeat(v, group, axis=1)
+    if cfg.use_fused_attention and s > 2 * bkv:
+        return streaming_attention(q, kk, vv, causal=causal, window=window,
+                                   scale=scale, bkv=bkv, q_offset=0,
+                                   unroll=unroll)
+    return naive_attention(q, kk, vv, causal=causal, window=window,
+                           scale=scale)
+
+
+def _qkv(p: dict, x: jax.Array, cfg: ModelConfig, rules: Rules,
+         positions: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """q, k, v (B, S, H, dh) of the hand-wired blocks: the projections,
+    then q/k RMSNorm and RoPE where the config has them."""
+    q, k, v = (proj(n, x, p[n], cfg, rules) for n in ("wq", "wk", "wv"))
+    if cfg.qk_norm:
+        q = normalize(q, p["q_norm"], cfg.norm_eps)
+        k = normalize(k, p["k_norm"], cfg.norm_eps)
+    if cfg.use_rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+# ---------------------------------------------------------------------------
+# Attention block (GQA + qk_norm + RoPE + cache)
+# ---------------------------------------------------------------------------
+
 def attention_block(p: dict, x: jax.Array, cfg: ModelConfig, rules: Rules,
                     *, positions: jax.Array, cache: Optional[dict] = None,
-                    window: Optional[int] = None, causal: bool = True,
-                    bkv: int = 512, unroll: bool = False,
+                    causal: bool = True, bkv: int = 512,
+                    unroll: bool = False,
                     mesh: Optional[jax.sharding.Mesh] = None,
                     dist_decode: bool = False,
                     kernel_ops: bool = False
                     ) -> tuple[jax.Array, Optional[dict]]:
     """x: (B, S, D).  positions: (S,) absolute positions of x's tokens.
-    window None -> cfg.window.  Returns (out, updated cache).
+    Returns (out, updated cache).  The window is ``cfg.attn_window``.
 
-    kernel_ops: route cache-free attention through ``kernels.ops`` —
-    the MCFuser-tuned kernel dispatched per shard via shard_map when a
-    mesh is ambient (docs/design.md §7), instead of the XLA
-    streaming-attention twin."""
+    kernel_ops: route cache-free attention through ``kernels.ops``
+    (``attention_core``) instead of the XLA streaming-attention twin."""
     b, s, d = x.shape
-    dh = cfg.dh
-    win = cfg.window if window is None else window
+    win = cfg.attn_window
+    q, k, v = _qkv(p, x, cfg, rules, positions)
+    q, k, v = (heads_major(q, rules, "tp"), heads_major(k, rules),
+               heads_major(v, rules))
 
-    q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, s, cfg.n_heads, dh)
-    k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, s, cfg.n_kv_heads, dh)
-    v = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, s, cfg.n_kv_heads, dh)
-    if cfg.qk_norm:
-        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    if cfg.use_rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-    q = constrain(q.transpose(0, 2, 1, 3), rules, "batch", "tp", None, None)
-    k = constrain(k.transpose(0, 2, 1, 3), rules, "batch", None, None, None)
-    v = constrain(v.transpose(0, 2, 1, 3), rules, "batch", None, None, None)
-
-    scale = 1.0 / math.sqrt(dh)
+    scale = 1.0 / math.sqrt(cfg.dh)
     group = cfg.n_heads // cfg.n_kv_heads
 
-    if cache is not None:
+    if cache is None:
+        o = attention_core(q, k, v, cfg, rules, window=win, causal=causal,
+                           bkv=bkv, unroll=unroll, mesh=mesh,
+                           kernel_ops=kernel_ops)
+    else:
         nc = cache["k"].shape[2]
         nm = mesh.shape[rules.model] if (mesh is not None
                                          and rules.model) else 1
@@ -304,10 +384,8 @@ def attention_block(p: dict, x: jax.Array, cfg: ModelConfig, rules: Rules,
                 positions, cache["pos"], causal=causal, window=win,
                 scale=scale, rules=rules, mesh=mesh, batch_axes=baxes)
             cache = {"k": knew, "v": vnew, "pos": posnew}
-            o = constrain(o, rules, "batch", "tp", None, None)
-            o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * dh)
-            out = jnp.einsum("bsh,hd->bsd", o, p["wo"])
-            return constrain(out, rules, "batch", "seq", None), cache
+            return proj("wo", merge_heads(o, rules), p["wo"], cfg,
+                        rules), cache
         if win and s >= win:
             # prefill longer than the ring: only the last `win` tokens
             # can ever be attended to again
@@ -340,28 +418,8 @@ def attention_block(p: dict, x: jax.Array, cfg: ModelConfig, rules: Rules,
             # decode / short: single-block scores are already tiny
             o = _positional_attention(q, kk, vv, positions, kv_pos,
                                       causal, win, scale)
-    elif kernel_ops and s > 1:
-        # sharded fused-kernel dispatch: GQA handled inside the kernel,
-        # no head repeat; batch/heads shard per the ambient mesh + rules
-        from ..kernels import ops as kernel_ops_mod
-        o = kernel_ops_mod.attention(
-            q, k, v, causal=causal, window=win, scale=scale,
-            mesh=mesh if rules.enabled else None, rules=rules)
-    else:
-        kk = jnp.repeat(k, group, axis=1)
-        vv = jnp.repeat(v, group, axis=1)
-        if cfg.use_fused_attention and s > 2 * bkv:
-            o = streaming_attention(q, kk, vv, causal=causal, window=win,
-                                    scale=scale, bkv=bkv, q_offset=0,
-                                    unroll=unroll)
-        else:
-            o = naive_attention(q, kk, vv, causal=causal, window=win,
-                                scale=scale)
 
-    o = constrain(o, rules, "batch", "tp", None, None)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * dh)
-    out = jnp.einsum("bsh,hd->bsd", o, p["wo"])
-    return constrain(out, rules, "batch", "seq", None), cache
+    return proj("wo", merge_heads(o, rules), p["wo"], cfg, rules), cache
 
 
 def distributed_decode_attention(q, k_cache, v_cache, k_new, v_new, slot,
@@ -476,7 +534,6 @@ def _paged_positional_attention(q, k, v, rows_pos, kv_pos, window, scale):
 def paged_attention_block(p: dict, x: jax.Array, cfg: ModelConfig,
                           rules: Rules, *, positions: jax.Array,
                           cache: dict, page_table: jax.Array,
-                          window: Optional[int] = None,
                           mesh: Optional[jax.sharding.Mesh] = None,
                           dist_decode: bool = False,
                           dist_pipelined: bool = False,
@@ -493,7 +550,7 @@ def paged_attention_block(p: dict, x: jax.Array, cfg: ModelConfig,
     ``"layer"`` index; page_table: (B, max_pages) physical page per
     logical page (-1 = unallocated).
 
-    Projections/RoPE/GQA are identical to ``attention_block``; the kv
+    Projections/RoPE/GQA are the ops ``attention_block`` calls; the kv
     write scatters this step's rows in place through
     ``serving.kv_pages.slot_coords`` (masked rows land on the scratch
     page) and attention runs over the
@@ -506,38 +563,18 @@ def paged_attention_block(p: dict, x: jax.Array, cfg: ModelConfig,
     ``dist_decode`` and a mesh with a model axis that divides the page
     table are present.
     """
-    b, s, d = x.shape
-    dh = cfg.dh
-    win = cfg.window if window is None else window
-
-    q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, s, cfg.n_heads, dh)
-    k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, s, cfg.n_kv_heads, dh)
-    v = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, s, cfg.n_kv_heads, dh)
-    if cfg.qk_norm:
-        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    if cfg.use_rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-
+    q, k, v = _qkv(p, x, cfg, rules, positions)
     cache = _write_kv(cache, page_table, positions, k, v)
-
-    qt = q.transpose(0, 2, 1, 3)          # (B, Hq, S, dh)
-    qt = constrain(qt, rules, "batch", "tp", None, None)
-    scale = 1.0 / math.sqrt(dh)
-    group = cfg.n_heads // cfg.n_kv_heads
-
-    o = _paged_attention_body(qt, cache, page_table, positions,
-                              group=group, win=win, scale=scale,
+    o = _paged_attention_body(heads_major(q, rules, "tp"), cache,
+                              page_table, positions,
+                              group=cfg.n_heads // cfg.n_kv_heads,
+                              win=cfg.attn_window,
+                              scale=1.0 / math.sqrt(cfg.dh),
                               rules=rules, mesh=mesh,
                               dist_decode=dist_decode,
                               dist_pipelined=dist_pipelined,
                               kernel_ops=kernel_ops, block=block)
-
-    o = constrain(o, rules, "batch", "tp", None, None)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * dh)
-    out = jnp.einsum("bsh,hd->bsd", o, p["wo"])
-    return constrain(out, rules, "batch", "seq", None), cache
+    return proj("wo", merge_heads(o, rules), p["wo"], cfg, rules), cache
 
 
 def _write_kv(cache: dict, page_table: jax.Array, positions: jax.Array,
@@ -674,15 +711,22 @@ def specs_mlp(cfg: ModelConfig, rules: Rules) -> dict:
             "w_down": rules.spec("model", "data")}
 
 
-def mlp_block(p: dict, x: jax.Array, cfg: ModelConfig, rules: Rules) -> jax.Array:
-    if cfg.act == "swiglu":
-        h = jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])
-    elif cfg.act == "geglu":
-        h = jax.nn.gelu(x @ p["w_gate"]) * (x @ p["w_up"])
+def gate_act(cfg: ModelConfig, rules: Rules, h: jax.Array,
+             up: Optional[jax.Array] = None) -> jax.Array:
+    """The MLP's activation: ``act(gate) * up`` for a gated MLP (``h``
+    is the gate), ``gelu(up)`` else (``h`` is the up projection)."""
+    if cfg.act in ("swiglu", "geglu"):
+        act = jax.nn.silu if cfg.act == "swiglu" else jax.nn.gelu
+        h = act(h) * up
     else:
-        h = jax.nn.gelu(x @ p["w_up"])
-    h = constrain(h, rules, "batch", None, "tp")
-    return constrain(h @ p["w_down"], rules, "batch", None, None)
+        h = jax.nn.gelu(h)
+    return constrain(h, rules, "batch", None, "tp")
+
+
+def mlp_block(p: dict, x: jax.Array, cfg: ModelConfig, rules: Rules) -> jax.Array:
+    ins = ("w_gate", "w_up") if cfg.act in ("swiglu", "geglu") else ("w_up",)
+    h = gate_act(cfg, rules, *(proj(n, x, p[n], cfg, rules) for n in ins))
+    return proj("w_down", h, p["w_down"], cfg, rules)
 
 
 # ---------------------------------------------------------------------------
@@ -697,16 +741,15 @@ def run_planned_layer(lp, p: dict, x: jax.Array, cfg: ModelConfig,
     """Execute one attention block from a planner ``LayerPlan`` — the
     zero-hand-specified-chains path behind ``Runtime(planner=True)``.
 
-    Walks the plan's op DAG; every node dispatches to the *same* jnp
-    code ``_apply_layer``'s hand-wired path runs (attention_block /
-    paged_attention_block + mlp_block twins, verbatim), so a
-    stitch-disabled plan is bit-identical to the hand-wired layer.
-    Glue stitched into a carved chain as prologue/epilogue instead
-    executes in f32 (the ``_*_f32`` twins — what a fused kernel's
-    VMEM-resident epilogue computes in) with ONE downcast at the carved
-    unit's boundary; on float32 configs that is still bitwise
-    identical, on bf16 it differs only by where rounding lands
-    (docs/planner.md).
+    Walks the plan's op DAG; every node calls the op function the
+    hand-wired blocks call (``attention_block`` /
+    ``paged_attention_block`` + ``mlp_block``), so a stitch-disabled
+    plan is bit-identical to the hand-wired layer.  Glue stitched into
+    a carved chain as prologue/epilogue instead executes in f32 (the
+    op's ``wide`` form — what a fused kernel's VMEM-resident epilogue
+    computes in) with ONE downcast at the carved unit's boundary; on
+    float32 configs that is still bitwise identical, on bf16 it
+    differs only by where rounding lands (docs/planner.md).
 
     Serving phases: a plan traced with ``phase="prefill"``/``"decode"``
     carries a ``kv_write`` node — pass the paged ``cache``
@@ -732,10 +775,10 @@ def run_planned_layer(lp, p: dict, x: jax.Array, cfg: ModelConfig,
     cache-free forward).
     """
     b, s, d = x.shape
-    dh = cfg.dh
     dt = x.dtype
     pm, pf = p["mix"], p["ff"]
-    win = cfg.window
+    weights = {**pm, **pf}
+    win = cfg.attn_window
     paged = cache is not None
     if paged and "k_pages" not in cache:
         raise NotImplementedError(
@@ -797,50 +840,20 @@ def run_planned_layer(lp, p: dict, x: jax.Array, cfg: ModelConfig,
                 out = out.astype(dt)
             env[nm] = out
             continue
+        wide = nm in stitched
         if role == "norm":
-            val = env[ins[0]]
-            pn = p[nm]    # DAG node names ln1/ln2 mirror the param keys
-            if nm in stitched:
-                out = (_layernorm_f32(val, pn["w"], pn["b"], cfg.norm_eps)
-                       if cfg.norm == "layernorm"
-                       else _rmsnorm_f32(val, pn["w"], cfg.norm_eps))
-            else:
-                out = apply_norm(pn, val, cfg)
+            # DAG node names ln1/ln2 mirror the param keys
+            out = apply_norm(p[nm], env[ins[0]], cfg, wide=wide)
         elif role == "gemm":
-            xin = env[ins[0]]
-            if nm == "wq":
-                out = jnp.einsum("bsd,dh->bsh", xin, pm["wq"]
-                                 ).reshape(b, s, cfg.n_heads, dh)
-            elif nm == "wk":
-                out = jnp.einsum("bsd,dh->bsh", xin, pm["wk"]
-                                 ).reshape(b, s, cfg.n_kv_heads, dh)
-            elif nm == "wv":
-                out = jnp.einsum("bsd,dh->bsh", xin, pm["wv"]
-                                 ).reshape(b, s, cfg.n_kv_heads, dh)
-            elif nm == "wo":
-                out = jnp.einsum("bsh,hd->bsd", xin, pm["wo"])
-                out = constrain(out, rules, "batch", "seq", None)
-            elif nm in ("w_gate", "w_up"):
-                out = xin @ pf[nm]
-            elif nm == "w_down":
-                out = constrain(xin @ pf["w_down"], rules,
-                                "batch", None, None)
-            else:
-                raise ValueError(f"unknown gemm node {nm!r}")
+            out = proj(nm, env[ins[0]], weights[nm], cfg, rules)
         elif role == "qk_norm":
             w = pm["q_norm"] if nm.endswith("_q") else pm["k_norm"]
-            val = env[ins[0]]
-            out = (_rmsnorm_f32(val, w, cfg.norm_eps) if nm in stitched
-                   else rmsnorm(val, w, cfg.norm_eps))
+            out = normalize(env[ins[0]], w, cfg.norm_eps, wide=wide)
         elif role == "rope":
-            val = env[ins[0]]
-            out = (_rope_f32(val, positions, cfg.rope_theta)
-                   if nm in stitched
-                   else rope(val, positions, cfg.rope_theta))
+            out = rope(env[ins[0]], positions, cfg.rope_theta, wide=wide)
         elif role == "kv_write":
-            # scatter this step's k/v through to the paged pool — the
-            # hand-wired block's write-through, verbatim (masked rows
-            # land on the scratch page, serving/kv_pages.py); the
+            # scatter this step's k/v through to the paged pool (masked
+            # rows land on the scratch page, serving/kv_pages.py); the
             # attention core then reads the cache, not these tensors
             if not paged:
                 raise ValueError("kv_write node requires a paged cache")
@@ -850,63 +863,32 @@ def run_planned_layer(lp, p: dict, x: jax.Array, cfg: ModelConfig,
         elif role == "attn_qk":
             # the attention core executes as one unit here (fused chain
             # or not — fusion changes pricing and TPU kernel dispatch,
-            # not the XLA twin): attention_block's cache-free
-            # mid-section — or, for a serving plan, the shared
-            # ``_paged_attention_body`` — verbatim
-            q = constrain(env[ins[0]].transpose(0, 2, 1, 3), rules,
-                          "batch", "tp", None, None)
-            scale = 1.0 / math.sqrt(dh)
-            group = cfg.n_heads // cfg.n_kv_heads
+            # not the XLA twin): the cache-free ``attention_core`` or,
+            # for a serving plan, the shared ``_paged_attention_body``
+            q = heads_major(env[ins[0]], rules, "tp")
             if paged:
                 o = _paged_attention_body(
-                    q, cache, page_table, positions, group=group,
-                    win=win, scale=scale, rules=rules, mesh=rt.mesh,
-                    dist_decode=rt.dist_decode_attn,
+                    q, cache, page_table, positions,
+                    group=cfg.n_heads // cfg.n_kv_heads, win=win,
+                    scale=1.0 / math.sqrt(cfg.dh), rules=rules,
+                    mesh=rt.mesh, dist_decode=rt.dist_decode_attn,
                     dist_pipelined=rt.dist_decode_pipelined,
                     kernel_ops=rt.kernel_ops, block=rt.paged_block)
-            elif rt.kernel_ops and s > 1:
-                from ..kernels import ops as kernel_ops_mod
-                k = constrain(env[ins[1]].transpose(0, 2, 1, 3), rules,
-                              "batch", None, None, None)
-                v = constrain(env["wv"].transpose(0, 2, 1, 3), rules,
-                              "batch", None, None, None)
-                o = kernel_ops_mod.attention(
-                    q, k, v, causal=True, window=win, scale=scale,
-                    mesh=rt.mesh if rules.enabled else None, rules=rules)
             else:
-                k = constrain(env[ins[1]].transpose(0, 2, 1, 3), rules,
-                              "batch", None, None, None)
-                v = constrain(env["wv"].transpose(0, 2, 1, 3), rules,
-                              "batch", None, None, None)
-                kk = jnp.repeat(k, group, axis=1)
-                vv = jnp.repeat(v, group, axis=1)
-                if cfg.use_fused_attention and s > 2 * rt.bkv:
-                    o = streaming_attention(q, kk, vv, causal=True,
-                                            window=win, scale=scale,
-                                            bkv=rt.bkv, q_offset=0,
-                                            unroll=rt.unroll)
-                else:
-                    o = naive_attention(q, kk, vv, causal=True,
-                                        window=win, scale=scale)
-            o = constrain(o, rules, "batch", "tp", None, None)
+                o = attention_core(
+                    q, heads_major(env[ins[1]], rules),
+                    heads_major(env["wv"], rules), cfg, rules, window=win,
+                    bkv=rt.bkv, unroll=rt.unroll, mesh=rt.mesh,
+                    kernel_ops=rt.kernel_ops)
             env["qk"] = env["softmax"] = None   # folded into this unit
-            out = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * dh)
+            out = merge_heads(o, rules)
             nm = "pv"
         elif role in ("softmax", "attn_pv"):
             continue                            # handled at attn_qk
         elif role == "gate_act":
-            if cfg.act in ("swiglu", "geglu"):
-                act = jax.nn.silu if cfg.act == "swiglu" else jax.nn.gelu
-                h = act(env[ins[0]]) * env[ins[1]]
-            else:
-                h = jax.nn.gelu(env[ins[0]])
-            out = constrain(h, rules, "batch", None, "tp")
+            out = gate_act(cfg, rules, *(env[i] for i in ins))
         elif role == "residual":
-            mix, res = env[ins[0]], env[ins[1]]
-            if nm in stitched:
-                out = res.astype(jnp.float32) + mix.astype(jnp.float32)
-            else:
-                out = res + mix
+            out = residual(env[ins[1]], env[ins[0]], wide=wide)
         else:
             raise ValueError(f"unknown node role {role!r}")
         if nm in downcast_at:
@@ -1268,7 +1250,7 @@ def mamba_block(p: dict, x: jax.Array, cfg: ModelConfig, rules: Rules,
         hT = h
     y = y + xr * p["D"][None, None, :, None]
     y = y.reshape(b, s, din)
-    y = rmsnorm(y.astype(x.dtype), p["norm_w"], cfg.norm_eps)
+    y = normalize(y.astype(x.dtype), p["norm_w"], cfg.norm_eps)
     y = y * jax.nn.silu(z)
     out = y @ p["w_out"]
     new_state = {"conv": new_conv, "ssm": hT} if state is not None else None

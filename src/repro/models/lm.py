@@ -27,7 +27,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..core import planner as planner_mod
 from ..dist.sharding import Rules, batch_placement, constrain
+from ..reliability import breaker as _breaker
 from . import layers as L
 from .config import ModelConfig
 
@@ -126,6 +128,16 @@ def chunked_ce(hidden: jax.Array, unembed_w: jax.Array, labels: jax.Array,
     (tot, cnt), _ = jax.lax.scan(body, (jnp.float32(0), jnp.float32(0)),
                                  (hc, lc), unroll=nc if unroll else 1)
     return tot / jnp.maximum(cnt, 1.0)
+
+
+def _plan_phase(seq: int, page_size: Optional[int],
+                kv_len: Optional[int]) -> dict:
+    """``core.planner.plan_model``'s phase keywords: the cache-free
+    forward, or prefill/decode (by row count) over a paged cache."""
+    if page_size is None:
+        return {}
+    return dict(phase="prefill" if seq > 1 else "decode", paged=page_size,
+                kv_len=kv_len)
 
 
 class LM:
@@ -237,62 +249,82 @@ class LM:
     # ------------------------------------------------------------------
     # layer application
     # ------------------------------------------------------------------
+    def layer_key(self, batch: int, seq: int,
+                  page_size: Optional[int] = None,
+                  kv_len: Optional[int] = None) -> Optional[tuple]:
+        """The key of the plan an attention layer runs on ``batch`` x
+        ``seq`` rows (``core.planner.plan_key``), over a paged cache of
+        ``page_size``-slot pages and ``kv_len`` slots per row where a
+        page size is given; None where this model never plans.  The
+        circuit breaker quarantines plans by this key
+        (docs/reliability.md)."""
+        if not (self.rt.planner and planner_mod.plannable(self.cfg)):
+            return None
+        return planner_mod.plan_key(self.cfg, batch, seq, self.rt.stitch,
+                                    **_plan_phase(seq, page_size, kv_len))
+
+    def _layer_plan(self, kind: str, x: jax.Array, cache: Optional[dict],
+                    page_table: Optional[jax.Array]) -> Optional[tuple]:
+        """Which plan runs this layer: ``(key, LayerPlan)``, or None
+        where the hand-wired block runs.
+
+        Attention layers of a plannable config plan under
+        ``Runtime(planner=True)``, cache-free or over a paged cache
+        (phase from the row count, ``paged`` and ``kv_len`` from the
+        pool and the page table); contiguous caches stay hand-wired.  A
+        plan quarantined by the circuit breaker (docs/reliability.md)
+        runs the hand-wired twin, bit-identical with stitching off,
+        and a plan that fails to carve is recorded there and degrades
+        the same way."""
+        paged = (cache is not None and page_table is not None
+                 and "k_pages" in cache)
+        if kind != "attn" or not (paged or (cache is None
+                                            and page_table is None)):
+            return None
+        b, s = int(x.shape[0]), int(x.shape[1])
+        ps = int(cache["k_pages"].shape[-3]) if paged else None
+        kv_len = int(page_table.shape[1]) * ps if paged else None
+        key = self.layer_key(b, s, ps, kv_len)
+        if key is None or _breaker.is_open(key):
+            return None
+        try:
+            plan = planner_mod.plan_model(self.cfg, b, s,
+                                          stitch=self.rt.stitch,
+                                          **_plan_phase(s, ps, kv_len))
+        except Exception as e:  # noqa: BLE001 - degrade
+            _breaker.record_failure(key, reason=f"{type(e).__name__}: {e}")
+            return None
+        return key, plan.layer
+
     def _apply_layer(self, kind: str, p: dict, x: jax.Array,
                      positions: jax.Array, cache: Optional[dict],
                      layer_idx_in_pattern: int,
                      page_table: Optional[jax.Array] = None
                      ) -> tuple[jax.Array, Any]:
         cfg, rt = self.cfg, self.rt
-        paged = (cache is not None and page_table is not None
-                 and "k_pages" in cache)
-        if (rt.planner and kind == "attn"
-                and ((cache is None and page_table is None) or paged)):
-            from ..core import planner as planner_mod
-            from ..reliability import breaker as _breaker
-            if planner_mod.plannable(cfg):
-                b_, s_ = int(x.shape[0]), int(x.shape[1])
-                if paged:
-                    ps_ = int(cache["k_pages"].shape[-3])
-                    plan_kw = dict(
-                        phase="prefill" if s_ > 1 else "decode",
-                        paged=ps_,
-                        kv_len=int(page_table.shape[1]) * ps_)
-                else:
-                    plan_kw = dict()
-                pkey = planner_mod.plan_key(cfg, b_, s_, rt.stitch,
-                                            **plan_kw)
-                # A quarantined plan fingerprint (circuit breaker,
-                # docs/reliability.md) degrades to the hand-wired twin
-                # below — bit-identical with stitching off — instead
-                # of retrying the broken planned dispatch.
-                if not _breaker.is_open(pkey):
-                    try:
-                        plan = planner_mod.plan_model(
-                            cfg, b_, s_, stitch=rt.stitch, **plan_kw)
-                        return L.run_planned_layer(
-                            plan.layer, p, x, cfg, rt.rules,
-                            positions=positions, rt=rt, cache=cache,
-                            page_table=page_table)
-                    except Exception as e:  # noqa: BLE001 - degrade
-                        _breaker.record_failure(
-                            pkey,
-                            reason=f"{type(e).__name__}: {e}")
+        planned = self._layer_plan(kind, x, cache, page_table)
+        if planned is not None:
+            key, plan = planned
+            try:
+                return L.run_planned_layer(
+                    plan, p, x, cfg, rt.rules, positions=positions, rt=rt,
+                    cache=cache, page_table=page_table)
+            except Exception as e:  # noqa: BLE001 - degrade
+                _breaker.record_failure(
+                    key, reason=f"{type(e).__name__}: {e}")
         h = L.apply_norm(p["ln1"], x, cfg)
         if kind == "attn":
-            win = cfg.window
-            if cfg.rglru is not None:      # hybrid: local-attn layers
-                win = cfg.rglru.local_window
             if cache is not None and "k_pages" in cache:
                 mix, new_cache = L.paged_attention_block(
                     p["mix"], h, cfg, rt.rules, positions=positions,
-                    cache=cache, page_table=page_table, window=win,
-                    mesh=rt.mesh, dist_decode=rt.dist_decode_attn,
+                    cache=cache, page_table=page_table, mesh=rt.mesh,
+                    dist_decode=rt.dist_decode_attn,
                     dist_pipelined=rt.dist_decode_pipelined,
                     kernel_ops=rt.kernel_ops, block=rt.paged_block)
             else:
                 mix, new_cache = L.attention_block(
                     p["mix"], h, cfg, rt.rules, positions=positions,
-                    cache=cache, window=win, causal=True, bkv=rt.bkv,
+                    cache=cache, causal=True, bkv=rt.bkv,
                     unroll=rt.unroll, mesh=rt.mesh,
                     dist_decode=rt.dist_decode_attn,
                     kernel_ops=rt.kernel_ops)
@@ -302,14 +334,14 @@ class LM:
         else:
             mix, new_cache = L.rglru_block(p["mix"], h, cfg, rt.rules,
                                            state=cache)
-        x = x + mix
+        x = L.residual(x, mix)
         if cfg.d_ff > 0:
             h2 = L.apply_norm(p["ln2"], x, cfg)
             if cfg.moe:
                 ff = L.moe_block(p["ff"], h2, cfg, rt.rules, rt.mesh)
             else:
                 ff = L.mlp_block(p["ff"], h2, cfg, rt.rules)
-            x = x + ff
+            x = L.residual(x, ff)
         return x, new_cache
 
     def _run_blocks(self, params: dict, x: jax.Array, positions: jax.Array,
@@ -445,10 +477,7 @@ class LM:
                           dtype=None):
         cfg = self.cfg
         if kind == "attn":
-            win = (cfg.rglru.local_window if cfg.rglru is not None
-                   else cfg.window)
-            return L.init_attn_cache(cfg, batch, max_len, window=win,
-                                     dtype=dtype)
+            return L.init_attn_cache(cfg, batch, max_len, dtype=dtype)
         dt = dtype or jnp.dtype(cfg.dtype)
         if kind == "mamba":
             s = cfg.ssm

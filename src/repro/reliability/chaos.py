@@ -9,8 +9,9 @@ and workload:
 1. **baseline** — fault-free engine run (also warms the schedule/plan
    disk cache, so the faulted phase has real records to corrupt);
 2. **faulted** — a fresh engine constructed and run with the fault
-   class armed (arming spans construction: plan pre-carve and regime
-   pricing are production load paths too);
+   class armed (arming spans construction, where regime pricing is a
+   production load path, and the run, whose first traces load the
+   plans);
 3. **relaunch** — faults cleared, a fresh engine replays from the
    (possibly repaired) cache — skipping anything the circuit breaker
    quarantined, without a retuning storm.

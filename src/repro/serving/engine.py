@@ -152,7 +152,7 @@ class ServingEngine:
 
     A model built with ``Runtime(planner=True)`` serves planner-carved
     blocks: prefill and decode steps execute phase-keyed plans from
-    ``core.planner`` (decode pre-planned at construction), bit-identical
+    ``core.planner`` (each planned on its first trace), bit-identical
     to the hand-wired paged path on f32 configs with stitching off
     (docs/planner.md §7, tests/test_serving.py).
     """
@@ -219,7 +219,7 @@ class ServingEngine:
                 dist_decode_pipelined=want_pipe and rt.mesh is not None,
                 paged_block=tiles))
         self.model = model
-        self._window = int(model.cfg.window or 0)
+        self._window = model.cfg.attn_window
         self._shadow_fns = None      # lazily jitted tier-1 twin pair
         # compiled step programs of the jitted tiers, keyed by
         # (tier, phase, input avals and shardings), the seconds each
@@ -232,27 +232,6 @@ class ServingEngine:
         self.temp_bytes: dict[tuple, Optional[int]] = {}
         self.cache = model.init_paged_cache(n_pages, page_size)
         self._build_exec()
-        if model.rt.planner:
-            # Pre-plan the steady-state decode DAG at construction so
-            # the first serving step never pays the carve: every later
-            # decode_step_paged hits the plan memo (and relaunches
-            # replay the ("plan", …, phase, paged) disk record —
-            # core/schedule_cache.py).  Prefill shapes vary per prompt
-            # and are planned (then memoized) on first sight.  A
-            # quarantined decode plan (circuit breaker) is skipped —
-            # the layer-level dispatch degrades to the hand-wired twin
-            # instead of re-carving a denylisted fingerprint.
-            from ..core import planner as planner_mod
-            if planner_mod.plannable(model.cfg):
-                dkey = planner_mod.plan_key(
-                    model.cfg, self.max_batch, 1, model.rt.stitch,
-                    phase="decode", paged=self.page_size,
-                    kv_len=self.n_ctx)
-                if not _breaker.is_open(dkey):
-                    planner_mod.plan_model(
-                        model.cfg, self.max_batch, 1,
-                        stitch=model.rt.stitch, phase="decode",
-                        paged=self.page_size, kv_len=self.n_ctx)
         self._golden_probe()
 
     # ------------------------------------------------------------------
@@ -317,15 +296,10 @@ class ServingEngine:
         ``reason`` is recorded verbatim on the breaker denylist entry —
         crashes pass ``"TypeName: msg"``, sentinel mismatches pass a
         shadow/golden-probe description."""
-        if self.exec_tier == 0 and self.model.rt.planner:
-            from ..core import planner as planner_mod
-            if planner_mod.plannable(self.model.cfg):
-                dkey = planner_mod.plan_key(
-                    self.model.cfg, self.max_batch, 1,
-                    self.model.rt.stitch, phase="decode",
-                    paged=self.page_size, kv_len=self.n_ctx)
-                _breaker.record_failure(
-                    dkey, reason=f"engine {phase}: {reason}")
+        dkey = self.model.layer_key(self.max_batch, 1, self.page_size,
+                                    self.n_ctx)
+        if self.exec_tier == 0 and dkey is not None:
+            _breaker.record_failure(dkey, reason=f"engine {phase}: {reason}")
         if self.verbose:
             print(f"serving tier demotion on {phase}: "
                   f"{TIERS[self.exec_tier]} -> "

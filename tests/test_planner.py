@@ -107,6 +107,50 @@ def test_planner_stitched_within_tolerance(arch):
         assert np.array_equal(np.asarray(hand), np.asarray(stitched))
 
 
+@pytest.mark.parametrize("stitch", [False, True])
+def test_planner_paged_windowed_steps(stitch):
+    """Paged prefill and decode steps under a sliding window shorter
+    than the prompt, so decode crosses the window's edge: the planner
+    walk against the hand-wired blocks — bit-for-bit with stitching
+    off, within the stitched tolerance with it on (and bitwise on this
+    float32 config)."""
+    cfg = dataclasses.replace(get_config("qwen3_8b", smoke=True), window=6)
+    params = S.build_model(cfg, Runtime(remat=False)).init_params(
+        jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (1, 16), 0, cfg.vocab)
+    table = jnp.arange(1, 9, dtype=jnp.int32)[None]     # 8 pages of 4
+    outs = []
+    for rt in (Runtime(remat=False),
+               Runtime(remat=False, planner=True, stitch=stitch)):
+        model = S.build_model(cfg, rt)
+        cache = model.init_paged_cache(10, 4)
+        logits, cache = jax.jit(model.prefill_paged)(
+            params, toks, cache, table, jnp.int32(13))
+        steps = [logits]
+        for i in range(3):
+            logits, cache = jax.jit(model.decode_step_paged)(
+                params, cache, toks[:, i], jnp.array([13 + i], jnp.int32),
+                table)
+            steps.append(logits)
+        outs.append(np.asarray(jnp.stack(steps)))
+    assert {k[8] for k in planner._PLAN_MEMO} == {"prefill", "decode"}
+    hand, planned = outs
+    np.testing.assert_allclose(planned, hand, rtol=1e-5, atol=1e-5)
+    if not stitch or cfg.dtype == "float32":
+        assert np.array_equal(hand, planned)
+
+
+@pytest.mark.parametrize("smoke", [False, True])
+@pytest.mark.parametrize("arch", ARCHS)
+def test_attn_window_rule(arch, smoke):
+    """One window rule for attention layers: a hybrid's local window,
+    else ``window`` — and the two agree wherever the planner runs."""
+    cfg = get_config(arch, smoke=smoke)
+    assert cfg.attn_window == (cfg.rglru.local_window if cfg.rglru
+                               else cfg.window)
+    assert not planner.plannable(cfg) or cfg.attn_window == cfg.window
+
+
 def test_planner_stitched_bf16_tolerance():
     """bf16 stitching genuinely moves rounding (wide glue, boundary
     downcast): not bitwise, but within bf16 resolution of hand-wired."""

@@ -746,19 +746,31 @@ def test_watchdog_counts_slow_injected_step(_model):
 
 
 def test_clear_quarantine_reenables_decode_preplan(_model):
-    """Operator round-trip: quarantining the decode plan fingerprint
-    makes engine construction skip the pre-carve; clear_quarantine +
-    a breaker reset restores it on the next relaunch."""
+    """Operator round-trip: engine construction plans nothing, and with
+    the decode plan fingerprint quarantined the first decode step runs
+    the hand-wired layer instead of planning it; clear_quarantine + a
+    breaker reset plans it again at the next relaunch's first decode
+    step."""
     _, params = _model
     from repro.core.perf_model import V5E as _hw
-    planned = LM(CFG, Runtime(planner=True, stitch=False))
     dkey = planner.plan_key(CFG, 2, 1, False, phase="decode", paged=4,
                             kv_len=16)
     breaker.record_failure(dkey, reason="operator test")
-    ServingEngine(planned, params, **ENG_KW)
+
+    def relaunch():
+        # a fresh model per relaunch, so no trace is reused
+        planned = LM(CFG, Runtime(planner=True, stitch=False))
+        eng = ServingEngine(planned, params, **ENG_KW)
+        assert not planner._PLAN_MEMO
+        eng.submit(np.arange(4, dtype=np.int32) % CFG.vocab, 2)
+        while eng.stats["decode_steps"] == 0:
+            eng.step()
+
+    relaunch()
     assert all(k[8] != "decode" for k in planner._PLAN_MEMO)
+    assert any(k[8] == "prefill" for k in planner._PLAN_MEMO)
     assert schedule_cache.clear_quarantine(dkey, _hw)
     breaker.reset()                    # relaunch: fresh memoization
     planner.clear_memo()
-    ServingEngine(planned, params, **ENG_KW)
-    assert any(k[8] == "decode" for k in planner._PLAN_MEMO)
+    relaunch()
+    assert dkey in planner._PLAN_MEMO

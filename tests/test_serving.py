@@ -531,6 +531,37 @@ def test_engine_planner_matches_hand_wired(stitch, _plan_cache):
             assert r.tokens == want
 
 
+@pytest.mark.parametrize("stitch", [False, True])
+def test_engine_planner_matches_hand_wired_windowed(stitch, _plan_cache):
+    """The same comparison under a sliding window shorter than the
+    prompts: prefill masks inside the prompt, and decode crosses the
+    window's edge at every step and reclaims the pages below it.  The
+    planned layers read the window from the one rule the hand-wired
+    blocks read (``ModelConfig.attn_window``), so the token streams are
+    bit-identical with stitching off, and on this float32 config with
+    it on."""
+    import dataclasses as _dc
+    planner = _plan_cache
+    cfg = _dc.replace(CFG, window=6)
+    hand = LM(cfg)
+    params = hand.init_params(jax.random.PRNGKey(0))
+    rng = np.random.RandomState(2)
+    reqs = [(rng.randint(0, cfg.vocab, size=n).astype(np.int32), g)
+            for n, g in ((9, 7), (13, 10), (7, 12), (11, 3))]
+    kw = dict(max_batch=3, page_size=4, n_pages=32, max_pages_per_seq=8,
+              choose_regime=False)
+    base, _ = ServingEngine(hand, params, **kw).run(reqs)
+
+    eng = ServingEngine(LM(cfg, Runtime(planner=True, stitch=stitch)),
+                        params, **kw)
+    results, stats = eng.run(reqs)
+    assert [r.tokens for r in results] == [r.tokens for r in base]
+    assert stats["reclaimed_pages"] > 0
+    keys = list(planner._PLAN_MEMO)
+    assert {"prefill", "decode"} <= {k[8] for k in keys}
+    assert all(k[2] == planner.config_fingerprint(cfg) for k in keys)
+
+
 def test_engine_planner_preemption_recovers(_plan_cache):
     """Preemption + recompute-prefill through planner-served blocks:
     same recovery semantics and the same tokens as the hand-wired
